@@ -106,7 +106,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         # the only branch that touches jax — deferred so gating a log
         # works on hosts without an accelerator stack
         from apex_tpu.loadtest.runner import run_scenario
+        from apex_tpu.utils.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         run = run_scenario(scenario, log_path=args.out)
         records = run.records
 

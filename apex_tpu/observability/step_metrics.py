@@ -65,7 +65,8 @@ class StepMetrics:
         :mod:`apex_tpu.utils.flops`) — enables ``model_tflops`` and,
         with a known peak, ``mfu``.
       peak_flops: per-chip peak FLOP/s; defaults to the chip table
-        (None on CPU — MFU then stays unset).
+        (None on CPU — MFU then stays unset; an unlisted TPU kind
+        raises rather than guess).
       memory_interval_steps: refresh device memory gauges every N steps
         (0 disables; backends without ``memory_stats`` emit nothing).
       clock: injectable monotonic clock, for deterministic tests.

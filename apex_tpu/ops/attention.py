@@ -36,8 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._support import (pallas_interpret, round_up,
-                                   tpu_compiler_params, use_pallas)
+from apex_tpu.ops._support import pallas_interpret, round_up, use_pallas
 
 __all__ = ["flash_attention", "flash_attention_packed",
            "packed_attention_supported", "flash_chunk_fwd",
@@ -243,7 +242,7 @@ def _run_fwd_single(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
             jax.ShapeDtypeStruct((batch, heads, sqp, dp), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, sqp), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
     )(*args, q, k, v)
@@ -397,7 +396,7 @@ def _run_fwd(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, dp), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
@@ -658,7 +657,7 @@ def _run_bwd_fused(q, k, v, do, lse, delta, kv_lengths, scale, causal,
         scratch_shapes=[pltpu.VMEM((bk, dp), jnp.float32),
                         pltpu.VMEM((bk, dp), jnp.float32)],
         input_output_aliases={len(kvl_spec) + 1: 0},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=pallas_interpret(),
@@ -999,7 +998,7 @@ def _run_fwd_packed(qkv2, kv_lengths, rope, drop, *, scale, s, batch, W,
             jax.ShapeDtypeStruct((s, batch * heads * d), qkv2.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, s), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
     )(*args, qkv2)
@@ -1043,7 +1042,7 @@ def _run_bwd_packed(qkv2, do2, o2, lse, kv_lengths, rope, drop, *, scale,
         ],
         out_specs=pl.BlockSpec((s, in_w), lambda b, c: (0, b * n_cells + c)),
         out_shape=jax.ShapeDtypeStruct(qkv2.shape, qkv2.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
     )(*args, qkv2, do2, o2, lse)
@@ -1319,7 +1318,7 @@ def _run_bwd_single(q, k, v, do, lse, delta, kv_lengths, scale, causal,
         ],
         scratch_shapes=[pltpu.VMEM((bk, dp), jnp.float32),
                         pltpu.VMEM((bk, dp), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
     )(*args, q, k, v, do, lse, delta)
@@ -1397,7 +1396,7 @@ def _run_bwd(q, k, v, do, lse, delta, kv_lengths, scale, causal,
         out_specs=pl.BlockSpec((1, 1, bq, dp), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dp), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
@@ -1434,7 +1433,7 @@ def _run_bwd(q, k, v, do, lse, delta, kv_lengths, scale, causal,
         ],
         scratch_shapes=[pltpu.VMEM((bk, dp), jnp.float32),
                         pltpu.VMEM((bk, dp), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),

@@ -32,8 +32,9 @@ roofline (docs/serving.md#kv-quantization, #speculative-decoding):
   with RESCALE-ON-APPEND — a page's scale only ever grows (scatter-max
   of the incoming rows' absmax), resident int8 rows are rescaled by
   ``old/new``, and the new rows quantize at the final scale — and the
-  kernel dequantizes inline on the VMEM-resident block, so the HBM
-  stream is half the bf16 bytes with no new read site.
+  kernel streams the int8 page as is, folding each page's per-head
+  scale into the scores and the weighted values, so the HBM stream is
+  half the bf16 bytes with no new read site and no dequantized copy.
 
 Layouts (see docs/serving.md#paged-kv):
 
@@ -58,7 +59,9 @@ reproduces the flat cache's single-token MXU formulation bit-for-bit on
 the gathered logical view, so the paged engine stays TOKEN-EXACT
 against the flat engine on CPU (the tier-1 parity bar); the kernel's
 flash accumulation is validated against the reference to numerical
-tolerance in interpret mode.
+tolerance in interpret mode, compiled by the real Mosaic compiler in
+tier-1 (``tests/test_chip_smoke.py``, no chip needed) and compared on
+the chip at GPT-2 124M widths by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -270,7 +273,8 @@ def _reference(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
 
 
 def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, group, window, quantized, sliding_window):
+                   page_size, heads, window, quantized, sliding_window,
+                   scale):
     """One (slot, page-block) grid cell of the streaming decode pass.
 
     The page table is scalar-prefetched, so block ``(r, j)``'s K/V page
@@ -279,13 +283,23 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     recurrence over page blocks (running max / normalizer / weighted
     accumulator in VMEM scratch, carried across the slot's inner grid
     iterations); the final block rescales and writes the context rows.
-    The ``window`` query rows fold into the per-kv-head query block
-    (``group * window`` rows), each masked to its own validity limit
-    ``pos + t``. Quantized pools dequantize the VMEM-resident block
-    in-register from the gathered per-page scales — HBM still streams
-    int8. Pages past the slot's valid length are skipped (their DMA is
-    the residual cost of the rectangular grid — ~one page per slot in
-    steady state since the engine allocates pages on demand)."""
+
+    Everything is 2-D and full-lane — the form Mosaic compiles (an
+    in-kernel ``[ps, f] -> [ps, kvh, dh]`` split of the lane dim, 4-D
+    transposes and non-leading dot batch dims are all refused at
+    ``head_dim`` 64): the ``m = window * heads`` queries arrive as the
+    block-masked ``[m, f]`` matrix :func:`_query_block` builds (each
+    query's vector in its K/V head's lane block, zeros elsewhere), so
+    ``scores = Qblock @ page^T`` is one MXU GEMM over the whole fused
+    ``f = kvh * dh`` dim, and ``P @ page`` yields ``[m, f]`` rows whose
+    own head's lane block holds that query's context (the caller
+    selects it). Quantized pools stream int8 and fold each page's
+    per-kv-head scale into the scores / weighted values as a per-query
+    column — a query only ever reads its own head's lanes, so scaling
+    its row equals dequantizing that head. Pages past the slot's valid
+    length are skipped (their DMA is the residual cost of the
+    rectangular grid — one page per slot, since consecutive sentinel
+    entries clamp to the same block and Pallas does not re-fetch it)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -293,6 +307,7 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     r = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[r]                  # first window row's append index
+    m = window * heads
 
     @pl.when(j == 0)
     def _init():
@@ -302,53 +317,65 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * page_size <= pos + (window - 1))
     def _accumulate():
-        w, hl, dh = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-        kvh = hl // group
-        # [w, kvh, group, dh] -> [kvh, w*group, dh]: per-kv-head query
-        # block with the window folded in
-        qh = q_ref[0].reshape(w, kvh, group, dh).transpose(1, 0, 2, 3) \
-            .reshape(kvh, w * group, dh).astype(jnp.float32)
-        kb = k_ref[0].reshape(page_size, kvh, dh).astype(jnp.float32)
-        vb = v_ref[0].reshape(page_size, kvh, dh).astype(jnp.float32)
-        if quantized:
-            kb = kb * ks_ref[0, 0][None, :, None]
-            vb = vb * vs_ref[0, 0][None, :, None]
+        qb = q_ref[0]                                     # [m, f]
+        kb = k_ref[0].astype(qb.dtype)                    # [ps, f]
+        vb = v_ref[0].astype(qb.dtype)
         s_blk = jax.lax.dot_general(
-            qh, kb, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)  # [kvh, w*group, ps]
-        s_blk = s_blk / jnp.sqrt(jnp.float32(dh))
+            qb, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [m, ps]
+        if quantized:
+            # this page's scale per query: column j of the slot's
+            # [m, pages_per_slot] table, picked with a lane mask
+            lane = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
+            k_col = jnp.sum(jnp.where(lane == j, ks_ref[0], 0.0),
+                            axis=1, keepdims=True)        # [m, 1]
+            v_col = jnp.sum(jnp.where(lane == j, vs_ref[0], 0.0),
+                            axis=1, keepdims=True)
+            s_blk = s_blk * k_col
         row = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        t = jax.lax.broadcasted_iota(
-            jnp.int32, (1, w * group, 1), 1) // group
-        lim = pos + t
+            jnp.int32, (1, page_size), 1)
+        # window row t of each query (queries are ordered [t, head]);
+        # a compare-and-add ladder, no vector integer division
+        qi = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+        lim = pos + sum(((qi >= t * heads).astype(jnp.int32)
+                         for t in range(1, window)),
+                        jnp.zeros((m, 1), jnp.int32))
         invalid = row > lim
         if sliding_window is not None:
             invalid = jnp.logical_or(invalid, row <= lim - sliding_window)
         s_blk = jnp.where(invalid, _NEG, s_blk)
         m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1))
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new[..., None])    # [kvh, w*group, ps]
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1)
+        p = jnp.exp(s_blk - m_new)                        # [m, ps]
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, vb, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)  # [kvh, w*group, dh]
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [m, f]
+        if quantized:
+            pv = pv * v_col
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        w, hl, dh = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-        kvh = hl // group
         # l > 0 for every real window row: row `pos + t` itself is valid
         # by construction (garbage rows past the slot's window are
         # normalized over whatever survived the mask — the engine never
         # reads them)
         l = jnp.where(l_ref[...] > 0.0, l_ref[...], 1.0)
-        ctx = acc_ref[...] / l[..., None]        # [kvh, w*group, dh]
-        ctx = ctx.reshape(kvh, w, group, dh).transpose(1, 0, 2, 3)
-        o_ref[...] = ctx.reshape(1, w, hl * dh).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _query_block(q, kv_head, kvh):
+    """``q`` ``[b, w, hl, dh]`` -> the block-masked ``[b, w*hl, kvh*dh]``
+    query matrix: each query's vector sits in the lane block of its K/V
+    head (``kv_head`` ``[w*hl]``), zeros elsewhere — the row-major twin
+    of :func:`_reference`'s ``qblock``."""
+    b, w, hl, dh = q.shape
+    tiled = jnp.tile(q.reshape(b, w * hl, dh), (1, 1, kvh))
+    mask = kv_head[:, None] == (jnp.arange(kvh * dh) // dh)[None, :]
+    return jnp.where(mask[None], tiled, jnp.zeros((), q.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("group", "sliding_window"))
@@ -357,6 +384,7 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
     n_pages, page_size, f = k_pages.shape
     b, w, hl, dh = q.shape
     kvh = f // dh
+    m = w * hl
     pages_per_slot = page_table.shape[1]
     # append first (donated in-place row writes); the kernel then
     # streams pages that already contain the new rows — one read of the
@@ -374,44 +402,55 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
         v_pages = _append_rows(v_pages, v_new, page_table, positions,
                                page_size)
     pt = jnp.minimum(page_table, n_pages - 1).astype(jnp.int32)
+    # K/V head of each of the m queries (ordered [window row, head])
+    kv_head = (jnp.arange(m) % hl) // group
 
     kernel = functools.partial(
-        _decode_kernel, page_size=page_size, group=group, window=w,
-        quantized=quantized, sliding_window=sliding_window)
+        _decode_kernel, page_size=page_size, heads=hl, window=w,
+        quantized=quantized, sliding_window=sliding_window,
+        scale=1.0 / float(dh) ** 0.5)
     in_specs = [
-        pl.BlockSpec((1, w, hl, dh), lambda r, j, pt, pos: (r, 0, 0, 0)),
+        pl.BlockSpec((1, m, f), lambda r, j, pt, pos: (r, 0, 0)),
         pl.BlockSpec((1, page_size, f),
                      lambda r, j, pt, pos: (pt[r, j], 0, 0)),
         pl.BlockSpec((1, page_size, f),
                      lambda r, j, pt, pos: (pt[r, j], 0, 0)),
     ]
-    inputs = [pt, positions.astype(jnp.int32), q, k_pages, v_pages]
+    inputs = [pt, positions.astype(jnp.int32),
+              _query_block(q, kv_head, kvh), k_pages, v_pages]
     if quantized:
-        # per-page scales, pre-gathered to the table layout so block
-        # (r, j) reads its own page's row — tiny f32 sidecar next to
-        # the int8 stream
-        in_specs += [
-            pl.BlockSpec((1, 1, kvh), lambda r, j, pt, pos: (r, j, 0)),
-            pl.BlockSpec((1, 1, kvh), lambda r, j, pt, pos: (r, j, 0)),
-        ]
-        inputs += [k_scales[pt], v_scales[pt]]
+        # per-(slot, query, page) scales: the page's sidecar row gathered
+        # to the table layout and expanded to each query's own K/V head
+        # — a [m, pages_per_slot] f32 tile per slot, resident across the
+        # slot's page loop next to the int8 stream
+        def per_query(scales):
+            return scales[pt][:, :, kv_head].transpose(0, 2, 1)
+
+        spec = pl.BlockSpec((1, m, pages_per_slot),
+                            lambda r, j, pt, pos: (r, 0, 0))
+        in_specs += [spec, spec]
+        inputs += [per_query(k_scales), per_query(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, pages_per_slot),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, w, hl * dh),
-                               lambda r, j, pt, pos: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, m, f), lambda r, j, pt, pos: (r, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((kvh, w * group), jnp.float32),      # running max
-            pltpu.VMEM((kvh, w * group), jnp.float32),      # normalizer
-            pltpu.VMEM((kvh, w * group, dh), jnp.float32),  # weighted acc
+            pltpu.VMEM((m, 1), jnp.float32),      # running max
+            pltpu.VMEM((m, 1), jnp.float32),      # normalizer
+            pltpu.VMEM((m, f), jnp.float32),      # weighted accumulator
         ])
-    ctx = pl.pallas_call(
+    ctx_big = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, w, hl * dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, m, f), q.dtype),
         interpret=pallas_interpret(),
     )(*inputs)
-    return ctx, k_pages, v_pages, k_scales, v_scales
+    # each query keeps its own K/V head's lane block
+    sel = (jnp.arange(kvh)[None, :]
+           == (jnp.arange(hl) // group)[:, None]).astype(q.dtype)
+    ctx = jnp.einsum("bwjkd,jk->bwjd",
+                     ctx_big.reshape(b, w, hl, kvh, dh), sel)
+    return ctx.reshape(b, w, hl * dh), k_pages, v_pages, k_scales, v_scales
 
 
 def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from apex_tpu.ops._support import pallas_interpret
 from apex_tpu.ops.attention import (
     _LSE_PAD,
     flash_attention,
@@ -101,17 +100,7 @@ def _ring_fwd_impl(q, k, v, kv_lengths, causal, window, scale, axis_name):
 
     init = (k, v, o0.astype(jnp.float32),
             jnp.where(lse0 > _PAD_THRESH, -jnp.inf, lse0))
-    if pallas_interpret():
-        # interpret-mode emulation (CPU tests): an interpret pallas_call
-        # inside a scan body trips XLA's SPMD partitioner (a PartitionId
-        # reaches it through the scan); cp is static, so unroll — compile
-        # time/temp memory only matter on the scan path real HW takes
-        carry = init
-        for t in range(1, cp):
-            carry, _ = hop(carry, t)
-        _, _, o, lse = carry
-    else:
-        (_, _, o, lse), _ = lax.scan(hop, init, jnp.arange(1, cp))
+    (_, _, o, lse), _ = lax.scan(hop, init, jnp.arange(1, cp))
     return o.astype(q.dtype), lse
 
 
@@ -156,14 +145,7 @@ def _ring_vjp_bwd(causal, window, scale, axis_name, res, do):
 
     init = (k, v, jnp.zeros(k.shape, jnp.float32),
             jnp.zeros(v.shape, jnp.float32), jnp.zeros(q.shape, jnp.float32))
-    if pallas_interpret():
-        # unrolled under interpret-mode emulation — see _ring_fwd_impl
-        carry = init
-        for t in range(cp - 1):
-            carry, _ = hop(carry, t)
-        kc, vc, dk, dv, dq = carry
-    else:
-        (kc, vc, dk, dv, dq), _ = lax.scan(hop, init, jnp.arange(cp - 1))
+    (kc, vc, dk, dv, dq), _ = lax.scan(hop, init, jnp.arange(cp - 1))
     # final chunk: accumulate, then rotate ONLY the accumulators home — the
     # K/V chunks' last rotation would be discarded traffic
     dq_j, dk_j, dv_j = chunk_bwd(kc, vc, (rank - (cp - 1)) % cp)

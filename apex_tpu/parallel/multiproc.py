@@ -43,10 +43,10 @@ def init_distributed(coordinator_address=None, num_processes=None,
             coordinator_address=coordinator_address,
             num_processes=num_processes, process_id=process_id)
     except RuntimeError as e:
-        # double-init message differs across jax versions ("already
-        # initialized" / "should only be called once")
-        msg = str(e)
-        if "already initialized" not in msg and "only be called once" not in msg:
+        # a second call in one process is a no-op here, anything else is
+        # an error (jax: "distributed.initialize should only be called
+        # once.")
+        if "only be called once" not in str(e):
             raise
     return jax.process_count()
 

@@ -171,8 +171,8 @@ class ResilienceConfig:
     #: model FLOPs per step (see :mod:`apex_tpu.utils.flops`) — enables
     #: ``model_tflops`` and, with a known/overridden peak, ``mfu``.
     model_flops_per_step: Optional[float] = None
-    #: per-chip peak FLOP/s override; default auto-detects from the chip
-    #: table (None on CPU/unknown — MFU then stays unset).
+    #: per-chip peak FLOP/s override; default reads the chip table (None
+    #: on CPU — MFU then stays unset; an unlisted TPU kind raises).
     peak_flops: Optional[float] = None
     #: device ``memory_stats()`` gauge cadence in steps (0 disables).
     memory_stats_interval_steps: int = 50

@@ -1826,6 +1826,28 @@ class InferenceEngine:
             self._window_h[slot] = window
             self._wlen_h[slot] = wl
 
+    def _decode_args(self) -> tuple:
+        """The decode program's arguments from the current host arrays
+        (paged: the page table rides right after the pool; with
+        speculation the fed tokens are the ``[n, k]`` window matrix)."""
+        fed = (jnp.asarray(self._window_h) if self._spec
+               else jnp.asarray(self._tokens_h))
+        table = (() if self.pages is None
+                 else (jnp.asarray(self._page_table_h),))
+        return (self._params, self._caches, *table, fed,
+                jnp.asarray(self._positions_h), jnp.asarray(self._temps_h),
+                jnp.asarray(self._topks_h), jnp.asarray(self._seeds_h),
+                jnp.asarray(self._adapter_ix_h), self._bank)
+
+    def decode_program_text(self) -> str:
+        """Optimized HLO text of the decode program, compiled for the
+        current backend without running it (nothing is donated) — what
+        ``chip_smoke.py`` reads to prove the fused kernel was compiled by
+        Mosaic (a ``tpu_custom_call``) and neither interpreted nor
+        replaced by the ``jnp`` reference."""
+        return (self._decode_fn._fn.lower(*self._decode_args())
+                .compile().as_text())
+
     def _decode_tick(self, finished: List[RequestResult]) -> None:
         if self._spec and self._active:
             self._build_windows()
@@ -1843,22 +1865,7 @@ class InferenceEngine:
                 "kv_bytes_per_step",
                 sum(len(self.pages.slot_pages(s)) for s in self._active)
                 * self._page_read_bytes)
-            fed = (jnp.asarray(self._window_h) if self._spec
-                   else jnp.asarray(self._tokens_h))
-            nxt, finite, self._caches = self._decode_fn(
-                self._params, self._caches,
-                jnp.asarray(self._page_table_h),
-                fed, jnp.asarray(self._positions_h),
-                jnp.asarray(self._temps_h), jnp.asarray(self._topks_h),
-                jnp.asarray(self._seeds_h),
-                jnp.asarray(self._adapter_ix_h), self._bank)
-        else:
-            nxt, finite, self._caches = self._decode_fn(
-                self._params, self._caches,
-                jnp.asarray(self._tokens_h), jnp.asarray(self._positions_h),
-                jnp.asarray(self._temps_h), jnp.asarray(self._topks_h),
-                jnp.asarray(self._seeds_h),
-                jnp.asarray(self._adapter_ix_h), self._bank)
+        nxt, finite, self._caches = self._decode_fn(*self._decode_args())
         nxt = np.asarray(nxt)
         finite = np.asarray(finite)
         if self._faults is not None:

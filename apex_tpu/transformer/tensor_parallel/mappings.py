@@ -57,8 +57,7 @@ def axis_bound(axis_name: str) -> bool:
 
 
 def axis_size(axis_name: str) -> int:
-    from apex_tpu.utils.sharding import axis_size as _axis_size
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def _local_chunk(x: jax.Array, axis_name: str, dim: int) -> jax.Array:

@@ -30,15 +30,25 @@ _PEAK_FLOPS = {
 
 
 def peak_flops_per_chip(device=None) -> Optional[float]:
-    """bf16 peak FLOP/s of ``device`` (default: the first visible device),
-    or None when the device kind is not in the table (CPU, unknown TPU)."""
+    """bf16 peak FLOP/s of ``device`` (default: the first visible device).
+
+    ``None`` only on the CPU platform, where MFU has no meaning. On any
+    other platform the ``device_kind`` must be in the table EXACTLY — an
+    unlisted kind raises, because a guessed peak is a wrong MFU under a
+    real name (a prefix match would hand an unknown "TPU v5x" the v5p
+    number)."""
     import jax
 
-    kind = (device or jax.devices()[0]).device_kind
-    for name, peak in _PEAK_FLOPS.items():
-        if kind.startswith(name):
-            return peak
-    return None
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    try:
+        return _PEAK_FLOPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add it to _PEAK_FLOPS with "
+            f"its source rather than assuming one") from None
 
 
 def transformer_train_flops(n_params: int, tokens: int, num_layers: int,

@@ -7,6 +7,7 @@ names are bound in the current trace (inside ``shard_map``).
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence, Tuple
 
 import jax
@@ -16,32 +17,14 @@ __all__ = ["spec_axis_names", "bound_axes", "broadcast_spec", "shard_map",
            "axis_size"]
 
 
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` across jax versions: 0.4.x lacks it, but a psum of
-    the literal ``1`` over a bound axis is evaluated statically at trace
-    time, so this returns a plain Python int either way (callers build
-    static grid/schedule structure from it)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+#: static size of a bound mesh axis (a plain Python int at trace time —
+#: callers build grid/schedule structure from it)
+axis_size = lax.axis_size
 
-
-def shard_map(f=None, *, mesh, in_specs, out_specs,
-              check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at the
-    top level with the replication check spelled ``check_vma``; 0.4.x only
-    has ``jax.experimental.shard_map`` with ``check_rep``. Every shard_map
-    in the package routes through here so a jax upgrade is one-file.
-    Without ``f`` returns a decorator (the new-API partial form)."""
-    if f is None:
-        return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_vma=check_vma)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+#: ``jax.shard_map`` with the replication check off: the package's
+#: per-rank bodies use explicit collective regions (custom-vjp psums)
+#: the varying-manual-axes checker cannot type
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def spec_axis_names(spec) -> set:

@@ -523,6 +523,7 @@ class TestVerifyCLI:
         corrupt_shard(str(tmp_path), 1, leaf=2, shard=1, kind="truncate")
         assert verify_main(["verify", str(tmp_path), "--shallow"]) == 1
 
+    @pytest.mark.slow
     def test_cli_subprocess_contract(self, tmp_path):
         """The real entry point: ``python -m apex_tpu.checkpoint verify``
         exits non-zero on damage, zero once the damage is gone."""

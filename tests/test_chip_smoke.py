@@ -1,0 +1,170 @@
+"""``chip_smoke.py`` on the CPU: its phases at a tiny config with
+interpret-mode kernels, its device gate, the compile-cache helper, the
+peak table's refusal to guess — and the decode kernel through the REAL
+Mosaic compiler (libtpu compiles for a described v5e topology without a
+chip), which is what interpret mode cannot see."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from apex_tpu.models import TransformerConfig
+from apex_tpu.ops import _support, decode_attention
+from apex_tpu.serving import EngineConfig
+from apex_tpu.utils import compile_cache
+from apex_tpu.utils.flops import peak_flops_per_chip
+
+TINY = TransformerConfig(
+    num_layers=2, hidden_size=64, num_attention_heads=4, vocab_size=128,
+    max_position_embeddings=64, hidden_dropout=0.0, attention_dropout=0.0,
+    compute_dtype=jnp.bfloat16)
+
+
+def test_main_refuses_cpu_before_building_anything(monkeypatch, capsys):
+    def no_build(*a, **k):
+        raise AssertionError("built a model on a CPU backend")
+
+    monkeypatch.setattr(chip_smoke, "gpt2_124m", no_build)
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.main()
+    assert exc.value.code not in (0, None)
+    assert "cpu" in str(exc.value.code)
+    assert capsys.readouterr().out == ""        # no result line
+
+
+def test_last_stdout_line_is_the_bare_verdict(monkeypatch, capsys):
+    """The driver parses the LAST stdout line: one JSON object with
+    exactly ``ok`` and ``device`` {platform, kind, count}. Phases are
+    stubbed; this pins main()'s output contract only."""
+    import json
+
+    import benchmarks._harness as harness
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(harness, "start", lambda: dict(device))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "tpu")
+    _support.pallas_mode.cache_clear()
+
+    class Ledger:
+        def summary(self):
+            return {}
+
+    monkeypatch.setattr(chip_smoke, "_CompileLedger", Ledger)
+    monkeypatch.setattr(chip_smoke, "seeded_model", lambda cfg: (None, None))
+    monkeypatch.setattr(chip_smoke, "train_phase", lambda *a, **k: {
+        "first_loss": 2.0, "last_loss": 1.0, "tpu_custom_calls": 1})
+    monkeypatch.setattr(chip_smoke, "kernel_phase", lambda **k: {})
+    monkeypatch.setattr(chip_smoke, "serve_phase",
+                        lambda *a, **k: {"tpu_custom_calls": 1})
+    try:
+        assert chip_smoke.main() == 0
+    finally:
+        _support.pallas_mode.cache_clear()
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].startswith("chip_smoke: summary ")
+    summary = json.loads(lines[-2].split("summary ", 1)[1])
+    assert summary["multichip"] == "not run: 1 device(s)"
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+
+
+def test_train_phase_tiny(pallas_kernels):
+    out = chip_smoke.train_phase(TINY, batch=4, seq=32, steps=5)
+    assert out["last_loss"] < out["first_loss"]
+    assert out["tpu_custom_calls"] == 0         # interpreted, not Mosaic
+
+
+def test_serve_phase_tiny(pallas_kernels):
+    engine_cfg = EngineConfig(max_slots=4, max_len=64, page_size=8)
+    out = chip_smoke.serve_phase(
+        *chip_smoke.seeded_model(TINY), engine_cfg,
+        # two prefill buckets (8, 32); #3 hits #2's 16-token prefix
+        lambda: chip_smoke.build_requests(
+            TINY.vocab_size, (5, 7, 20, 24, 6, 8), (6, 8, 5, 7, 4, 10),
+            16, (2, 3)),
+        generate_checks=1)
+    assert out["requests"] == 6 and out["tokens"] == 40
+    assert out["prefix_hits"] >= 1
+    assert out["bare_vs_supervised_identical"] == "6/6"
+
+
+@pytest.mark.slow   # kernel-vs-reference itself is tier-1 in
+#                     test_serving_paged / test_spec_quant; this only
+#                     drives the phase function's own plumbing
+def test_kernel_phase_tiny(pallas_kernels):
+    errors = chip_smoke.kernel_phase(
+        slots=4, heads=4, head_dim=16, page_size=8, pages_per_slot=8,
+        dtype=jnp.bfloat16)["max_abs_err"]
+    assert set(errors) == {"bf16", "bf16_w3", "int8", "int8_w3"}
+
+
+def test_compile_cache_is_placeable_and_fixed(monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert compile_cache.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = compile_cache.enable_compile_cache()
+        assert first == compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.abspath(chip_smoke.__file__))
+        assert first == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_peak_flops_refuses_to_guess():
+    class Fake:
+        platform, device_kind = "tpu", "TPU v5x"
+
+    assert peak_flops_per_chip() is None        # cpu: MFU has no meaning
+    with pytest.raises(ValueError, match="TPU v5x"):
+        peak_flops_per_chip(Fake())
+    Fake.device_kind = "TPU v5 lite"
+    assert peak_flops_per_chip(Fake()) == 197e12
+
+
+@pytest.mark.parametrize("window,quantized", [(1, False), (3, False),
+                                              (1, True), (3, True)])
+def test_decode_kernel_compiles_under_mosaic(monkeypatch, window, quantized):
+    """GPT-2 124M widths (12 heads x 64, page 64) through libtpu's Mosaic
+    compiler for a described v5e — no chip needed. Interpret mode accepts
+    reshapes, transposes and block shapes the real compiler refuses."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("a chip run compiles the kernel for real")
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as e:   # no libtpu in this environment
+        pytest.skip(f"no TPU topology description available: {e}")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "tpu")
+    _support.pallas_mode.cache_clear()
+    on = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    b, heads, dh, ps, pps, n_pages = 8, 12, 64, 64, 16, 130
+    f = heads * dh
+    pool = arg((n_pages, ps, f), jnp.int8 if quantized else jnp.bfloat16)
+    scales = arg((n_pages, heads), jnp.float32) if quantized else None
+    try:
+        compiled = decode_attention._pallas.lower(
+            arg((b, window, heads, dh), jnp.bfloat16),
+            arg((b, window, f), jnp.bfloat16),
+            arg((b, window, f), jnp.bfloat16), pool, pool, scales, scales,
+            arg((b, pps), jnp.int32), arg((b,), jnp.int32),
+            group=1, sliding_window=None).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    assert "tpu_custom_call" in compiled.as_text()

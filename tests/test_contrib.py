@@ -156,6 +156,7 @@ class TestTransducer:
         np.testing.assert_allclose(np.asarray(loss), ref, rtol=1e-5,
                                    atol=1e-5)
 
+    @pytest.mark.slow
     def test_loss_grads_finite(self):
         from apex_tpu.contrib.transducer import transducer_loss
 
@@ -204,6 +205,7 @@ class TestMultiheadAttn:
                                    np.asarray(out_prefix),
                                    rtol=1e-4, atol=1e-5)
 
+    @pytest.mark.slow
     def test_encdec_and_norm_add(self):
         from apex_tpu.contrib.multihead_attn import EncdecMultiheadAttn
 
@@ -283,6 +285,7 @@ class TestGroupBN:
 
 
 class TestBottleneck:
+    @pytest.mark.slow
     def test_spatial_matches_unsharded(self):
         from apex_tpu.contrib.bottleneck import Bottleneck, SpatialBottleneck
 

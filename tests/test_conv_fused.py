@@ -171,6 +171,7 @@ class TestResNetFusedParity:
         jax.tree.map(np.testing.assert_allclose, new_s, state)
 
 
+@pytest.mark.slow   # opt-in-off path (ROADMAP D9); 1x1 op parity stays tier-1
 class TestConv3x3Parity:
     """Fused 3x3 kernel (full-image blocks, 9-tap shifted GEMMs) vs the
     XLA composition oracle — forward, stats, and all gradients including

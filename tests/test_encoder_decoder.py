@@ -168,6 +168,7 @@ class TestEncoderDecoderModel:
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 
+    @pytest.mark.slow
     def test_encoder_padding_mask_blocks_pads(self):
         model = self._model()
         params = model.init(jax.random.PRNGKey(0))

@@ -235,6 +235,7 @@ class TestFp8Dense:
         np.testing.assert_allclose(np.asarray(g), np.asarray(ref),
                                    rtol=0.1)
 
+    @pytest.mark.slow
     def test_backward_e5m2_rounding_applied(self):
         # the cotangent path must show e5m2 quantization effects (current
         # scaling): grads through fp8_dense differ from exact bf16 grads
@@ -286,6 +287,7 @@ class TestNativeFp8Dispatch:
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b)), st_n, st_q)
 
+    @pytest.mark.slow
     def test_gradient_parity_vs_unquantized(self):
         """The two backwards round in different places (native quantizes
         the cotangent BEFORE its GEMMs — the TE order; qdq rounds the

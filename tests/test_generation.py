@@ -267,6 +267,7 @@ class TestCacheForms:
             np.testing.assert_allclose(np.asarray(l_l), np.asarray(l_f),
                                        rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.slow
     def test_flat_cache_kv_lengths_masks_padding(self):
         """kv_lengths must mask pad slots on the FLAT path exactly as on
         the 4D path (the flat branch initially dropped it — r5 review)."""

@@ -111,7 +111,8 @@ def _copy_rnn_weights_to_torch(trnn, params, bidirectional=False):
 
 
 class TestRNN:
-    @pytest.mark.parametrize("bidirectional", [False, True])
+    @pytest.mark.parametrize("bidirectional", [
+        False, pytest.param(True, marks=pytest.mark.slow)])
     def test_lstm_matches_torch(self, bidirectional):
         T, B, I, H, L = 6, 3, 5, 7, 2
         model = LSTM(I, H, L, bias=True, bidirectional=bidirectional)
@@ -161,6 +162,7 @@ class TestRNN:
                                    ref_out.detach().numpy(),
                                    rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.slow
     def test_mlstm_shapes_and_grads(self):
         T, B, I, H = 4, 2, 3, 5
         model = mLSTM(I, H, 1, bias=True)

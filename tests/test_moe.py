@@ -181,6 +181,7 @@ class TestExpertParallel:
         np.testing.assert_allclose(float(aux[0]), float(aux_ref), rtol=1e-5)
         parallel_state.destroy_model_parallel()
 
+    @pytest.mark.slow
     def test_ep_top2_matches_dense(self):
         parallel_state.destroy_model_parallel()
         mesh = parallel_state.initialize_model_parallel()
@@ -323,6 +324,7 @@ class TestMoETransformer:
         assert all(np.isfinite(losses))
         assert losses[-1] < losses[0]
 
+    @pytest.mark.slow
     def test_moe_router_gets_gradient(self):
         model = self._model()
         params = model.init(jax.random.PRNGKey(0))
@@ -339,6 +341,7 @@ class TestMoETransformer:
         logits = model.apply(params, tokens)
         assert logits.shape == (8, 2, 64)
 
+    @pytest.mark.slow
     def test_moe_in_bert_adds_aux_to_lm_loss(self):
         """MoE composes with BERT (round 3): the pre-scaled aux joins the
         masked-LM loss, and router grads flow."""
@@ -362,6 +365,7 @@ class TestMoETransformer:
         router_g = g["transformer"]["layers"]["mlp"]["router"]
         assert float(jnp.sum(jnp.abs(router_g))) > 0
 
+    @pytest.mark.slow
     def test_moe_in_vit_returns_logits_and_aux(self):
         from apex_tpu.models import TransformerConfig, ViTConfig, ViTModel
 

@@ -289,7 +289,8 @@ def _pack_qkv(q, k, v, qpg, d):
 
 class TestPackedQKV:
     @pytest.mark.parametrize("causal", [False, True])
-    @pytest.mark.parametrize("g,qpg", [(4, 1), (2, 2), (1, 4)])
+    @pytest.mark.parametrize("g,qpg", [
+        pytest.param(4, 1, marks=pytest.mark.slow), (2, 2), (1, 4)])
     def test_fwd_bwd_matches_unpacked(self, causal, g, qpg):
         s, b, d = 128, 2, 64
         h = g * qpg
@@ -349,7 +350,8 @@ class TestPackedQKV:
             np.testing.assert_allclose(np.asarray(gp), np.asarray(gr),
                                        rtol=2e-3, atol=2e-3)
 
-    @pytest.mark.parametrize("s,causal", [(100, True), (197, False)])
+    @pytest.mark.parametrize("s,causal", [
+        (100, True), pytest.param(197, False, marks=pytest.mark.slow)])
     def test_ragged_s_pads_internally(self, s, causal):
         # ViT-class lengths (197 = 196 patches + CLS): rows pad to the
         # sublane multiple, padded keys masked via kv_lengths, padded
@@ -430,6 +432,7 @@ class TestFusedMultiblockBackward:
         v = _rand((2, 3, 256, 64), seed=33)
         self._grads(q, k, v, causal=causal)
 
+    @pytest.mark.slow
     def test_causal_dead_blocks_4x4(self):
         # nq = nk = 4: 6 of 16 blocks are causally dead — their steps
         # must pass dq through unchanged (a dropped write loses a j
@@ -439,6 +442,7 @@ class TestFusedMultiblockBackward:
         v = _rand((1, 2, 512, 64), seed=36)
         self._grads(q, k, v, causal=True)
 
+    @pytest.mark.slow
     def test_gqa_group_sweep(self):
         # grouped heads extend the inner t sweep; dk/dv scratch must
         # accumulate across the whole (g, i) walk before flushing
@@ -447,6 +451,7 @@ class TestFusedMultiblockBackward:
         v = _rand((2, 2, 256, 64), seed=39)
         self._grads(q, k, v, causal=True)
 
+    @pytest.mark.slow
     def test_varlen(self):
         q = _rand((2, 2, 256, 64), seed=40)
         k = _rand((2, 2, 256, 64), seed=41)
@@ -471,7 +476,8 @@ class TestPackedRope:
     path — forward and the un-rotated dqkv cotangent, full and partial
     rotary dims."""
 
-    @pytest.mark.parametrize("rot", [64, 32])
+    @pytest.mark.parametrize("rot", [
+        64, pytest.param(32, marks=pytest.mark.slow)])
     def test_rope_parity(self, rot):
         from apex_tpu.ops.rope import fused_rope
         s, b, g, qpg, d = 128, 2, 4, 1, 64

@@ -39,7 +39,8 @@ def ref_rms_norm(x, w, eps):
     return y
 
 
-@pytest.fixture(params=[(4, 8, 96), (2, 384)])
+@pytest.fixture(params=[
+    (4, 8, 96), pytest.param((2, 384), marks=pytest.mark.slow)])
 def shapes(request):
     return request.param
 

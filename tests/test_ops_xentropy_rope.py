@@ -4,6 +4,7 @@ and the fused_rope functional tests)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from apex_tpu.ops import (
     softmax_cross_entropy_loss,
@@ -87,6 +88,7 @@ def test_rope_cached():
     np.testing.assert_allclose(y, fused_rope(t, f), atol=1e-6)
 
 
+@pytest.mark.slow
 def test_rope_thd():
     d, h = 8, 2
     lens = [3, 5, 2]

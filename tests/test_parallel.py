@@ -217,6 +217,7 @@ class TestSpecAwareGradSync:
         parallel_state.destroy_model_parallel()
 
 
+@pytest.mark.slow
 def test_syncbn_unequal_per_rank_batches(data_mesh):
     """Count-weighted merge with unequal REAL batch sizes per rank
     (reference ``tests/distributed/synced_batchnorm/
@@ -257,6 +258,7 @@ def test_syncbn_unequal_per_rank_batches(data_mesh):
                                real.var(axis=0, ddof=1), atol=1e-4)
 
 
+@pytest.mark.slow
 def test_syncbn_unequal_batches_grads(data_mesh):
     """Gradients through the count-weighted masked SyncBN match the
     reference computation on only-the-real rows (the grad-parity half of
@@ -393,6 +395,7 @@ def test_syncbn_mask_robust_to_garbage_padding():
     assert np.isfinite(np.asarray(new_s3["var"])).all()
 
 
+@pytest.mark.slow
 def test_convert_syncbn_model(data_mesh):
     """The functional convert_syncbn_model analog (reference
     apex/parallel/__init__.py:21-77): flax BatchNorm modules in the

@@ -796,6 +796,7 @@ class TestEnginePreemptResume:
                  and r.get("span") in ("preempt", "resume")]
         assert {m["trace_id"] for m in marks} == {victim.trace_id}
 
+    @pytest.mark.slow
     def test_sampled_resume_token_exact(self, small):
         model, params = small
         cfg = self._cfg()

@@ -228,7 +228,10 @@ class TestActivations:
     the gelu-only reference ParallelMLP). Gated runs one fused 2*ffn
     column projection with gate/up unit-interleaved."""
 
-    @pytest.mark.parametrize("act", ["gelu", "relu", "swiglu", "geglu"])
+    @pytest.mark.parametrize("act", [
+        "gelu", "swiglu",
+        pytest.param("relu", marks=pytest.mark.slow),
+        pytest.param("geglu", marks=pytest.mark.slow)])
     def test_trains(self, act):
         model = GPTModel(_cfg(activation=act,
                               position_embedding_type="learned"))
@@ -374,6 +377,7 @@ class TestSlidingWindowModel:
         _cfg(sliding_window=4, context_parallel_method="ring")
 
 
+@pytest.mark.slow
 def test_sliding_window_with_dropout_trains_windowed():
     """Regression for the dropped-mask bug: with attention dropout active
     (unfused softmax path) the window must still bind — rows beyond the

@@ -217,42 +217,26 @@ def _single(fn, case, group, sliding_window=None):
 
 
 class TestFusedKernelParity:
-    def test_interpret_matches_reference(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "interpret")
-        _support.pallas_mode.cache_clear()
-        try:
-            case = _rand_paged_case(0)
-            ctx_k, kk, vk = _single(_pallas, case, group=2)
-            ctx_r, kr, vr = _single(_reference, case, group=2)
-            np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
-            # the append is the same scatter on both paths: exact
-            np.testing.assert_array_equal(kk, kr)
-            np.testing.assert_array_equal(vk, vr)
-        finally:
-            _support.pallas_mode.cache_clear()
+    def test_interpret_matches_reference(self, pallas_kernels):
+        case = _rand_paged_case(0)
+        ctx_k, kk, vk = _single(_pallas, case, group=2)
+        ctx_r, kr, vr = _single(_reference, case, group=2)
+        np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
+        # the append is the same scatter on both paths: exact
+        np.testing.assert_array_equal(kk, kr)
+        np.testing.assert_array_equal(vk, vr)
 
-    def test_interpret_sliding_window(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "interpret")
-        _support.pallas_mode.cache_clear()
-        try:
-            case = _rand_paged_case(1)
-            ctx_k, _, _ = _single(_pallas, case, group=2, sliding_window=5)
-            ctx_r, _, _ = _single(_reference, case, group=2,
-                                  sliding_window=5)
-            np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
-        finally:
-            _support.pallas_mode.cache_clear()
+    def test_interpret_sliding_window(self, pallas_kernels):
+        case = _rand_paged_case(1)
+        ctx_k, _, _ = _single(_pallas, case, group=2, sliding_window=5)
+        ctx_r, _, _ = _single(_reference, case, group=2, sliding_window=5)
+        np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
 
-    def test_interpret_mha_group_one(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "interpret")
-        _support.pallas_mode.cache_clear()
-        try:
-            case = _rand_paged_case(2, kvh=4, group=1)
-            ctx_k, _, _ = _single(_pallas, case, group=1)
-            ctx_r, _, _ = _single(_reference, case, group=1)
-            np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
-        finally:
-            _support.pallas_mode.cache_clear()
+    def test_interpret_mha_group_one(self, pallas_kernels):
+        case = _rand_paged_case(2, kvh=4, group=1)
+        ctx_k, _, _ = _single(_pallas, case, group=1)
+        ctx_r, _, _ = _single(_reference, case, group=1)
+        np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
 
     def test_cpu_dispatch_is_reference(self):
         """With pallas off (the CPU default) the public entry point IS

@@ -183,6 +183,7 @@ class TestWindowedOp:
         np.testing.assert_array_equal(np.asarray(kw), np.asarray(kp_s))
         np.testing.assert_array_equal(np.asarray(vw), np.asarray(vp_s))
 
+    @pytest.mark.slow
     def test_quantized_reference_close_to_float(self):
         """int8 pools with per-page scales reproduce the float context
         to quantization tolerance (the dequantize-inside-the-op
@@ -197,23 +198,24 @@ class TestWindowedOp:
         np.testing.assert_allclose(np.asarray(ctx_q), np.asarray(ctx_f),
                                    atol=0.08, rtol=0.1)
 
-    def test_interpret_kernel_quantized_matches_reference(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "interpret")
-        _support.pallas_mode.cache_clear()
-        try:
-            q, k_new, v_new, kp, vp, pt, pos = _window_case(2)
-            k_q, k_s, v_q, v_s = _quantize_pools(kp, vp)
-            out_k = _pallas(q, k_new, v_new, k_q, v_q, k_s, v_s, pt, pos,
-                            group=2, sliding_window=None)
-            out_r = _reference(q, k_new, v_new, k_q, v_q, k_s, v_s, pt,
-                               pos, group=2, sliding_window=None)
-            np.testing.assert_allclose(np.asarray(out_k[0]),
-                                       np.asarray(out_r[0]),
-                                       atol=2e-5, rtol=2e-5)
-            for a, b in zip(out_k[1:], out_r[1:]):   # pools + scales
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        finally:
-            _support.pallas_mode.cache_clear()
+    def test_interpret_kernel_quantized_matches_reference(self,
+                                                          pallas_kernels):
+        q, k_new, v_new, kp, vp, pt, pos = _window_case(2)
+        k_q, k_s, v_q, v_s = _quantize_pools(kp, vp)
+        out_k = _pallas(q, k_new, v_new, k_q, v_q, k_s, v_s, pt, pos,
+                        group=2, sliding_window=None)
+        # jitted like _pallas: the rescale-on-append is bitwise the same
+        # on both sides only when both are compiled programs (op-by-op
+        # eager dispatch lands one scale an ulp away from the fused one)
+        out_r = jax.jit(_reference, static_argnames=(
+            "group", "sliding_window"))(
+                q, k_new, v_new, k_q, v_q, k_s, v_s, pt, pos,
+                group=2, sliding_window=None)
+        np.testing.assert_allclose(np.asarray(out_k[0]),
+                                   np.asarray(out_r[0]),
+                                   atol=2e-5, rtol=2e-5)
+        for a, b in zip(out_k[1:], out_r[1:]):   # pools + scales
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_scale_grows_monotonically_and_rescales_residents(self):
         """Rescale-on-append: a page's scale only ever grows; resident

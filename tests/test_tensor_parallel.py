@@ -303,7 +303,8 @@ class TestVocabParallelCrossEntropy:
             return (1 - s) * nll - s * logp.mean(axis=-1)
         return nll
 
-    @pytest.mark.parametrize("smoothing", [0.0, 0.1])
+    @pytest.mark.parametrize("smoothing", [
+        0.0, pytest.param(0.1, marks=pytest.mark.slow)])
     def test_matches_full_softmax(self, tp8_mesh, smoothing):
         logits = jax.random.normal(jax.random.PRNGKey(0), (4, 6, 64))
         target = jax.random.randint(jax.random.PRNGKey(1), (4, 6), 0, 64)
@@ -314,7 +315,8 @@ class TestVocabParallelCrossEntropy:
             tp8_mesh, (P(None, None, TENSOR), P()), P())(logits, target)
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.parametrize("smoothing", [0.0, 0.1])
+    @pytest.mark.parametrize("smoothing", [
+        0.0, pytest.param(0.1, marks=pytest.mark.slow)])
     def test_grads_match(self, tp8_mesh, smoothing):
         logits = jax.random.normal(jax.random.PRNGKey(0), (4, 6, 64))
         target = jax.random.randint(jax.random.PRNGKey(1), (4, 6), 0, 64)
@@ -410,6 +412,7 @@ class TestZLoss:
                                    np.asarray(self._ref(logits, target, 1e-2)),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.slow
     def test_grads_match_autodiff_reference(self):
         from apex_tpu.transformer.tensor_parallel import (
             vocab_parallel_cross_entropy,
@@ -434,6 +437,7 @@ class TestZLoss:
         b = vocab_parallel_cross_entropy(logits, target, z_loss=0.0)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.slow
     def test_with_label_smoothing_grads_consistent(self):
         """Regression: z-loss must be added AFTER the smoothing rescale so
         the custom vjp matches autodiff of the returned value."""

@@ -54,6 +54,7 @@ class TestResNet:
         # eval does not touch stats
         jax.tree.map(np.testing.assert_allclose, s1, state)
 
+    @pytest.mark.slow
     def test_bf16_compute(self):
         model = ResNet(ResNetConfig(depth=18, num_classes=4,
                                     compute_dtype=jnp.bfloat16))
@@ -133,6 +134,7 @@ class TestDCGAN:
         assert img.shape == (4, 64, 64, 3)
         assert float(jnp.max(jnp.abs(img))) <= 1.0
 
+    @pytest.mark.slow
     def test_discriminator_shapes(self):
         cfg = DCGANConfig(latent_dim=32, gen_features=16, disc_features=16)
         disc = Discriminator(cfg)
@@ -142,6 +144,7 @@ class TestDCGAN:
             lambda p, s, x: disc.apply(p, s, x, train=True))(params, state, x)
         assert logit.shape == (4,)
 
+    @pytest.mark.slow
     def test_adversarial_step(self):
         """One G/D update each with separate optimizers — the multi-model,
         multi-optimizer capability of examples/dcgan/main_amp.py."""

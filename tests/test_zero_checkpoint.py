@@ -203,6 +203,7 @@ class TestGatherDtypeAndRemainders:
         mixed = dict(bf16, w1=_params()["w1"])
         assert opt._resolve_gather_dtype(mixed) == jnp.float32
 
+    @pytest.mark.slow
     def test_store_param_remainders_matches_master_mode(self):
         """(bf16 image + int16 remainder) storage follows the fp32-master
         trajectory; differences are bounded by round-half-up vs
