@@ -26,6 +26,14 @@ when available, falling back to tracking distinct abstract signatures
 ``resilience.run_training`` wraps its ``step_fn`` automatically (config
 ``retrace_budget``), so a storm surfaces as a watchdog event instead of a
 silent slowdown.
+
+While compilations are still expected (``compiles <
+expected_compiles``) a call may trace, and it is made from a frame with
+room on the interpreter's stack (:func:`_call_with_stack_room`): tracing
+a step program is a second of deep, hot Python recursion, and where that
+recursion happens to straddle the end of a 16 KiB block of CPython's
+frame stack every crossing costs an ``mmap``, a page fault and a
+``munmap``. Once the expected programs are compiled the call is direct.
 """
 
 from __future__ import annotations
@@ -46,6 +54,32 @@ class RetraceBudgetExceeded(RuntimeError):
         self.name = name
         self.retraces = retraces
         self.budget = budget
+
+
+def _call_with_stack_room(fn, args, kwargs):
+    """``fn(*args, **kwargs)`` from a frame that leaves the frames below
+    it 256 KiB of contiguous interpreter stack.
+
+    CPython (3.11+) keeps Python frames in 16 KiB blocks, maps a new
+    block when a call does not fit into the current one and unmaps it as
+    soon as the call returns. A loop whose callees fall just beyond the
+    end of a block therefore pays two system calls and a page fault per
+    iteration (7.6 us against 42 ns for an empty call, measured on
+    Python 3.12), and jax's tracing is such a loop at every depth: where
+    the block ends is decided by the sizes of all the frames above the
+    jitted call, so an unrelated edit or another launcher moves it.
+    Lowering the serving engine's six prefill programs for a v5e took
+    162,000-216,000 page faults without this frame and 27,000-31,000
+    with it (PERF.md, PR 26). A frame larger than a block gets a block
+    of its own, twice its size, and everything it calls lives in the
+    rest of that block.
+    """
+    return fn(*args, **kwargs)
+
+
+# 32,768 slots x 8 bytes: the frame gets a 512 KiB block, half of it free
+_call_with_stack_room.__code__ = _call_with_stack_room.__code__.replace(
+    co_stacksize=32768)
 
 
 def _abstract_signature(args: Tuple[Any, ...], kwargs: dict) -> Tuple:
@@ -119,7 +153,10 @@ class RetraceWatchdog:
         return max(0, self.compiles - self.expected_compiles)
 
     def __call__(self, *args, **kwargs):
-        out = self._fn(*args, **kwargs)
+        if self.compiles < self.expected_compiles:
+            out = _call_with_stack_room(self._fn, args, kwargs)
+        else:
+            out = self._fn(*args, **kwargs)
         self.calls += 1
         self._observe(args, kwargs)
         return out

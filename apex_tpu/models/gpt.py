@@ -24,6 +24,7 @@ from apex_tpu.models.transformer import (
     position_table_params,
     position_table_spec,
 )
+from apex_tpu.observability.tracing import SCOPE_LM_HEAD_LOSS
 from apex_tpu.transformer.tensor_parallel.cross_entropy import (
     vocab_parallel_cross_entropy,
 )
@@ -31,9 +32,11 @@ from apex_tpu.transformer.tensor_parallel.layers import (
     VocabParallelEmbedding,
     linear_with_grad_accumulation_and_async_allreduce,
 )
+from apex_tpu.utils.profiling import nvtx_range
 __all__ = ["GPTModel", "lm_head_loss"]
 
 
+@nvtx_range(SCOPE_LM_HEAD_LOSS)
 def lm_head_loss(embedding_weight, hidden, labels, loss_mask, config):
     """Weight-tied LM head + vocab-parallel loss tail shared by
     :class:`GPTModel` and :class:`~apex_tpu.models.pipelined.PipelinedGPT`.

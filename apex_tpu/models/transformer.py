@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
+from apex_tpu.observability.tracing import SCOPE_ATTENTION, SCOPE_MLP
 from apex_tpu.ops import (
     flash_attention,
     flash_attention_packed,
@@ -54,6 +55,7 @@ from apex_tpu.transformer.tensor_parallel.mappings import (
 )
 from apex_tpu.transformer.tensor_parallel.random import model_parallel_rng_key
 from apex_tpu.transformer.tensor_parallel.utils import divide
+from apex_tpu.utils.profiling import nvtx_range
 from apex_tpu.utils.activations import (
     apply_activation,
     is_gated,
@@ -684,6 +686,7 @@ class ParallelAttention:
         out = self.dense.apply(params["dense"], ctx)
         return out, (ck, cv)
 
+    @nvtx_range(SCOPE_ATTENTION)
     def apply(self, params, hidden, *, encoder_output=None,
               attention_mask=None, kv_lengths=None, kv_cache=None,
               cache_index=None, rng=None, deterministic=True,
@@ -1067,14 +1070,17 @@ class ParallelTransformerLayer:
             # in T), below it the one-shot capacity dispatch.
             if moe_drop_free is None:
                 moe_drop_free = kv_cache is not None
-            mlp_out, aux = self.mlp.apply(
-                params["mlp"], x.astype(c.compute_dtype),
-                rng=moe_rng, deterministic=deterministic,
-                drop_free=moe_drop_free)
+            with nvtx_range(SCOPE_MLP):
+                mlp_out, aux = self.mlp.apply(
+                    params["mlp"], x.astype(c.compute_dtype),
+                    rng=moe_rng, deterministic=deterministic,
+                    drop_free=moe_drop_free)
         else:
-            mlp_out = self.mlp.apply(
-                params["mlp"], x.astype(c.compute_dtype),
-                lora=None if lora is None else lora.get("dense_h_to_4h"))
+            with nvtx_range(SCOPE_MLP):
+                mlp_out = self.mlp.apply(
+                    params["mlp"], x.astype(c.compute_dtype),
+                    lora=None if lora is None
+                    else lora.get("dense_h_to_4h"))
             aux = None
         mlp_out = _dropout(mlp_out, c.hidden_dropout, rngs[1], deterministic,
                            model_parallel_region=c.sequence_parallel,

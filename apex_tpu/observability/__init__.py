@@ -14,9 +14,12 @@ subsystem of a pre-training stack; this package is that subsystem here.
   :mod:`apex_tpu.utils.flops`), plus device ``memory_stats`` gauges.
   ``ResilienceConfig(metrics=registry)`` wires the whole layer into
   :func:`apex_tpu.resilience.run_training`.
-- :func:`span` / :class:`ProfilerCapture` — named scopes that also
-  record host durations, and windowed ``jax.profiler`` captures
-  (every-N-steps or on watchdog incident).
+- :func:`span` / :class:`ProfilerCapture` — host spans written into
+  the profiler's trace on the device clock (the serving tick's
+  ``tick.*`` spans; optionally timed into a registry histogram), and
+  windowed ``jax.profiler`` captures (every-N-steps or on watchdog
+  incident). :mod:`~apex_tpu.observability.tracing` also holds the
+  span and scope names as constants.
 - :func:`build_report` / :func:`render_report` — fold a run's JSONL log
   into the report ``python -m apex_tpu.monitor`` prints.
 - :class:`SLOSpec` / :func:`evaluate_slos`

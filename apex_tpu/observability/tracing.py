@@ -1,35 +1,102 @@
 """Span tracing + on-demand profiler capture.
 
-Two pieces on top of :mod:`apex_tpu.utils.profiling`:
+The program's two ways of putting a name on work, and the names:
 
-- :func:`span` — a named scope that *also* records its host-side wall
-  duration into a registry histogram (``span/<name>_s``). The scope name
-  still lands in XLA HLO metadata (it is ``jax.named_scope`` underneath),
-  so one annotation shows up both in the profiler timeline and in the
-  run's own metrics.
+- :func:`span` — the one HOST-span primitive: a
+  ``jax.profiler.TraceAnnotation``. While any profiler session is on
+  (a ``ProfilerCapture`` window, ``jax.profiler.start_trace``, a
+  benchmark's traced run) the span is written into the profiler's
+  trace, on the clock of the device ops, with its keyword attributes as
+  the event's stats; with no session on it costs a flag test. Given a
+  ``registry`` it also observes its host wall time into the
+  ``span/<name>_s`` histogram. A host span names host work only: it puts
+  nothing into a compiled program.
+- names INSIDE jitted code are :func:`~apex_tpu.utils.profiling.
+  nvtx_range` (``jax.named_scope``): the scope becomes a path element of
+  every enclosed instruction's ``op_name``, which the trace file carries
+  in each program's HLO proto.
 - :class:`ProfilerCapture` — windowed ``jax.profiler`` trace capture the
   resilience driver can drive: start every N steps and stop
   ``capture_steps`` later, and/or start on a watchdog incident — so when
   a run goes sideways there is a trace of the bad window without having
-  profiled the whole run.
+  profiled the whole run. Around supervisor ticks it is how an operator
+  gets the ``tick.*`` spans below into a trace (docs/observability.md).
+
+The names are constants here, imported by the program and quoted by the
+benchmark's metric files (``cellbench/metrics/*.json``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
+import time
 from typing import Callable, Optional
 
+import jax
+
 from apex_tpu.utils.logging import get_logger, log_event
-from apex_tpu.utils.profiling import nvtx_range, profiler_start, profiler_stop
+from apex_tpu.utils.profiling import profiler_start, profiler_stop
 
-__all__ = ["span", "ProfilerCapture"]
+__all__ = ["span", "ProfilerCapture", "TICK_LEAF_SPANS"]
+
+# -- host spans of the serving tick (serving/engine.py, supervisor.py) ------
+# grouping spans
+TICK_SUPERVISOR = "supervisor.tick"  # all of EngineSupervisor.tick
+TICK_ENGINE = "engine.tick"          # all of InferenceEngine.tick
+TICK_PREFILL = "tick.prefill"        # one prefill or chunk: trace_id,
+#                                      prompt_tokens, bucket (chunk)
+# leaf spans: disjoint, each around one step of the tick; the counts that
+# ride on them as attributes follow the colon
+TICK_SCHEDULE = "tick.schedule"      # expire, evict, preempt, pop the
+#                                      queue, map and extend pages, draft
+#                                      windows: queued, active, pages_mapped
+TICK_UPLOAD = "tick.upload"          # host arrays -> device: arrays, bytes
+TICK_DISPATCH = "tick.dispatch"      # the jitted call, until it returns:
+#                                      program, rows
+TICK_READBACK = "tick.readback"      # blocking np.asarray: reads, bytes
+TICK_COMMIT = "tick.commit"          # tokens into records, retirements,
+#                                      gauges, the supervisor's harvest:
+#                                      tokens, retired
+TICK_LEAF_SPANS = (TICK_SCHEDULE, TICK_UPLOAD, TICK_DISPATCH, TICK_READBACK,
+                   TICK_COMMIT)
+
+# -- scope names inside the step programs (jax.named_scope) -----------------
+SCOPE_PAGED_DECODE = "paged_decode_attention"   # ops/decode_attention.py
+SCOPE_FLASH_FWD = "flash_attention_fwd"         # ops/attention.py
+SCOPE_FLASH_BWD = "flash_attention_bwd"
+SCOPE_LAYER_NORM = "layer_norm"                 # ops/layer_norm.py
+SCOPE_SAMPLE = "sample"                         # serving/engine.py
+SCOPE_ATTENTION = "attention"                   # models/transformer.py
+SCOPE_MLP = "mlp"
+SCOPE_LM_HEAD_LOSS = "lm_head_loss"
+SCOPE_OPTIMIZER = "optimizer"                   # resilience.py: the update
+SCOPE_LOSS_SCALE = "loss_scale"                 # unscale + finite check
+SCOPE_TP_ALL_REDUCE = "tp_all_reduce"           # tensor_parallel/mappings.py
+SCOPE_DP_GRAD_ALL_REDUCE = "dp_grad_all_reduce"  # resilience.py
 
 
-def span(name: str, registry):
-    """``with span("fwd", reg):`` — :func:`~apex_tpu.utils.profiling.
-    nvtx_range` with the registry wired in: the enclosed host wall time
-    is observed into the ``span/<name>_s`` histogram."""
-    return nvtx_range(name, registry=registry)
+@contextlib.contextmanager
+def _observed(annotation, name: str, registry):
+    t0 = time.perf_counter()
+    try:
+        with annotation:
+            yield annotation
+    finally:
+        # host-side wall duration: dispatch time, not device time
+        registry.observe(f"span/{name}_s", time.perf_counter() - t0)
+
+
+def span(name: str, registry=None, **attrs):
+    """``with span("tick.upload", arrays=7, bytes=n) as s:`` — a host
+    span in the profiler's trace (see the module docstring). ``s.
+    set_metadata(k=v)`` adds attributes known only at the end of the
+    span. With ``registry`` the enclosed host wall time is also observed
+    into the ``span/<name>_s`` histogram."""
+    annotation = jax.profiler.TraceAnnotation(name, **attrs)
+    if registry is None:
+        return annotation
+    return _observed(annotation, name, registry)
 
 
 class ProfilerCapture:

@@ -36,7 +36,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.tracing import SCOPE_FLASH_BWD, SCOPE_FLASH_FWD
 from apex_tpu.ops._support import pallas_interpret, round_up, use_pallas
+from apex_tpu.utils.profiling import nvtx_range
 
 __all__ = ["flash_attention", "flash_attention_packed",
            "packed_attention_supported", "flash_chunk_fwd",
@@ -245,6 +247,7 @@ def _run_fwd_single(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
+        name="flash_attention_fwd_single",
     )(*args, q, k, v)
     return o, lse[:, :, 0, :]
 
@@ -400,6 +403,7 @@ def _run_fwd(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_attention_fwd",
     )(*args, q, k, v)
     return o, lse[:, :, 0, :]
 
@@ -661,6 +665,7 @@ def _run_bwd_fused(q, k, v, do, lse, delta, kv_lengths, scale, causal,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_fused",
     )(*args, dq_zero, q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
 
@@ -1001,6 +1006,7 @@ def _run_fwd_packed(qkv2, kv_lengths, rope, drop, *, scale, s, batch, W,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
+        name="flash_attention_fwd_packed",
     )(*args, qkv2)
     return o, lse
 
@@ -1045,6 +1051,7 @@ def _run_bwd_packed(qkv2, do2, o2, lse, kv_lengths, rope, drop, *, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_packed",
     )(*args, qkv2, do2, o2, lse)
 
 
@@ -1106,6 +1113,7 @@ def _drop_tuple(seed, rate):
     return None if rate == 0.0 else (seed, rate)
 
 
+@nvtx_range(SCOPE_FLASH_FWD)
 def _flash_packed_fwd_impl(qkv, kv_lengths, rope_cos, rope_sin, seed,
                            scale, causal, window, qpg, d, rot, rate):
     s, b, W, g, geom, heads = _packed_geom_of(qkv, qpg, d)
@@ -1126,6 +1134,7 @@ def _flash_packed_vjp_fwd(qkv, kv_lengths, rope_cos, rope_sin, seed, scale,
     return o, (qkv, kv_lengths, rope_cos, rope_sin, seed, o, lse)
 
 
+@nvtx_range(SCOPE_FLASH_BWD)
 def _flash_packed_vjp_bwd(scale, causal, window, qpg, d, rot, rate, res,
                           do):
     qkv, kv_lengths, rope_cos, rope_sin, seed, o, lse = res
@@ -1321,6 +1330,7 @@ def _run_bwd_single(q, k, v, do, lse, delta, kv_lengths, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_single",
     )(*args, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1400,6 +1410,7 @@ def _run_bwd(q, k, v, do, lse, delta, kv_lengths, scale, causal,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_dq",
     )(*args, q, k, v, do, lse, delta)
 
     # trailing grid dim walks (q head in group, q block) pairs:
@@ -1437,6 +1448,7 @@ def _run_bwd(q, k, v, do, lse, delta, kv_lengths, scale, causal,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_dkv",
     )(*args, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1466,6 +1478,7 @@ def _flash(q, k, v, kv_lengths, scale, causal, bq, bk, window):
     return o
 
 
+@nvtx_range(SCOPE_FLASH_FWD)
 def _flash_fwd_impl(q, k, v, kv_lengths, scale, causal, bq, bk, window):
     sq, d = q.shape[2], q.shape[3]
     sk = k.shape[2]
@@ -1482,6 +1495,7 @@ def _flash_vjp_fwd(q, k, v, kv_lengths, scale, causal, bq, bk, window):
     return o, (q, k, v, kv_lengths, o, lse)
 
 
+@nvtx_range(SCOPE_FLASH_BWD)
 def _flash_vjp_bwd(scale, causal, bq, bk, window, res, do):
     q, k, v, kv_lengths, o, lse = res
     sq, d = q.shape[2], q.shape[3]
@@ -1582,6 +1596,7 @@ def _chunk_reference_bwd(q, k, v, do, lse, delta, kv_lengths, scale,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+@nvtx_range(SCOPE_FLASH_FWD)
 def flash_chunk_fwd(q, k, v, *, q_start, k_start, causal=False, window=None,
                     kv_lengths=None, softmax_scale=None,
                     block_q: Optional[int] = None,
@@ -1612,6 +1627,7 @@ def flash_chunk_fwd(q, k, v, *, q_start, k_start, causal=False, window=None,
     return o[:, :, :sq, :d], lse[:, :, :sq]
 
 
+@nvtx_range(SCOPE_FLASH_BWD)
 def flash_chunk_bwd(q, k, v, do, lse, delta, *, q_start, k_start,
                     causal=False, window=None, kv_lengths=None,
                     softmax_scale=None,

@@ -73,7 +73,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.tracing import SCOPE_PAGED_DECODE
 from apex_tpu.ops._support import cdiv, pallas_interpret, use_pallas
+from apex_tpu.utils.profiling import nvtx_range
 
 __all__ = ["fused_paged_decode_attention", "paged_pages_for",
            "paged_quant_fill", "paged_quant_scatter"]
@@ -444,6 +446,7 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, m, f), q.dtype),
         interpret=pallas_interpret(),
+        name="paged_decode_attention",
     )(*inputs)
     # each query keeps its own K/V head's lane block
     sel = (jnp.arange(kvh)[None, :]
@@ -521,9 +524,10 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
             f"scales must be [n_pages, kv_heads] = "
             f"({k_pages.shape[0]}, {kvh}), got {k_scales.shape}")
     fn = _pallas if use_pallas() else _reference
-    ctx, k_pages, v_pages, k_scales, v_scales = fn(
-        q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
-        page_table, positions, queries_per_group, sliding_window)
+    with nvtx_range(SCOPE_PAGED_DECODE):
+        ctx, k_pages, v_pages, k_scales, v_scales = fn(
+            q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
+            page_table, positions, queries_per_group, sliding_window)
     if squeeze:
         ctx = ctx[:, 0]
     if k_scales is None:

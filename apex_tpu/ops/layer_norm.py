@@ -25,7 +25,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.observability.tracing import SCOPE_LAYER_NORM
 from apex_tpu.ops._support import block_rows, cdiv, min_sublane, pallas_interpret, round_up, use_pallas
+from apex_tpu.utils.profiling import nvtx_range
 
 _VMEM_BUDGET = 4 * 1024 * 1024  # per-operand block budget, bytes
 
@@ -120,6 +122,7 @@ def _fwd_pallas(x2, w, b, h, eps, is_rms, out_dtype):
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="layer_norm_fwd",
     )(*args)
     if hp != h:
         y = y[:, :h]
@@ -243,6 +246,7 @@ def _bwd_pallas(dy2, x2, mean, invvar, w, h, is_rms, has_bias):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=pallas_interpret(),
+        name="layer_norm_bwd",
     )(*args)
     dx = outs[0][:, :h]
     dw = db = None
@@ -281,6 +285,7 @@ def _norm(x, weight, bias, normalized_shape, eps, is_rms, memory_efficient,
     return y
 
 
+@nvtx_range(SCOPE_LAYER_NORM)
 def _norm_fwd_impl(x, weight, bias, normalized_shape, eps, is_rms,
                    out_dtype=None):
     m, h, _ = _norm_shapes(x, normalized_shape)
@@ -313,6 +318,7 @@ def _norm_vjp_fwd(x, weight, bias, normalized_shape, eps, is_rms,
     return y, (x, y, mean, invvar, weight, bias, x_dtype_marker)
 
 
+@nvtx_range(SCOPE_LAYER_NORM)
 def _norm_vjp_bwd(normalized_shape, eps, is_rms, memory_efficient,
                   out_dtype, res, dy):
     x_dtype = res[-1].dtype

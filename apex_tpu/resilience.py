@@ -73,9 +73,15 @@ from apex_tpu.checkpoint import (
     ShardedCheckpointManager,
 )
 from apex_tpu.observability.step_metrics import StepMetrics
+from apex_tpu.observability.tracing import (
+    SCOPE_DP_GRAD_ALL_REDUCE,
+    SCOPE_LOSS_SCALE,
+    SCOPE_OPTIMIZER,
+)
 from apex_tpu.training import sync_data_parallel_grads
 from apex_tpu.transformer.parallel_state import DATA_AXIS
 from apex_tpu.utils.logging import get_logger, log_event
+from apex_tpu.utils.profiling import nvtx_range
 from apex_tpu.utils.tree import global_norm
 
 __all__ = [
@@ -357,21 +363,25 @@ def make_resilient_train_step(
 
         grads, loss = jax.grad(fwd, has_aux=True)(params)
         if mesh is not None:
-            grads = sync_data_parallel_grads(grads, grad_sync_axes,
-                                             param_spec)
-            loss = sync_data_parallel_grads(loss, data_axes)
-        if sstate is not None:
-            grads, found_inf = scaler.unscale(grads, sstate)
-        else:
-            found_inf = jnp.logical_not(all_finite(grads))
+            with nvtx_range(SCOPE_DP_GRAD_ALL_REDUCE):
+                grads = sync_data_parallel_grads(grads, grad_sync_axes,
+                                                 param_spec)
+                loss = sync_data_parallel_grads(loss, data_axes)
+        with nvtx_range(SCOPE_LOSS_SCALE):
+            if sstate is not None:
+                grads, found_inf = scaler.unscale(grads, sstate)
+            else:
+                found_inf = jnp.logical_not(all_finite(grads))
         gnorm = global_norm(grads)
-        new_params, new_opt = optimizer.step(grads, params, opt_state,
-                                             found_inf=found_inf)
+        with nvtx_range(SCOPE_OPTIMIZER):
+            new_params, new_opt = optimizer.step(grads, params, opt_state,
+                                                 found_inf=found_inf)
         new_state = {"params": new_params, "opt_state": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss, "grad_norm": gnorm, "skipped": found_inf}
         if sstate is not None:
-            new_sstate = scaler.update(sstate, found_inf)
+            with nvtx_range(SCOPE_LOSS_SCALE):
+                new_sstate = scaler.update(sstate, found_inf)
             new_state["scaler"] = new_sstate
             metrics["loss_scale"] = new_sstate.loss_scale
         return new_state, metrics

@@ -98,6 +98,17 @@ from apex_tpu.serving.prefix import (
     prefix_hash_chain,
     prefix_salt,
 )
+from apex_tpu.observability.tracing import (
+    SCOPE_SAMPLE,
+    TICK_COMMIT,
+    TICK_DISPATCH,
+    TICK_ENGINE,
+    TICK_PREFILL,
+    TICK_READBACK,
+    TICK_SCHEDULE,
+    TICK_UPLOAD,
+    span,
+)
 from apex_tpu.serving.scheduler import (
     DeadlineExpiredError,
     FCFSScheduler,
@@ -109,6 +120,7 @@ from apex_tpu.serving.scheduler import (
 from apex_tpu.serving.slots import PagePool, SlotPool
 from apex_tpu.serving.speculation import propose_draft
 from apex_tpu.utils.logging import get_logger, log_event
+from apex_tpu.utils.profiling import nvtx_range
 
 __all__ = ["EngineConfig", "InferenceEngine"]
 
@@ -319,6 +331,7 @@ class _Active:
         self.finite_ok = True    # AND of every chunk's isfinite flag
 
 
+@nvtx_range(SCOPE_SAMPLE)
 def _sample_tokens(logits, temps, topks, seeds, steps):
     """Per-row sampling over ``logits`` [n, V]: greedy where
     ``temps == 0``, else softmax at the row's temperature truncated to
@@ -497,6 +510,14 @@ class InferenceEngine:
         if self._spec:
             self._window_h = np.zeros((n, self._spec), np.int32)
             self._wlen_h = np.ones(n, np.int32)
+        #: what one decode step uploads (the ``tick.upload`` span's
+        #: attributes): the host arrays of ``_decode_args``
+        up = [self._window_h if self._spec else self._tokens_h,
+              self._positions_h, self._temps_h, self._topks_h,
+              self._seeds_h, self._adapter_ix_h]
+        if self.pages is not None:
+            up.append(self._page_table_h)
+        self._decode_upload = (len(up), sum(a.nbytes for a in up))
 
         donate = self.config.donate_caches
         if donate is None:
@@ -1093,32 +1114,38 @@ class InferenceEngine:
         if self._closed:
             raise RuntimeError("engine is closed")
         finished: List[RequestResult] = []
-        now = clock.now()
-        self._expire(now, finished)
-        self._evict_cancelled(finished)
-        self._maybe_preempt(now)
-        self._chunk_tokens_tick = 0
-        if self.config.prefill_token_budget is None:
-            self._admit(finished)
-        else:
-            self._chunked_admit(finished)
-        if self._chunk_tokens_tick:
-            # one observation per tick with prefill activity — the
-            # histogram's sum is the total chunked prefill tokens, its
-            # max must never exceed prefill_token_budget
-            self.metrics.observe("prefill_tokens_per_tick",
-                                 self._chunk_tokens_tick)
-        self._decode_tick(finished)
-        self.metrics.observe("slot_occupancy", self.slots.occupancy)
-        if self.pages is not None:
-            self.metrics.set_gauge("kv_pages_in_use",
-                                   self.pages.in_use_count)
-            self.metrics.set_gauge("kv_pages_free", self.pages.free_count)
-            self.metrics.observe("kv_page_occupancy", self.pages.occupancy)
-            delta = self.pages.evictions - self._evictions_seen
-            if delta:
-                self.metrics.inc("prefix_evictions", delta)
-                self._evictions_seen = self.pages.evictions
+        with span(TICK_ENGINE):
+            with span(TICK_SCHEDULE, queued=self.scheduler.depth,
+                      active=len(self._active)):
+                now = clock.now()
+                self._expire(now, finished)
+                self._evict_cancelled(finished)
+                self._maybe_preempt(now)
+                self._chunk_tokens_tick = 0
+            if self.config.prefill_token_budget is None:
+                self._admit(finished)
+            else:
+                self._chunked_admit(finished)
+            if self._chunk_tokens_tick:
+                # one observation per tick with prefill activity — the
+                # histogram's sum is the total chunked prefill tokens, its
+                # max must never exceed prefill_token_budget
+                self.metrics.observe("prefill_tokens_per_tick",
+                                     self._chunk_tokens_tick)
+            self._decode_tick(finished)
+            with span(TICK_COMMIT):
+                self.metrics.observe("slot_occupancy", self.slots.occupancy)
+                if self.pages is not None:
+                    self.metrics.set_gauge("kv_pages_in_use",
+                                           self.pages.in_use_count)
+                    self.metrics.set_gauge("kv_pages_free",
+                                           self.pages.free_count)
+                    self.metrics.observe("kv_page_occupancy",
+                                         self.pages.occupancy)
+                    delta = self.pages.evictions - self._evictions_seen
+                    if delta:
+                        self.metrics.inc("prefix_evictions", delta)
+                        self._evictions_seen = self.pages.evictions
         return finished
 
     def serve(self, requests: Sequence[Request], *,
@@ -1389,13 +1416,14 @@ class InferenceEngine:
         return len(victims)
 
     def _admit(self, finished: List[RequestResult]) -> None:
-        shed: List = []
-        now = clock.now()
-        batch = self.scheduler.pop_admissible(
-            self.slots.free_count, decoding=bool(self._active),
-            predicate=self._make_page_predicate(), shed=shed, now=now)
-        for request, submit_ts in shed:
-            finished.append(self._shed_pages(request, submit_ts, now))
+        with span(TICK_SCHEDULE):
+            shed: List = []
+            now = clock.now()
+            batch = self.scheduler.pop_admissible(
+                self.slots.free_count, decoding=bool(self._active),
+                predicate=self._make_page_predicate(), shed=shed, now=now)
+            for request, submit_ts in shed:
+                finished.append(self._shed_pages(request, submit_ts, now))
         for request, submit_ts in batch:
             slot = self.slots.allocate()
             assert slot is not None  # pop_admissible respects free_count
@@ -1426,21 +1454,24 @@ class InferenceEngine:
             limit = min(limit,
                         self.config.scheduler.max_prefills_per_tick)
         while spent < budget and admitted < limit and self.scheduler.depth:
-            shed: List = []
-            now = clock.now()
-            batch = self.scheduler.pop_admissible(
-                1, decoding=False, predicate=self._make_page_predicate(),
-                shed=shed, now=now)
-            for request, submit_ts in shed:
-                finished.append(self._shed_pages(request, submit_ts, now))
-            if not batch:
-                break           # head deferred (pages) or queue drained
-            request, submit_ts = batch[0]
-            slot = self.slots.allocate()
-            assert slot is not None
-            rec = self._begin_chunked_prefill(request, slot, submit_ts)
-            if rec is None:
-                break           # intern-eviction race: requeued at front
+            with span(TICK_SCHEDULE):
+                shed: List = []
+                now = clock.now()
+                batch = self.scheduler.pop_admissible(
+                    1, decoding=False,
+                    predicate=self._make_page_predicate(),
+                    shed=shed, now=now)
+                for request, submit_ts in shed:
+                    finished.append(
+                        self._shed_pages(request, submit_ts, now))
+                if not batch:
+                    break       # head deferred (pages) or queue drained
+                request, submit_ts = batch[0]
+                slot = self.slots.allocate()
+                assert slot is not None
+                rec = self._begin_chunked_prefill(request, slot, submit_ts)
+                if rec is None:
+                    break       # intern-eviction race: requeued at front
             admitted += 1
             ran = self._run_chunk(rec, budget - spent, finished)
             if ran == 0:
@@ -1466,136 +1497,176 @@ class InferenceEngine:
 
     def _prefill_into(self, request: Request, slot: int, submit_ts: float,
                       finished: List[RequestResult]) -> None:
-        rec = _Active(request, slot, submit_ts)
-        rec.prefill_start = clock.now()
-        sp = request.sampling
-        # resolve the adapter row NOW (non-strict: an id unloaded while
-        # queued degrades to the null row — base output — rather than
-        # crashing admission; submit() already validated it existed)
-        rec.adapter_ix = self._adapter_index(sp.adapter_id, strict=False)
-        aix = jnp.asarray([rec.adapter_ix], jnp.int32)
-        bank = self._bank
-        topk = jnp.int32(sp.top_k if sp.top_k is not None else self._vocab)
-        chain, shared_pages, skip_first = (), [], False
-        shared_used = 0
-        if self.pages is not None:
-            # re-match the prefix NOW (the predicate's match may have
-            # been reshaped by a later head's intern eviction), commit
-            # the worst-case reservation minus the shared pages, then
-            # physically map only the prompt's pages (decode extends on
-            # demand)
-            chain, shared_pages, skip_first = self._plan_prefix(request)
-            shared_used = len(shared_pages)
-            need = self.pages.pages_for(request.total_len) - shared_used
-            mapped = self.pages.map_slot(slot, request.prompt_len,
-                                         shared=shared_pages or None)
-            if mapped is None:
-                self.slots.release(slot)
-                if self.config.prefix_cache:
-                    # an intern eviction between the admission predicate
-                    # and this map changed what's reclaimable — FCFS
-                    # honest, the request retries from the FRONT of the
-                    # queue on a later tick (co-tenant retirements will
-                    # unpin pages)
-                    self.scheduler.requeue_front(request, submit_ts)
-                    return
-                raise RuntimeError(
-                    f"page pool exhausted at prefill despite admission "
-                    f"reservation (slot {slot}, "
-                    f"free={self.pages.free_count}) — reservation "
-                    f"accounting is broken")
-            rec.reserved_pages = need
-            self._reserved_pages += need
-            row = self._page_table_h[slot]
-            row[:] = self.pages.n_pages
-            row[:len(mapped)] = mapped
-            # freshly mapped PRIVATE pages may be recycled (e.g. from a
-            # pressure-evicted intern run) with stale scales; zero them
-            # so the rescale-on-append floor starts clean. Shared pages
-            # keep their scales — that's their dequant key.
-            self._reset_fresh_scales(mapped[shared_used:])
-        try:
-            if self._faults is not None:
-                self._faults.before_prefill()
-            finite = True
-            if self.pages is not None and shared_used:
-                # prefix-cache hit: prefill ONLY the suffix (bucketed
-                # like a full prefill). start is the first token NOT
-                # covered by shared pages — or, fully covered, the
-                # prompt's last token recomputed for its logits only
-                ps = self.config.page_size
-                start = (request.prompt_len - 1 if skip_first
-                         else shared_used * ps)
-                suffix_len = request.prompt_len - start
-                bucket = bucket_for(suffix_len, self.config.max_len)
-                suffix = np.zeros((1, bucket), np.int32)
-                suffix[0, :suffix_len] = request.prompt[start:]
-                first, finite, self._caches = self._suffix_fn(
-                    self._params, self._caches,
-                    jnp.asarray(self._page_table_h[slot]),
-                    jnp.asarray(suffix), jnp.int32(start),
-                    jnp.int32(suffix_len), jnp.int32(request.prompt_len),
-                    jnp.float32(sp.temperature), topk,
-                    jnp.int32(sp.seed), jnp.bool_(skip_first), aix, bank)
-            elif self.pages is not None:
-                bucket = bucket_for(request.prompt_len, self.config.max_len)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :request.prompt_len] = request.prompt
-                first, finite, self._caches = self._prefill_fn(
-                    self._params, self._caches,
-                    jnp.asarray(self._page_table_h[slot]),
-                    jnp.asarray(padded), jnp.int32(request.prompt_len),
-                    jnp.float32(sp.temperature), topk,
-                    jnp.int32(sp.seed), aix, bank)
-            else:
-                bucket = bucket_for(request.prompt_len, self.config.max_len)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :request.prompt_len] = request.prompt
-                first, self._caches = self._prefill_fn(
-                    self._params, self._caches, jnp.asarray(padded),
-                    jnp.int32(slot), jnp.int32(request.prompt_len),
-                    jnp.float32(sp.temperature), topk,
-                    jnp.int32(sp.seed), aix, bank)
-            first = int(np.asarray(first))
-        except Exception:
-            # keep the pool invariants even as the failure propagates:
-            # the slot never held committed state (nothing scattered, or
-            # the scatter's result was discarded with the raised call)
-            self.slots.release(slot)
+        with span(TICK_PREFILL, trace_id=request.trace_id,
+                  prompt_tokens=request.prompt_len) as group:
+            with span(TICK_SCHEDULE):
+                rec = _Active(request, slot, submit_ts)
+                rec.prefill_start = clock.now()
+                sp = request.sampling
+                # resolve the adapter row NOW (non-strict: an id unloaded
+                # while queued degrades to the null row — base output —
+                # rather than crashing admission; submit() already validated
+                # it existed)
+                rec.adapter_ix = self._adapter_index(sp.adapter_id,
+                                                     strict=False)
+            with span(TICK_UPLOAD, arrays=2):
+                aix = jnp.asarray([rec.adapter_ix], jnp.int32)
+                bank = self._bank
+                topk = jnp.int32(sp.top_k if sp.top_k is not None
+                                 else self._vocab)
+            chain, shared_pages, skip_first = (), [], False
+            shared_used = 0
             if self.pages is not None:
-                self.pages.release_slot(slot)
-                self._reserved_pages -= rec.reserved_pages
-                self._page_table_h[slot, :] = self.pages.n_pages
-            raise
-        if self.pages is not None and self.config.prefix_cache:
-            if shared_used:
-                self.metrics.inc("prefix_hits")
-                self.metrics.inc("prefix_pages_shared", shared_used)
-            else:
-                self.metrics.inc("prefix_misses")
-            # publish the prompt's full pages (shared run + freshly
-            # prefilled privates) so later prompts hit; gated on finite
-            # logits — a poisoned prefill must never be shared. On an
-            # exact repeat this is a no-op; a longer prompt upgrades the
-            # subsumed shorter entry.
-            if chain and bool(np.asarray(finite)):
-                self.pages.intern_prefix(
-                    chain,
-                    [int(p) for p in self._page_table_h[slot][:len(chain)]])
-        rec.prefill_end = clock.now()
-        rec.tokens.append(first)
-        rec.last_token = first
-        # token #1 lands with the prefill result — TTFT is submit -> here
-        rec.first_token_ts = rec.last_token_ts = rec.prefill_end
-        rec.position = request.prompt_len
-        self._active[slot] = rec
-        self.admission_log.append(request.request_id)
-        self.metrics.inc("prefills")
-        self.metrics.inc("tokens_generated")
-        self._sync_slot(rec)
-        done = self._finish_reason(rec, first)
-        if done is not None:
-            finished.append(self._retire(rec, done, clock.now()))
+                with span(TICK_SCHEDULE) as sched:
+                    # re-match the prefix NOW (the predicate's match may have
+                    # been reshaped by a later head's intern eviction), commit
+                    # the worst-case reservation minus the shared pages, then
+                    # physically map only the prompt's pages (decode extends
+                    # on demand)
+                    chain, shared_pages, skip_first = \
+                        self._plan_prefix(request)
+                    shared_used = len(shared_pages)
+                    need = (self.pages.pages_for(request.total_len)
+                            - shared_used)
+                    mapped = self.pages.map_slot(slot, request.prompt_len,
+                                                 shared=shared_pages or None)
+                    if mapped is None:
+                        self.slots.release(slot)
+                        if self.config.prefix_cache:
+                            # an intern eviction between the admission
+                            # predicate and this map changed what's
+                            # reclaimable — FCFS honest, the request retries
+                            # from the FRONT of the queue on a later tick
+                            # (co-tenant retirements will unpin pages)
+                            self.scheduler.requeue_front(request, submit_ts)
+                            return
+                        raise RuntimeError(
+                            f"page pool exhausted at prefill despite "
+                            f"admission reservation (slot {slot}, "
+                            f"free={self.pages.free_count}) — reservation "
+                            f"accounting is broken")
+                    rec.reserved_pages = need
+                    self._reserved_pages += need
+                    row = self._page_table_h[slot]
+                    row[:] = self.pages.n_pages
+                    row[:len(mapped)] = mapped
+                    # freshly mapped PRIVATE pages may be recycled (e.g. from
+                    # a pressure-evicted intern run) with stale scales; zero
+                    # them so the rescale-on-append floor starts clean. Shared
+                    # pages keep their scales — that's their dequant key.
+                    self._reset_fresh_scales(mapped[shared_used:])
+                    sched.set_metadata(pages_mapped=len(mapped))
+            try:
+                if self._faults is not None:
+                    self._faults.before_prefill()
+                finite = True
+                if self.pages is not None and shared_used:
+                    # prefix-cache hit: prefill ONLY the suffix (bucketed
+                    # like a full prefill). start is the first token NOT
+                    # covered by shared pages — or, fully covered, the
+                    # prompt's last token recomputed for its logits only
+                    with span(TICK_UPLOAD) as up:
+                        ps = self.config.page_size
+                        start = (request.prompt_len - 1 if skip_first
+                                 else shared_used * ps)
+                        suffix_len = request.prompt_len - start
+                        bucket = bucket_for(suffix_len, self.config.max_len)
+                        suffix = np.zeros((1, bucket), np.int32)
+                        suffix[0, :suffix_len] = request.prompt[start:]
+                        args = (
+                            self._params, self._caches,
+                            jnp.asarray(self._page_table_h[slot]),
+                            jnp.asarray(suffix), jnp.int32(start),
+                            jnp.int32(suffix_len),
+                            jnp.int32(request.prompt_len),
+                            jnp.float32(sp.temperature), topk,
+                            jnp.int32(sp.seed), jnp.bool_(skip_first), aix,
+                            bank)
+                        up.set_metadata(
+                            arrays=8, bytes=suffix.nbytes
+                            + self._page_table_h[slot].nbytes)
+                    with span(TICK_DISPATCH, program="suffix_prefill",
+                              rows=bucket):
+                        first, finite, self._caches = self._suffix_fn(*args)
+                elif self.pages is not None:
+                    with span(TICK_UPLOAD) as up:
+                        bucket = bucket_for(request.prompt_len,
+                                            self.config.max_len)
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :request.prompt_len] = request.prompt
+                        args = (
+                            self._params, self._caches,
+                            jnp.asarray(self._page_table_h[slot]),
+                            jnp.asarray(padded), jnp.int32(request.prompt_len),
+                            jnp.float32(sp.temperature), topk,
+                            jnp.int32(sp.seed), aix, bank)
+                        up.set_metadata(
+                            arrays=5, bytes=padded.nbytes
+                            + self._page_table_h[slot].nbytes)
+                    with span(TICK_DISPATCH, program="paged_prefill",
+                              rows=bucket):
+                        first, finite, self._caches = self._prefill_fn(*args)
+                else:
+                    with span(TICK_UPLOAD) as up:
+                        bucket = bucket_for(request.prompt_len,
+                                            self.config.max_len)
+                        padded = np.zeros((1, bucket), np.int32)
+                        padded[0, :request.prompt_len] = request.prompt
+                        args = (
+                            self._params, self._caches, jnp.asarray(padded),
+                            jnp.int32(slot), jnp.int32(request.prompt_len),
+                            jnp.float32(sp.temperature), topk,
+                            jnp.int32(sp.seed), aix, bank)
+                        up.set_metadata(arrays=5, bytes=padded.nbytes)
+                    with span(TICK_DISPATCH, program="prefill", rows=bucket):
+                        first, self._caches = self._prefill_fn(*args)
+                del args
+                group.set_metadata(bucket=bucket)
+                with span(TICK_READBACK, reads=1, bytes=4):
+                    first = int(np.asarray(first))
+            except Exception:
+                # keep the pool invariants even as the failure propagates:
+                # the slot never held committed state (nothing scattered, or
+                # the scatter's result was discarded with the raised call)
+                self.slots.release(slot)
+                if self.pages is not None:
+                    self.pages.release_slot(slot)
+                    self._reserved_pages -= rec.reserved_pages
+                    self._page_table_h[slot, :] = self.pages.n_pages
+                raise
+            with span(TICK_COMMIT, tokens=1) as commit:
+                if self.pages is not None and self.config.prefix_cache:
+                    if shared_used:
+                        self.metrics.inc("prefix_hits")
+                        self.metrics.inc("prefix_pages_shared", shared_used)
+                    else:
+                        self.metrics.inc("prefix_misses")
+                    # publish the prompt's full pages (shared run + freshly
+                    # prefilled privates) so later prompts hit; gated on
+                    # finite logits — a poisoned prefill must never be
+                    # shared. On an exact repeat this is a no-op; a longer
+                    # prompt upgrades the subsumed shorter entry.
+                    if chain and bool(np.asarray(finite)):
+                        self.pages.intern_prefix(
+                            chain,
+                            [int(p)
+                             for p in self._page_table_h[slot][:len(chain)]])
+                rec.prefill_end = clock.now()
+                rec.tokens.append(first)
+                rec.last_token = first
+                # token #1 lands with the prefill result — TTFT is submit ->
+                # here
+                rec.first_token_ts = rec.last_token_ts = rec.prefill_end
+                rec.position = request.prompt_len
+                self._active[slot] = rec
+                self.admission_log.append(request.request_id)
+                self.metrics.inc("prefills")
+                self.metrics.inc("tokens_generated")
+                self._sync_slot(rec)
+                done = self._finish_reason(rec, first)
+                if done is not None:
+                    finished.append(self._retire(rec, done, clock.now()))
+                    commit.set_metadata(retired=1)
 
     def _begin_chunked_prefill(self, request: Request, slot: int,
                                submit_ts: float) -> Optional[_Active]:
@@ -1665,67 +1736,91 @@ class InferenceEngine:
         is the request's first token, bitwise what the monolithic
         prefill emits; intermediate chunks' samples are discarded."""
         request = rec.request
-        remaining = request.prompt_len - rec.prefill_pos
-        chunk_len = min(remaining, budget_left)
-        if chunk_len < remaining and self.pages is not None:
-            # internal chunk boundaries stay page-aligned: every fresh
-            # page is then written whole in ONE scatter onto a zeroed
-            # scale, so int8 page contents (and the interned prefix
-            # pages) are bitwise what the monolithic fill produces
-            ps = self.config.page_size
-            chunk_len = ((rec.prefill_pos + chunk_len) // ps) * ps \
-                - rec.prefill_pos
-        if chunk_len <= 0:
-            return 0
-        sp = request.sampling
-        start = rec.prefill_pos
-        bucket = bucket_for(chunk_len, self.config.max_len)
-        chunk = np.zeros((1, bucket), np.int32)
-        chunk[0, :chunk_len] = request.prompt[start:start + chunk_len]
-        aix = jnp.asarray([rec.adapter_ix], jnp.int32)
-        topk = jnp.int32(sp.top_k if sp.top_k is not None else self._vocab)
-        try:
-            if self._faults is not None:
-                self._faults.before_prefill()
-            if self.pages is not None:
-                first, finite, self._caches = self._suffix_fn(
-                    self._params, self._caches, jnp.asarray(rec.page_row),
-                    jnp.asarray(chunk), jnp.int32(start),
-                    jnp.int32(chunk_len), jnp.int32(request.prompt_len),
-                    jnp.float32(sp.temperature), topk, jnp.int32(sp.seed),
-                    jnp.bool_(rec.skip_first and rec.prefill_chunks == 0),
-                    aix, self._bank)
-            else:
-                first, finite, self._caches = self._chunk_fn(
-                    self._params, self._caches, jnp.int32(rec.slot),
-                    jnp.asarray(chunk), jnp.int32(start),
-                    jnp.int32(chunk_len), jnp.int32(request.prompt_len),
-                    jnp.float32(sp.temperature), topk, jnp.int32(sp.seed),
-                    aix, self._bank)
-            rec.finite_ok = rec.finite_ok and bool(np.asarray(finite))
-            first = int(np.asarray(first))
-        except Exception:
-            # same failure contract as the monolithic prefill: the slot
-            # never held committed state — release everything as the
-            # exception propagates; the supervisor's restart re-prefills
-            # the request from its prompt through the same admit path
-            del self._prefilling[rec.slot]
-            self.slots.release(rec.slot)
-            if self.pages is not None:
-                self.pages.release_slot(rec.slot)
-                self._reserved_pages -= rec.reserved_pages
-                self._page_table_h[rec.slot, :] = self.pages.n_pages
-            self._clear_slot(rec.slot)
-            raise
-        rec.prefill_pos += chunk_len
-        rec.prefill_chunks += 1
-        self.metrics.inc("prefill_chunks")
-        self._chunk_tokens_tick += chunk_len
-        if rec.prefill_pos < request.prompt_len:
-            rec.chunk_marks.append(clock.now())
-        else:
-            self._complete_chunked_prefill(rec, first, finished)
-        return chunk_len
+        with span(TICK_PREFILL, trace_id=request.trace_id,
+                  prompt_tokens=request.prompt_len,
+                  chunk=rec.prefill_chunks) as group:
+            with span(TICK_SCHEDULE):
+                remaining = request.prompt_len - rec.prefill_pos
+                chunk_len = min(remaining, budget_left)
+                if chunk_len < remaining and self.pages is not None:
+                    # internal chunk boundaries stay page-aligned: every
+                    # fresh page is then written whole in ONE scatter onto a
+                    # zeroed scale, so int8 page contents (and the interned
+                    # prefix pages) are bitwise what the monolithic fill
+                    # produces
+                    ps = self.config.page_size
+                    chunk_len = ((rec.prefill_pos + chunk_len) // ps) * ps \
+                        - rec.prefill_pos
+                if chunk_len <= 0:
+                    return 0
+            with span(TICK_UPLOAD, arrays=2, bytes=8):
+                sp = request.sampling
+                start = rec.prefill_pos
+                bucket = bucket_for(chunk_len, self.config.max_len)
+                chunk = np.zeros((1, bucket), np.int32)
+                chunk[0, :chunk_len] = request.prompt[start:start + chunk_len]
+                aix = jnp.asarray([rec.adapter_ix], jnp.int32)
+                topk = jnp.int32(sp.top_k if sp.top_k is not None
+                                 else self._vocab)
+            group.set_metadata(bucket=bucket)
+            try:
+                if self._faults is not None:
+                    self._faults.before_prefill()
+                if self.pages is not None:
+                    with span(TICK_UPLOAD, arrays=8, bytes=chunk.nbytes
+                              + rec.page_row.nbytes):
+                        args = (
+                            self._params, self._caches,
+                            jnp.asarray(rec.page_row), jnp.asarray(chunk),
+                            jnp.int32(start), jnp.int32(chunk_len),
+                            jnp.int32(request.prompt_len),
+                            jnp.float32(sp.temperature), topk,
+                            jnp.int32(sp.seed),
+                            jnp.bool_(rec.skip_first
+                                      and rec.prefill_chunks == 0),
+                            aix, self._bank)
+                    with span(TICK_DISPATCH, program="suffix_prefill",
+                              rows=bucket):
+                        first, finite, self._caches = self._suffix_fn(*args)
+                else:
+                    with span(TICK_UPLOAD, arrays=8, bytes=chunk.nbytes):
+                        args = (
+                            self._params, self._caches, jnp.int32(rec.slot),
+                            jnp.asarray(chunk), jnp.int32(start),
+                            jnp.int32(chunk_len),
+                            jnp.int32(request.prompt_len),
+                            jnp.float32(sp.temperature), topk,
+                            jnp.int32(sp.seed), aix, self._bank)
+                    with span(TICK_DISPATCH, program="flat_chunk",
+                              rows=bucket):
+                        first, finite, self._caches = self._chunk_fn(*args)
+                del args
+                with span(TICK_READBACK, reads=2, bytes=5):
+                    rec.finite_ok = rec.finite_ok and bool(np.asarray(finite))
+                    first = int(np.asarray(first))
+            except Exception:
+                # same failure contract as the monolithic prefill: the slot
+                # never held committed state — release everything as the
+                # exception propagates; the supervisor's restart re-prefills
+                # the request from its prompt through the same admit path
+                del self._prefilling[rec.slot]
+                self.slots.release(rec.slot)
+                if self.pages is not None:
+                    self.pages.release_slot(rec.slot)
+                    self._reserved_pages -= rec.reserved_pages
+                    self._page_table_h[rec.slot, :] = self.pages.n_pages
+                self._clear_slot(rec.slot)
+                raise
+            with span(TICK_COMMIT):
+                rec.prefill_pos += chunk_len
+                rec.prefill_chunks += 1
+                self.metrics.inc("prefill_chunks")
+                self._chunk_tokens_tick += chunk_len
+                if rec.prefill_pos < request.prompt_len:
+                    rec.chunk_marks.append(clock.now())
+                else:
+                    self._complete_chunked_prefill(rec, first, finished)
+            return chunk_len
 
     def _complete_chunked_prefill(self, rec: _Active, first: int,
                                   finished: List[RequestResult]) -> None:
@@ -1849,53 +1944,71 @@ class InferenceEngine:
                 .compile().as_text())
 
     def _decode_tick(self, finished: List[RequestResult]) -> None:
-        if self._spec and self._active:
-            self._build_windows()
-        if self.pages is not None:
-            self._extend_pages(finished)
-        if not self._active:
-            return
-        if self._faults is not None:
-            self._faults.before_decode()
-        if self.pages is not None:
-            # roofline gauge: bytes of KV stream one decode step reads
-            # (mapped pages of every active slot, dtype- and sidecar-
-            # aware) — THE denominator speculation and int8 shrink
-            self.metrics.set_gauge(
-                "kv_bytes_per_step",
-                sum(len(self.pages.slot_pages(s)) for s in self._active)
-                * self._page_read_bytes)
-        nxt, finite, self._caches = self._decode_fn(*self._decode_args())
-        nxt = np.asarray(nxt)
-        finite = np.asarray(finite)
-        if self._faults is not None:
-            nxt, finite = self._faults.corrupt_decode(nxt, finite)
-        self.metrics.inc("decode_steps")
-        self.metrics.observe("decode_batch_size", len(self._active))
-        now = clock.now()
-        if self._spec:
-            self._accept_windows(nxt, finite, now, finished)
-            return
-        for slot in sorted(self._active):
-            rec = self._active[slot]
-            token = int(nxt[slot])
-            # integrity check, off the critical path: non-finite logits
-            # or an out-of-vocab token mean THIS row is poisoned —
-            # quarantine it alone, co-tenant rows keep their clean step
-            if not bool(finite[slot]) or not 0 <= token < self._vocab:
-                cause = ("nonfinite_logits" if not bool(finite[slot])
-                         else "out_of_vocab_token")
-                finished.append(self._quarantine(rec, cause, now))
-                continue
-            rec.position += 1            # last_token's K/V are now cached
-            rec.tokens.append(token)
-            rec.last_token = token
-            rec.last_token_ts = now
-            self.metrics.inc("tokens_generated")
-            self._sync_slot(rec)
-            done = self._finish_reason(rec, token)
-            if done is not None:
-                finished.append(self._retire(rec, done, now))
+        with span(TICK_SCHEDULE, active=len(self._active)) as sched:
+            if self._spec and self._active:
+                self._build_windows()
+            if self.pages is not None:
+                self._extend_pages(finished)
+            if not self._active:
+                return
+            if self._faults is not None:
+                self._faults.before_decode()
+            if self.pages is not None:
+                # roofline gauge: bytes of KV stream one decode step
+                # reads (mapped pages of every active slot, dtype- and
+                # sidecar-aware) — THE denominator speculation and int8
+                # shrink
+                mapped = sum(len(self.pages.slot_pages(s))
+                             for s in self._active)
+                self.metrics.set_gauge("kv_bytes_per_step",
+                                       mapped * self._page_read_bytes)
+                sched.set_metadata(pages_mapped=mapped)
+        with span(TICK_UPLOAD, arrays=self._decode_upload[0],
+                  bytes=self._decode_upload[1]):
+            args = self._decode_args()
+        with span(TICK_DISPATCH, program="decode", rows=len(self._active)):
+            nxt, finite, self._caches = self._decode_fn(*args)
+        del args
+        with span(TICK_READBACK, reads=2) as back:
+            nxt = np.asarray(nxt)
+            finite = np.asarray(finite)
+            back.set_metadata(bytes=nxt.nbytes + finite.nbytes)
+        with span(TICK_COMMIT) as commit:
+            retired = len(finished)
+            if self._faults is not None:
+                nxt, finite = self._faults.corrupt_decode(nxt, finite)
+            self.metrics.inc("decode_steps")
+            self.metrics.observe("decode_batch_size", len(self._active))
+            now = clock.now()
+            if self._spec:
+                self._accept_windows(nxt, finite, now, finished)
+                commit.set_metadata(retired=len(finished) - retired)
+                return
+            emitted = 0
+            for slot in sorted(self._active):
+                rec = self._active[slot]
+                token = int(nxt[slot])
+                # integrity check, off the critical path: non-finite
+                # logits or an out-of-vocab token mean THIS row is
+                # poisoned — quarantine it alone, co-tenant rows keep
+                # their clean step
+                if not bool(finite[slot]) or not 0 <= token < self._vocab:
+                    cause = ("nonfinite_logits" if not bool(finite[slot])
+                             else "out_of_vocab_token")
+                    finished.append(self._quarantine(rec, cause, now))
+                    continue
+                rec.position += 1        # last_token's K/V are now cached
+                rec.tokens.append(token)
+                rec.last_token = token
+                rec.last_token_ts = now
+                emitted += 1
+                self.metrics.inc("tokens_generated")
+                self._sync_slot(rec)
+                done = self._finish_reason(rec, token)
+                if done is not None:
+                    finished.append(self._retire(rec, done, now))
+            commit.set_metadata(tokens=emitted,
+                                retired=len(finished) - retired)
 
     def _accept_windows(self, nxt, finite, now: float,
                         finished: List[RequestResult]) -> None:

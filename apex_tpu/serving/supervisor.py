@@ -58,6 +58,12 @@ from apex_tpu.observability.trace import (
     SPAN_SHED,
     emit_span,
 )
+from apex_tpu.observability.tracing import (
+    TICK_COMMIT,
+    TICK_SCHEDULE,
+    TICK_SUPERVISOR,
+    span,
+)
 from apex_tpu.serving.engine import EngineConfig, InferenceEngine
 from apex_tpu.serving.request import (
     FINISH_CANCELLED,
@@ -476,42 +482,50 @@ class EngineSupervisor:
         SUPERVISOR's view during this call."""
         if self._closed:
             raise RuntimeError("supervisor is closed")
-        before = set(self.completed)
-        now = clock.now()
-        self._poll_breaker(now)
-        self._drain_backlog()
-        compiles = self.engine.prefill_compiles + self.engine.decode_compiles
-        t0 = clock.now()
-        failure: Optional[str] = None
-        try:
-            self.engine.tick()
-        except Exception as exc:  # tick faults are recoverable by design
-            failure = f"{type(exc).__name__}: {exc}"
-        else:
-            hung = self.supervisor.hung_tick_s
-            elapsed = clock.now() - t0
-            # warmup ticks are exempt: a bounded, expected XLA compile
-            # (fresh engine, new prefill bucket) is not a hang
-            compiled = (self.engine.prefill_compiles
-                        + self.engine.decode_compiles) > compiles
-            if hung is not None and elapsed > hung and not compiled:
-                failure = (f"hung tick: {elapsed:.3f}s > "
-                           f"budget {hung:.3f}s")
-        if failure is not None:
-            self._on_tick_failure(failure)
-        else:
-            self._consecutive_failures = 0
-            if self.breaker_state == BREAKER_HALF_OPEN:
-                self._breaker_to(BREAKER_CLOSED)
-            after = clock.now()
-            self._harvest(after)
-            # preempted slots parked this tick become resume
-            # continuations NOW — re-queued in their own class lane so
-            # strict priority keeps them behind the displacing traffic
-            self._drain_parked(after)
-            self._drain_backlog()
-        return [self.completed[rid] for rid in sorted(
-            set(self.completed) - before)]
+        with span(TICK_SUPERVISOR):
+            with span(TICK_SCHEDULE, backlog=len(self._backlog)):
+                before = set(self.completed)
+                now = clock.now()
+                self._poll_breaker(now)
+                self._drain_backlog()
+                compiles = (self.engine.prefill_compiles
+                            + self.engine.decode_compiles)
+                t0 = clock.now()
+            failure: Optional[str] = None
+            try:
+                self.engine.tick()
+            except Exception as exc:  # tick faults are recoverable by design
+                failure = f"{type(exc).__name__}: {exc}"
+            with span(TICK_COMMIT) as commit:
+                if failure is None:
+                    hung = self.supervisor.hung_tick_s
+                    elapsed = clock.now() - t0
+                    # warmup ticks are exempt: a bounded, expected XLA
+                    # compile (fresh engine, new prefill bucket) is not a
+                    # hang
+                    compiled = (self.engine.prefill_compiles
+                                + self.engine.decode_compiles) > compiles
+                    if hung is not None and elapsed > hung and not compiled:
+                        failure = (f"hung tick: {elapsed:.3f}s > "
+                                   f"budget {hung:.3f}s")
+                if failure is not None:
+                    self._on_tick_failure(failure)
+                else:
+                    self._consecutive_failures = 0
+                    if self.breaker_state == BREAKER_HALF_OPEN:
+                        self._breaker_to(BREAKER_CLOSED)
+                    after = clock.now()
+                    self._harvest(after)
+                    # preempted slots parked this tick become resume
+                    # continuations NOW — re-queued in their own class
+                    # lane so strict priority keeps them behind the
+                    # displacing traffic
+                    self._drain_parked(after)
+                    self._drain_backlog()
+                done = [self.completed[rid] for rid in sorted(
+                    set(self.completed) - before)]
+                commit.set_metadata(retired=len(done))
+            return done
 
     def serve(self, requests: Sequence[Request], *,
               on_tick: Optional[Callable[["EngineSupervisor", int], None]]

@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.observability.tracing import SCOPE_TP_ALL_REDUCE
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
+from apex_tpu.utils.profiling import nvtx_range
 
 __all__ = [
     "copy_to_tensor_model_parallel_region",
@@ -85,7 +87,8 @@ def _copy_fwd(x, axis_name):
 
 def _copy_bwd(axis_name, _, g):
     if axis_bound(axis_name):
-        g = lax.psum(g, axis_name)
+        with nvtx_range(SCOPE_TP_ALL_REDUCE):
+            g = lax.psum(g, axis_name)
     return (g,)
 
 
@@ -97,7 +100,8 @@ def reduce_from_tensor_model_parallel_region(x, axis_name=TENSOR_AXIS):
     """All-reduce forward, identity backward (``_ReduceFromModelParallelRegion``,
     reference ``mappings.py:159-172``)."""
     if axis_bound(axis_name):
-        return lax.psum(x, axis_name)
+        with nvtx_range(SCOPE_TP_ALL_REDUCE):
+            return lax.psum(x, axis_name)
     return x
 
 
@@ -204,7 +208,9 @@ def _sp_gather_fwd(x, tensor_parallel_output_grad, axis_name):
 def _sp_gather_bwd(tensor_parallel_output_grad, axis_name, _, g):
     if axis_bound(axis_name):
         if tensor_parallel_output_grad:
-            g = lax.psum_scatter(g, axis_name, scatter_dimension=0, tiled=True)
+            with nvtx_range(SCOPE_TP_ALL_REDUCE):
+                g = lax.psum_scatter(g, axis_name, scatter_dimension=0,
+                                     tiled=True)
         else:
             g = _local_chunk(g, axis_name, 0)
     return (g,)
@@ -218,7 +224,9 @@ def reduce_scatter_to_sequence_parallel_region(x, axis_name=TENSOR_AXIS):
     """Reduce-scatter dim 0 forward, all-gather backward
     (``_ReduceScatterToSequenceParallelRegion``, reference ``mappings.py:254-268``)."""
     if axis_bound(axis_name):
-        return lax.psum_scatter(x, axis_name, scatter_dimension=0, tiled=True)
+        with nvtx_range(SCOPE_TP_ALL_REDUCE):
+            return lax.psum_scatter(x, axis_name, scatter_dimension=0,
+                                    tiled=True)
     return x
 
 
@@ -258,7 +266,8 @@ def _mark_sp_fwd(p, axis_name):
 
 def _mark_sp_bwd(axis_name, _, g):
     if axis_bound(axis_name):
-        g = lax.psum(g, axis_name)
+        with nvtx_range(SCOPE_TP_ALL_REDUCE):
+            g = lax.psum(g, axis_name)
     return (g,)
 
 

@@ -6,12 +6,10 @@ from apex_tpu.utils.flops import (
     transformer_train_flops,
 )
 from apex_tpu.utils.profiling import (
-    annotate_fn,
     device_memory_stats,
     nvtx_range,
     profiler_start,
     profiler_stop,
-    trace,
 )
 from apex_tpu.utils.tree import (
     tree_cast,
@@ -29,10 +27,8 @@ __all__ = [
     "tree_zeros_like",
     "global_norm",
     "nvtx_range",
-    "annotate_fn",
     "profiler_start",
     "profiler_stop",
-    "trace",
     "device_memory_stats",
     "peak_flops_per_chip",
     "resnet50_train_flops",

@@ -5,16 +5,14 @@ flag wraps hooks/comm in ``torch.cuda.nvtx`` ranges,
 ``apex/parallel/distributed.py:361-364``; the imagenet example calls
 ``cudaProfilerStart`` at a chosen iteration). TPU-native equivalents:
 
-- :func:`nvtx_range` — ``jax.named_scope`` context manager (the name lands
-  in XLA HLO metadata and shows up in the profiler timeline exactly like an
-  NVTX range does in Nsight); pass a
-  :class:`~apex_tpu.observability.registry.MetricsRegistry` and the scope
-  also records its host-side wall duration into the ``span/<name>_s``
-  histogram — one annotation, visible both in the trace and in the run's
-  own metrics;
+- :func:`nvtx_range` — ``jax.named_scope`` context manager: the one
+  primitive for names INSIDE jitted code. The name becomes a path element
+  of every enclosed instruction's ``op_name`` in the compiled program,
+  which is how a profiler trace attributes device time to it (an NVTX
+  range in Nsight). Host-side spans are
+  :func:`apex_tpu.observability.tracing.span`;
 - :func:`profiler_start` / :func:`profiler_stop` — ``jax.profiler`` trace
   capture to a TensorBoard-readable directory;
-- :func:`annotate_fn` — decorator form of :func:`nvtx_range`;
 - :func:`device_memory_stats` — per-device live-bytes summary (role of
   ``report_memory``, ``pipeline_parallel/utils.py:253-263``, which also
   re-exports this).
@@ -22,54 +20,19 @@ flag wraps hooks/comm in ``torch.cuda.nvtx`` ranges,
 
 from __future__ import annotations
 
-import contextlib
-import functools
-import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict
 
 import jax
 
-__all__ = ["nvtx_range", "annotate_fn", "profiler_start", "profiler_stop",
-           "trace", "device_memory_stats"]
+__all__ = ["nvtx_range", "profiler_start", "profiler_stop",
+           "device_memory_stats"]
 
 
-@contextlib.contextmanager
-def _timed_scope(name: str, registry):
-    t0 = time.perf_counter()
-    try:
-        with jax.named_scope(name):
-            yield
-    finally:
-        # host-side wall duration: dispatch time, not device time — in a
-        # saturated pipeline they converge; either way it is free (no sync)
-        registry.observe(f"span/{name}_s", time.perf_counter() - t0)
-
-
-def nvtx_range(name: str, registry=None):
-    """``with nvtx_range("fwd"):`` — names the enclosed computation in the
-    profiler timeline (``jax.named_scope``). With ``registry`` (a
-    ``MetricsRegistry``), the scope's host-side wall duration is also
-    observed into the ``span/<name>_s`` histogram."""
-    if registry is None:
-        return jax.named_scope(name)
-    return _timed_scope(name, registry)
-
-
-def annotate_fn(name: Optional[str] = None, registry=None) -> Callable:
-    """Decorator: run the function under a named scope (optionally timed
-    into ``registry``, as :func:`nvtx_range`)."""
-
-    def deco(fn: Callable) -> Callable:
-        scope = name or fn.__name__
-
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            with nvtx_range(scope, registry=registry):
-                return fn(*a, **kw)
-
-        return wrapped
-
-    return deco
+def nvtx_range(name: str):
+    """``with nvtx_range("fwd"):`` — names the enclosed computation in
+    the compiled program and so in the profiler timeline
+    (``jax.named_scope``)."""
+    return jax.named_scope(name)
 
 
 def profiler_start(log_dir: str) -> None:
@@ -80,16 +43,6 @@ def profiler_start(log_dir: str) -> None:
 
 def profiler_stop() -> None:
     jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Context-manager form: profile exactly the enclosed iterations."""
-    profiler_start(log_dir)
-    try:
-        yield
-    finally:
-        profiler_stop()
 
 
 def device_memory_stats(device=None) -> Dict[str, Any]:

@@ -946,6 +946,53 @@ class TestRetraceWatchdog:
         wd(jnp.ones((4,), jnp.bfloat16))
         assert wd.retraces == 1
 
+    def test_calls_that_may_trace_get_stack_room(self):
+        """While compiles are expected the callable runs under the
+        roomy frame; once they are all in, the call is direct."""
+        import sys
+
+        callers = []
+
+        def plain(x, scale=1):
+            callers.append(sys._getframe(1).f_code.co_name)
+            return x * scale
+
+        wd = RetraceWatchdog(plain, budget=None, expected_compiles=2)
+        assert float(wd(jnp.ones(()), scale=3)) == 3.0
+        wd(jnp.ones((2,)))
+        wd(jnp.ones((2,)))
+        assert callers == ["_call_with_stack_room"] * 2 + ["__call__"]
+        assert wd.compiles == 2 and wd.calls == 3 and wd.retraces == 0
+
+    def test_stack_room_takes_the_page_faults_out_of_deep_loops(self):
+        """The fault the frame is there for: at some depth a loop's
+        callees fall beyond the end of a block of the interpreter's frame
+        stack and every call faults a page in. From the roomy frame no
+        depth does."""
+        import resource
+
+        from apex_tpu.analysis.retrace import _call_with_stack_room
+
+        def leaf():
+            return 0
+
+        def loop():
+            before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+            for _ in range(2000):
+                leaf()
+            return resource.getrusage(resource.RUSAGE_THREAD).ru_minflt \
+                - before
+
+        def at_depth(n):
+            return loop() if n == 0 else at_depth(n - 1)
+
+        def worst():
+            return max(at_depth(d) for d in range(300))
+
+        if worst() < 1000:
+            pytest.skip("this interpreter's frame stack has no such edge")
+        assert _call_with_stack_room(worst, (), {}) < 200
+
 
 class TestRunTrainingRetraceIntegration:
     def _step(self):

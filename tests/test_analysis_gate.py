@@ -41,6 +41,9 @@ CHEAP_MODEL_TEST_MODULES = {
     "test_gqa.py",
     "test_imports.py",
     "test_moe.py",
+    # 2-layer hidden-32 engine and train step, one compile each, reused
+    # by every test of the module: 24 s for the whole file (PR 26)
+    "test_tick_spans.py",
     "test_trace_fleet.py",
 }
 

@@ -202,18 +202,12 @@ class TestModelParallelGradScaler:
 
 
 class TestProfiling:
-    def test_nvtx_range_and_annotate(self):
-        from apex_tpu.utils import annotate_fn, nvtx_range
+    def test_nvtx_range_names_eager_work(self):
+        from apex_tpu.utils import nvtx_range
 
         with nvtx_range("block"):
             y = jnp.sum(jnp.ones(4))
         assert float(y) == 4.0
-
-        @annotate_fn("scoped")
-        def f(x):
-            return x * 2
-
-        np.testing.assert_allclose(np.asarray(f(jnp.ones(2))), 2 * np.ones(2))
 
     def test_named_scope_in_jit(self):
         from apex_tpu.utils import nvtx_range
@@ -233,10 +227,13 @@ class TestProfiling:
 
     @pytest.mark.slow
     def test_trace_writes_profile(self, tmp_path):
-        from apex_tpu.utils import trace
+        from apex_tpu.utils import profiler_start, profiler_stop
 
-        with trace(str(tmp_path)):
+        profiler_start(str(tmp_path))
+        try:
             jax.block_until_ready(jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))))
+        finally:
+            profiler_stop()
         import os
         found = any("trace" in f or f.endswith(".pb") or "plugins" in r
                     for r, _, fs in os.walk(tmp_path) for f in fs + [r])
