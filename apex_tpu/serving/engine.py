@@ -331,6 +331,34 @@ class _Active:
         self.finite_ok = True    # AND of every chunk's isfinite flag
 
 
+def _kth_largest(rows, k):
+    """The ``k[i]``-th largest value of each row of float32 ``rows``
+    [n, V] (ties counted, as ``sort(row)[V - k]``), found without
+    ordering the row. Each value maps to a uint32 key of the same order
+    (all bits of a negative flipped, the sign bit of the rest set); the
+    largest key ``t`` that at least ``k`` keys of the row reach is built
+    from the top down, two bits to a counting pass over the row (the
+    three keys that extend ``t`` are counted in one read), and mapped
+    back. Exact for any k in [1, V]; a k outside it gives NaN, under
+    which the caller's ``<`` masks nothing."""
+    bits = jax.lax.bitcast_convert_type(rows, jnp.uint32)
+    top = np.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+    digits = np.arange(1, 4, dtype=np.uint32)
+
+    def settle(i, t):
+        shift = np.uint32(30) - 2 * i.astype(jnp.uint32)
+        trial = t[:, None] | (digits << shift)              # [n, 3], rising
+        reach = jnp.sum(keys[:, None, :] >= trial[:, :, None], axis=-1,
+                        dtype=jnp.int32)
+        digit = jnp.sum(reach >= k[:, None], axis=-1).astype(jnp.uint32)
+        return t | (digit << shift)
+
+    t = jax.lax.fori_loop(0, 16, settle, jnp.zeros(rows.shape[:1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(t >= top, t ^ top, ~t), jnp.float32)
+
+
 @nvtx_range(SCOPE_SAMPLE)
 def _sample_tokens(logits, temps, topks, seeds, steps):
     """Per-row sampling over ``logits`` [n, V]: greedy where
@@ -339,15 +367,14 @@ def _sample_tokens(logits, temps, topks, seeds, steps):
     ``fold_in(PRNGKey(seed), step)`` so a request's stream depends only
     on its own (seed, positions) — never on batch co-tenants."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    v = logits.shape[-1]
     safe_t = jnp.where(temps > 0.0, temps, 1.0).astype(logits.dtype)
     scaled = logits / safe_t[:, None]
-    # kth-largest per row via one sort (top_k varies per row, so the
-    # static-k lax.top_k form generate() uses cannot batch here);
+    # top_k varies per row, so the static-k lax.top_k form generate()
+    # uses cannot batch here: the row's kth largest is found by a search
+    # over order-preserving integer keys (16 counting passes, no sort);
     # mask logits < kth — identical support to generate()'s truncation
-    order = jnp.sort(scaled, axis=-1)                      # ascending
-    kth = jnp.take_along_axis(order, (v - topks)[:, None], axis=-1)
-    masked = jnp.where(scaled < kth, -jnp.inf, scaled)
+    kth = _kth_largest(scaled, topks)
+    masked = jnp.where(scaled < kth[:, None], -jnp.inf, scaled)
 
     def draw(seed, step, row):
         key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
