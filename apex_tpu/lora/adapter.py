@@ -50,6 +50,13 @@ def target_dims(config) -> Dict[str, Tuple[int, int]]:
     engine's shard_map slices the ``B`` bank with the heads. MoE models
     carry no ``dense_h_to_4h`` (expert weights are routed, not adapted)."""
     c = config
+    if getattr(c, "mixed_layers", False) or getattr(
+            c, "num_routed_experts", None):
+        raise NotImplementedError(
+            "LoRA adapters are stacked [num_layers, ...] banks over layers "
+            "of one kind with a dense MLP; a model with "
+            "attention_layer_types / num_dense_layers / num_routed_experts "
+            "is not supported")
     qpg = c.num_attention_heads // c.kv_heads
     dims = {"query_key_value": (c.hidden_size,
                                 c.kv_heads * (qpg + 2) * c.head_dim)}

@@ -63,7 +63,8 @@ def preslice_layer_params(params, num_layers: int):
     EVERY step (~115 us/step at GPT-2 124M bs8 — PERF.md round 5); the
     barrier pins the slices as buffers so XLA cannot sink them back.
     No-op when the params are already a list or have no stacked
-    transformer layers."""
+    transformer layers (a model whose layers differ holds a per-layer
+    list from the start: two parameter shapes cannot be stacked)."""
     if "transformer" not in params or "layers" not in params["transformer"]:
         return params
     lp = params["transformer"]["layers"]
@@ -176,7 +177,7 @@ def _gather_vocab(logits: jax.Array, axis_name: str) -> jax.Array:
 
 def _cached_forward(model, params, caches, tokens: jax.Array, index,
                     last_only: bool = False, last_index=None,
-                    paged_state=None, lora=None):
+                    paged_state=None, lora=None, routing=None):
     """Run ``tokens`` [batch, s] occupying cache slots [index, index+s) ->
     (fp32 full-vocab logits [s, batch, V], new caches). ``last_only``:
     compute the LM head for the FINAL position only (returns [1, b, V]) —
@@ -188,11 +189,14 @@ def _cached_forward(model, params, caches, tokens: jax.Array, index,
     sits mid-sequence. ``index`` may be a ``[batch]`` vector of per-row
     cache offsets (continuous-batching decode over FLAT caches): each
     row then reads its own learned-position rows / rope angles and
-    writes K/V at its own offset."""
+    writes K/V at its own offset. ``routing``: a
+    ``transformer.moe.RoutingStats`` for the routed layers' counts."""
     c = model.config
     emb_p = params["embedding"]
     s = tokens.shape[1]
     emb = model.embedding.apply(emb_p["word_embeddings"], tokens)  # [b,s,h]
+    if c.embedding_multiplier != 1.0:
+        emb = emb.astype(jnp.float32) * c.embedding_multiplier
     if c.position_embedding_type == "learned":
         if getattr(index, "ndim", 0) == 1:
             positions = index[:, None] + jnp.arange(s)[None, :]    # [b, s]
@@ -208,20 +212,19 @@ def _cached_forward(model, params, caches, tokens: jax.Array, index,
     hidden = hidden.astype(c.compute_dtype)
     hidden, new_caches = model.transformer.apply(
         params["transformer"], hidden, kv_caches=caches, cache_index=index,
-        paged_state=paged_state, lora=lora)
-    from apex_tpu.models.gpt import lm_head_loss
+        paged_state=paged_state, lora=lora, routing=routing)
+    from apex_tpu.models.gpt import lm_head_loss, output_weight
     if last_only:
         hidden = hidden[-1:]
     elif last_index is not None:
         hidden = lax.dynamic_slice_in_dim(hidden, last_index, 1, axis=0)
-    logits = lm_head_loss(
-        emb_p["word_embeddings"]["weight"], hidden, None, None, c)
+    logits = lm_head_loss(output_weight(params, c), hidden, None, None, c)
     logits = _gather_vocab(logits, c.axis_name)
     return logits.astype(jnp.float32), new_caches
 
 
 def decode_step(model, params, caches, tokens: jax.Array, index,
-                paged_state=None, lora=None):
+                paged_state=None, lora=None, routing=None):
     """One incremental step: ``tokens`` [batch] at position ``index`` ->
     (fp32 full-vocab logits [batch, V], updated caches). ``caches`` is
     either form :func:`init_kv_caches` produces — the stacked ``(k, v)``
@@ -236,7 +239,8 @@ def decode_step(model, params, caches, tokens: jax.Array, index,
     :func:`generate`)."""
     logits, new_caches = _cached_forward(model, params, caches,
                                          tokens[:, None], index,
-                                         paged_state=paged_state, lora=lora)
+                                         paged_state=paged_state, lora=lora,
+                                         routing=routing)
     return logits[0], new_caches
 
 

@@ -33,7 +33,16 @@ from apex_tpu.transformer.tensor_parallel.layers import (
     linear_with_grad_accumulation_and_async_allreduce,
 )
 from apex_tpu.utils.profiling import nvtx_range
-__all__ = ["GPTModel", "lm_head_loss"]
+__all__ = ["GPTModel", "lm_head_loss", "output_weight"]
+
+
+def output_weight(params, config):
+    """The LM head's ``[V, h]`` matrix: the word embedding (tied, the
+    default) or the model's own ``output_layer``
+    (``untie_embeddings_and_output_weights``)."""
+    if config.untie_embeddings_and_output_weights:
+        return params["output_layer"]["weight"]
+    return params["embedding"]["word_embeddings"]["weight"]
 
 
 @nvtx_range(SCOPE_LM_HEAD_LOSS)
@@ -95,7 +104,8 @@ def lm_head_loss(embedding_weight, hidden, labels, loss_mask, config):
 
 @dataclass
 class GPTModel:
-    """GPT: embeddings -> ParallelTransformer (causal) -> tied LM head."""
+    """GPT: embeddings -> ParallelTransformer (causal) -> LM head (tied
+    to the embedding unless ``untie_embeddings_and_output_weights``)."""
 
     config: TransformerConfig
 
@@ -109,22 +119,30 @@ class GPTModel:
     def init(self, key: jax.Array) -> Dict[str, Any]:
         c = self.config
         k_emb, k_pos, k_tr = jax.random.split(key, 3)
-        return {
+        p = {
             "embedding": {
                 "word_embeddings": self.embedding.init(k_emb),
                 **position_table_params(c, k_pos),
             },
             "transformer": self.transformer.init(k_tr),
         }
+        if c.untie_embeddings_and_output_weights:
+            # same shape and sharding as the embedding it is untied from
+            p["output_layer"] = self.embedding.init(
+                jax.random.fold_in(key, 3))
+        return p
 
     def spec(self) -> Dict[str, Any]:
-        return {
+        s = {
             "embedding": {
                 "word_embeddings": self.embedding.spec(),
                 **position_table_spec(self.config),
             },
             "transformer": self.transformer.spec(),
         }
+        if self.config.untie_embeddings_and_output_weights:
+            s["output_layer"] = self.embedding.spec()
+        return s
 
     def _embed(self, params, tokens, rng, deterministic):
         """tokens [b, s] -> hidden [s(, shard), b, h] (Megatron layout)."""
@@ -162,7 +180,7 @@ class GPTModel:
         if self.config.num_moe_experts:
             hidden, moe_aux = hidden
         out = lm_head_loss(
-            params["embedding"]["word_embeddings"]["weight"], hidden,
+            output_weight(params, self.config), hidden,
             labels, loss_mask, self.config)
         if moe_aux is not None and labels is not None:
             out = out + moe_aux        # load-balancing term, pre-scaled

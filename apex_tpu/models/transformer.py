@@ -140,6 +140,31 @@ class TransformerConfig:
     compute_dtype: Any = jnp.float32  # activations cast at block entry
     init_method_std: float = 0.02
     axis_name: str = TENSOR_AXIS
+    # -- layers of more than one kind, and the sparse-expert serving block
+    # -- (docs/moe.md). Every default below leaves a model as it was.
+    # head size where it is not hidden / heads (Megatron's kv_channels)
+    kv_channels: Optional[int] = None
+    # attention kind of each layer: "sliding" (the last `sliding_window`
+    # keys, rotary positions where the model has them) or "full" (every
+    # key, NO rotary positions); None = every layer alike, windowed iff
+    # `sliding_window` is set
+    attention_layer_types: Optional[Tuple[str, ...]] = None
+    add_bias_linear: bool = True       # False: no bias on any projection
+    qk_layernorm: bool = False         # RMSNorm over each head of q and k
+    attention_output_gate: bool = False  # ctx * sigmoid(x W_gate) before W_o
+    # four norms a block: h += N(attn(N(h))); h += N(mlp(N(h)))
+    sandwich_norm: bool = False
+    embedding_multiplier: float = 1.0
+    untie_embeddings_and_output_weights: bool = False
+    # routed experts (transformer/moe.py RoutedExperts): the leading
+    # `num_dense_layers` layers keep the dense MLP, the rest route
+    num_routed_experts: Optional[int] = None
+    routed_top_k: int = 1
+    routed_ffn_hidden_size: Optional[int] = None   # one expert's width
+    route_scale: float = 1.0
+    num_shared_experts: int = 0
+    routed_expert_range: Optional[Tuple[int, int]] = None   # held here
+    num_dense_layers: int = 0
 
     def __post_init__(self):
         if self.position_embedding_type not in ("learned", "rope", "none"):
@@ -165,6 +190,38 @@ class TransformerConfig:
             # under context parallelism the window is exact across chunk
             # boundaries: ring masks with global positions, ulysses windows
             # the gathered full sequence
+        kinds = self.attention_layer_types
+        if kinds is not None:
+            if len(kinds) != self.num_layers:
+                raise ValueError(
+                    f"attention_layer_types has {len(kinds)} entries for "
+                    f"num_layers = {self.num_layers}")
+            bad = sorted(set(kinds) - {"full", "sliding"})
+            if bad:
+                raise ValueError(
+                    f"attention_layer_types entries must be 'full' or "
+                    f"'sliding', got {bad}")
+            if "sliding" in kinds and self.sliding_window is None:
+                raise ValueError(
+                    "attention_layer_types names 'sliding' layers but "
+                    "sliding_window is not set")
+            if self.attn_mask_type != AttnMaskType.causal:
+                raise ValueError(
+                    "attention_layer_types requires causal attention "
+                    "(attn_mask_type)")
+        if self.num_routed_experts:
+            if self.num_moe_experts:
+                raise ValueError(
+                    "num_routed_experts (the serving layer) and "
+                    "num_moe_experts (the training layer) are exclusive")
+            if not self.routed_ffn_hidden_size:
+                raise ValueError(
+                    "num_routed_experts needs routed_ffn_hidden_size (one "
+                    "routed expert's width)")
+            if not 0 <= self.num_dense_layers <= self.num_layers:
+                raise ValueError(
+                    f"num_dense_layers ({self.num_dense_layers}) must lie "
+                    f"in 0..num_layers ({self.num_layers})")
 
     @property
     def ffn_size(self) -> int:
@@ -172,7 +229,25 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.kv_channels is not None:
+            return self.kv_channels
         return divide(self.hidden_size, self.num_attention_heads)
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """``(attention kind, feed-forward kind)`` of each layer:
+        ``"default" | "full" | "sliding"`` and ``"dense" | "routed"``."""
+        attn = self.attention_layer_types or ("default",) * self.num_layers
+        dense = (self.num_dense_layers if self.num_routed_experts
+                 else self.num_layers)
+        return tuple((a, "dense" if i < dense else "routed")
+                     for i, a in enumerate(attn))
+
+    @property
+    def mixed_layers(self) -> bool:
+        """More than one kind of layer: the parameters are a per-layer
+        LIST (two shapes cannot be stacked) and the stack runs unrolled."""
+        return len(set(self.layer_kinds)) > 1
 
     @property
     def kv_heads(self) -> int:
@@ -268,6 +343,8 @@ def embed_tokens(embedding, emb_params, tokens, config, *, tokentype_params=None
     from apex_tpu.transformer.tensor_parallel.mappings import axis_bound
 
     emb = embedding.apply(emb_params["word_embeddings"], tokens)
+    if c.embedding_multiplier != 1.0:
+        emb = emb.astype(jnp.float32) * c.embedding_multiplier
     s_local = tokens.shape[1]
     if c.position_embedding_type == "learned":
         if c.context_parallel_method and axis_bound(c.context_axis):
@@ -336,6 +413,16 @@ def _ln(params, x, eps, sequence_parallel=False, axis_name=TENSOR_AXIS,
                                    out_dtype=x.dtype)
 
 
+def _head_rms(x, weight, eps):
+    """RMSNorm over the last (head) dim of ``[..., heads, dh]`` with one
+    learned ``[dh]`` weight for all heads: float32 statistics, the input's
+    dtype out (the q/k norm of ``qk_layernorm``)."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32)).astype(x.dtype)
+
+
 def _lora_delta(x, lora):
     """Per-slot low-rank delta for one target projection: ``(x @ A) @ B``
     with PER-BATCH-ELEMENT factors — ``x [s, b, h]``, ``A [b, h, r]``,
@@ -373,12 +460,14 @@ class ParallelMLP:
         # for both halves)
         self.dense_h_to_4h = ColumnParallelLinear(
             c.hidden_size, (2 if self.gated else 1) * c.ffn_size,
-            gather_output=False, bias=not self.gated,
+            gather_output=False,
+            bias=not self.gated and c.add_bias_linear,
             init_method=c.init_method(),
             sequence_parallel_enabled=c.sequence_parallel,
             params_dtype=c.params_dtype, axis_name=c.axis_name)
         self.dense_4h_to_h = RowParallelLinear(
             c.ffn_size, c.hidden_size, input_is_parallel=True,
+            bias=c.add_bias_linear,
             init_method=c.output_init_method(),
             sequence_parallel_enabled=c.sequence_parallel,
             params_dtype=c.params_dtype, axis_name=c.axis_name)
@@ -415,9 +504,17 @@ class ParallelAttention:
 
     config: TransformerConfig
     attn_type: Any = AttnType.self_attn
+    #: "default" (the config's one window), or this layer's kind in a
+    #: model whose layers differ: "full" | "sliding"
+    layer_kind: str = "default"
 
     def __post_init__(self):
         c = self.config
+        self.window = (None if self.layer_kind == "full"
+                       else c.sliding_window)
+        self.rope = (c.position_embedding_type == "rope"
+                     and self.layer_kind != "full")
+        proj = c.num_attention_heads * c.head_dim
         if self.attn_type == AttnType.self_attn:
             # fused QKV, grouped layout [g0: qpg·dh + k·dh + v·dh | g1: ...]
             # so a TP slice holds whole K/V groups (Megatron fuses the same
@@ -426,6 +523,7 @@ class ParallelAttention:
             qkv_size = c.kv_heads * (qpg + 2) * c.head_dim
             self.query_key_value = ColumnParallelLinear(
                 c.hidden_size, qkv_size, gather_output=False,
+                bias=c.add_bias_linear,
                 init_method=c.init_method(),
                 sequence_parallel_enabled=c.sequence_parallel,
                 params_dtype=c.params_dtype, axis_name=c.axis_name)
@@ -445,10 +543,18 @@ class ParallelAttention:
                 sequence_parallel_enabled=False,
                 params_dtype=c.params_dtype, axis_name=c.axis_name)
         self.dense = RowParallelLinear(
-            c.hidden_size, c.hidden_size, input_is_parallel=True,
+            proj, c.hidden_size, input_is_parallel=True,
+            bias=c.add_bias_linear,
             init_method=c.output_init_method(),
             sequence_parallel_enabled=c.sequence_parallel,
             params_dtype=c.params_dtype, axis_name=c.axis_name)
+        self.gate = None
+        if c.attention_output_gate:
+            self.gate = ColumnParallelLinear(
+                c.hidden_size, proj, gather_output=False, bias=False,
+                init_method=c.init_method(),
+                sequence_parallel_enabled=c.sequence_parallel,
+                params_dtype=c.params_dtype, axis_name=c.axis_name)
         self.scale_mask_softmax = FusedScaleMaskSoftmax(
             attn_mask_type=(AttnMaskType.padding
                             if self.attn_type == AttnType.cross_attn
@@ -459,20 +565,35 @@ class ParallelAttention:
     def init(self, key):
         k1, k2 = jax.random.split(key)
         if self.attn_type == AttnType.self_attn:
-            return {"query_key_value": self.query_key_value.init(k1),
-                    "dense": self.dense.init(k2)}
-        k1a, k1b = jax.random.split(k1)
-        return {"query": self.query.init(k1a),
-                "key_value": self.key_value.init(k1b),
-                "dense": self.dense.init(k2)}
+            p = {"query_key_value": self.query_key_value.init(k1),
+                 "dense": self.dense.init(k2)}
+        else:
+            k1a, k1b = jax.random.split(k1)
+            p = {"query": self.query.init(k1a),
+                 "key_value": self.key_value.init(k1b),
+                 "dense": self.dense.init(k2)}
+        c = self.config
+        if self.gate is not None:
+            p["gate"] = self.gate.init(jax.random.fold_in(key, 2))
+        if c.qk_layernorm:
+            for name in ("q_layernorm", "k_layernorm"):
+                p[name] = {"weight": jnp.ones((c.head_dim,), c.params_dtype)}
+        return p
 
     def spec(self):
         if self.attn_type == AttnType.self_attn:
-            return {"query_key_value": self.query_key_value.spec(),
-                    "dense": self.dense.spec()}
-        return {"query": self.query.spec(),
-                "key_value": self.key_value.spec(),
-                "dense": self.dense.spec()}
+            s = {"query_key_value": self.query_key_value.spec(),
+                 "dense": self.dense.spec()}
+        else:
+            s = {"query": self.query.spec(),
+                 "key_value": self.key_value.spec(),
+                 "dense": self.dense.spec()}
+        if self.gate is not None:
+            s["gate"] = self.gate.spec()
+        if self.config.qk_layernorm:
+            for name in ("q_layernorm", "k_layernorm"):
+                s[name] = {"weight": PartitionSpec()}
+        return s
 
     def _core_attention(self, q, k, v, attention_mask, kv_lengths,
                         rng, deterministic, window=None):
@@ -566,7 +687,7 @@ class ParallelAttention:
                          model_parallel_region=True, axis_name=c.axis_name)
         return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
-    def _flat_cache_attention(self, params, q, k, v, ck, cv, cache_index,
+    def _flat_cache_attention(self, out_proj, q, k, v, ck, cv, cache_index,
                               attention_mask, kv_lengths, rng,
                               deterministic):
         """Incremental decode over a FLAT ``[b, S, kvh*dh]`` cache pair.
@@ -615,9 +736,9 @@ class ParallelAttention:
         slots = jnp.arange(S)[None, None, None, :]
         allowed_up_to = ci + jnp.arange(s)[None, None, :, None]
         invalid = slots > allowed_up_to
-        if c.sliding_window is not None:
+        if self.window is not None:
             invalid = jnp.logical_or(
-                invalid, slots <= allowed_up_to - c.sliding_window)
+                invalid, slots <= allowed_up_to - self.window)
         if kv_lengths is not None:
             invalid = jnp.logical_or(
                 invalid, slots >= kv_lengths[:, None, None, None])
@@ -670,8 +791,7 @@ class ParallelAttention:
             ctx = jnp.einsum("bjkd,jk->bjd",
                              ctx_big.reshape(b, hl, kvh, dh), sel)
             ctx = ctx.reshape(b, hl * dh)[None]           # [1, b, hl*dh]
-            out = self.dense.apply(params["dense"], ctx)
-            return out, (ck, cv)
+            return out_proj(ctx), (ck, cv)
         K4 = ck.reshape(b, S, kvh, dh).astype(q.dtype)
         V4 = cv.reshape(b, S, kvh, dh).astype(q.dtype)
         qg = q.reshape(b, kvh, g, s, dh)
@@ -683,8 +803,7 @@ class ParallelAttention:
         pg = probs.astype(V4.dtype).reshape(b, kvh, g, s, S)
         ctx = jnp.einsum("bhgqk,bkhd->bhgqd", pg, V4).reshape(b, hl, s, dh)
         ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, hl * dh)
-        out = self.dense.apply(params["dense"], ctx)
-        return out, (ck, cv)
+        return out_proj(ctx), (ck, cv)
 
     @nvtx_range(SCOPE_ATTENTION)
     def apply(self, params, hidden, *, encoder_output=None,
@@ -719,6 +838,17 @@ class ParallelAttention:
         """
         c = self.config
         dh = c.head_dim
+        gate = (None if self.gate is None
+                else self.gate.apply(params["gate"], hidden))
+
+        def out_proj(ctx):
+            """``ctx [s, b, heads * dh]`` -> the block's output: the
+            sigmoid gate (float32, one rounding) and then ``W_o``."""
+            if gate is not None:
+                ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(ctx.dtype)
+            return self.dense.apply(params["dense"], ctx)
+
         if self.attn_type == AttnType.self_attn:
             qkv = self.query_key_value.apply(params["query_key_value"],
                                              hidden)
@@ -747,11 +877,12 @@ class ParallelAttention:
             if (kv_cache is None and cache_index is None
                     and attention_mask is None
                     and not c.context_parallel_method
+                    and not c.qk_layernorm and gate is None
                     and (not drop_active or rng is not None)
                     and packed_attention_supported(s, local_groups, qpg,
                                                    dh)):
                 freqs = None
-                if c.position_embedding_type == "rope":
+                if self.rope:
                     # positions start at 0: no cache offset (cache_index
                     # gated above) and no bound context axis (CP gated
                     # above)
@@ -772,18 +903,23 @@ class ParallelAttention:
                     qkv, queries_per_group=qpg, head_dim=dh,
                     causal=c.attn_mask_type == AttnMaskType.causal,
                     kv_lengths=kv_lengths,
-                    sliding_window=c.sliding_window,
+                    sliding_window=self.window,
                     rope_freqs=freqs,
                     dropout_rate=(c.attention_dropout if drop_active
                                   else 0.0),
                     dropout_seed=seed)
-                return self.dense.apply(params["dense"], ctx)
+                return out_proj(ctx)
             qkv = qkv.reshape(s, b, local_groups, qpg + 2, dh)
             q = qkv[:, :, :, :qpg].reshape(s, b, local_groups * qpg, dh)
             k = qkv[:, :, :, qpg]
             v = qkv[:, :, :, qpg + 1]
             local_heads = local_groups * qpg
-            if c.position_embedding_type == "rope":
+            if c.qk_layernorm:
+                q = _head_rms(q, params["q_layernorm"]["weight"],
+                              c.layernorm_epsilon)
+                k = _head_rms(k, params["k_layernorm"]["weight"],
+                              c.layernorm_epsilon)
+            if self.rope:
                 from apex_tpu.ops import fused_rope
                 from apex_tpu.transformer.tensor_parallel.mappings import (
                     axis_bound,
@@ -852,7 +988,7 @@ class ParallelAttention:
                 res = fused_paged_decode_attention(
                     qw, kw, vw, ck, cv, paged_state, cache_index,
                     queries_per_group=local_heads // kvh_l,
-                    sliding_window=c.sliding_window,
+                    sliding_window=self.window,
                     k_scales=k_scales, v_scales=v_scales)
                 if k_scales is not None:
                     ctx, ck, cv, k_scales, v_scales = res
@@ -861,9 +997,7 @@ class ParallelAttention:
                     ctx, ck, cv = res
                     new = (ck, cv)
                 # ctx [b, s, hl*dh] -> [s, b, hl*dh] for the dense proj
-                out = self.dense.apply(params["dense"],
-                                       ctx.transpose(1, 0, 2))
-                return out, new
+                return out_proj(ctx.transpose(1, 0, 2)), new
             if ck.ndim == 3:
                 # FLAT decode cache [b, S, local_kv_heads*dh]: with the 4D
                 # [b, h, S, d] carry XLA picks a layout whose minor dim is
@@ -873,7 +1007,7 @@ class ParallelAttention:
                 # at h*d (>= 128) and the whole cache stream full-lane
                 # (PERF.md round 5: bs8 decode 10.4k -> 13.8k tok/s)
                 out, new_cache = self._flat_cache_attention(
-                    params, q, k, v, ck, cv, cache_index, attention_mask,
+                    out_proj, q, k, v, ck, cv, cache_index, attention_mask,
                     kv_lengths, rng, deterministic)
                 return out, new_cache
             if getattr(cache_index, "ndim", 0) == 1:
@@ -897,10 +1031,10 @@ class ParallelAttention:
                 ctx = flash_attention(
                     q, ck[:, :, :s].astype(q.dtype),
                     cv[:, :, :s].astype(q.dtype), causal=True,
-                    sliding_window=c.sliding_window)
+                    sliding_window=self.window)
                 ctx = ctx.transpose(2, 0, 1, 3).reshape(
                     s, b, local_heads * dh)
-                return self.dense.apply(params["dense"], ctx), new_cache
+                return out_proj(ctx), new_cache
             k, v = ck.astype(q.dtype), cv.astype(q.dtype)
             # per-query causal+prefix mask over the padded cache: query i of
             # the slice may see slots j <= cache_index + i (the dispatcher's
@@ -909,18 +1043,18 @@ class ParallelAttention:
             slots = jnp.arange(k.shape[2])[None, None, None, :]
             allowed_up_to = cache_index + jnp.arange(s)[None, None, :, None]
             invalid = slots > allowed_up_to
-            if c.sliding_window is not None:
+            if self.window is not None:
                 invalid = jnp.logical_or(
-                    invalid, slots <= allowed_up_to - c.sliding_window)
+                    invalid, slots <= allowed_up_to - self.window)
             attention_mask = (invalid if attention_mask is None
                               else jnp.logical_or(attention_mask, invalid))
-        window = (c.sliding_window
+        window = (self.window
                   if (self.attn_type == AttnType.self_attn
                       and kv_cache is None) else None)
         ctx = self._core_attention(q, k, v, attention_mask, kv_lengths,
                                    rng, deterministic, window=window)
         ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, local_heads * dh)
-        out = self.dense.apply(params["dense"], ctx)
+        out = out_proj(ctx)
         return out if new_cache is None else (out, new_cache)
 
 
@@ -937,17 +1071,35 @@ class ParallelTransformerLayer:
 
     config: TransformerConfig
     layer_type: Any = LayerType.encoder
+    #: this layer's ``(attention kind, feed-forward kind)``, an entry of
+    #: ``TransformerConfig.layer_kinds``
+    layer_kind: Tuple[str, str] = ("default", "dense")
 
     def __post_init__(self):
         c = self.config
-        self.attention = ParallelAttention(c)
+        self.attention = ParallelAttention(c, layer_kind=self.layer_kind[0])
+        self.routed = self.layer_kind[1] == "routed"
         if self.layer_type == LayerType.decoder:
             # decoder blocks add cross-attention over the encoder output
             # (reference ParallelTransformerLayer inter_attention branch,
             # standalone_transformer_lm.py ~:1090-1115)
             self.inter_attention = ParallelAttention(
                 c, attn_type=AttnType.cross_attn)
-        if c.num_moe_experts:
+        if self.routed:
+            from apex_tpu.transformer.moe import (RoutedExperts,
+                                                  RoutedMoEConfig)
+            self.mlp = RoutedExperts(RoutedMoEConfig(
+                hidden_size=c.hidden_size,
+                ffn_hidden_size=c.routed_ffn_hidden_size,
+                num_experts=c.num_routed_experts,
+                top_k=c.routed_top_k,
+                route_scale=c.route_scale,
+                num_shared_experts=c.num_shared_experts,
+                expert_range=c.routed_expert_range,
+                params_dtype=c.params_dtype,
+                compute_dtype=c.compute_dtype,
+                init_method_std=c.init_method_std))
+        elif c.num_moe_experts:
             from apex_tpu.transformer.moe import MoEConfig, SwitchMLP
             self.mlp = SwitchMLP(MoEConfig(
                 hidden_size=c.hidden_size,
@@ -976,6 +1128,11 @@ class ParallelTransformerLayer:
                 c.hidden_size, c.params_dtype, c.normalization),
             "mlp": self.mlp.init(k2),
         }
+        if c.sandwich_norm:
+            # post_attention_layernorm then norms the attention OUTPUT
+            for name in ("pre_mlp_layernorm", "post_mlp_layernorm"):
+                p[name] = _ln_params(c.hidden_size, c.params_dtype,
+                                     c.normalization)
         if self.layer_type == LayerType.decoder:
             p["inter_attention"] = self.inter_attention.init(k3)
             p["post_inter_attention_layernorm"] = _ln_params(
@@ -990,6 +1147,9 @@ class ParallelTransformerLayer:
             "post_attention_layernorm": _ln_spec(norm),
             "mlp": self.mlp.spec(),
         }
+        if self.config.sandwich_norm:
+            s["pre_mlp_layernorm"] = _ln_spec(norm)
+            s["post_mlp_layernorm"] = _ln_spec(norm)
         if self.layer_type == LayerType.decoder:
             s["inter_attention"] = self.inter_attention.spec()
             s["post_inter_attention_layernorm"] = _ln_spec(norm)
@@ -1000,7 +1160,7 @@ class ParallelTransformerLayer:
               attention_mask=None, kv_lengths=None, kv_cache=None,
               cache_index=None, rng=None, deterministic=True,
               moe_drop_free=None, attention_seed=None, paged_state=None,
-              lora=None):
+              lora=None, routing=None):
         """``encoder_output`` (decoder layers) must be the FULL encoder
         sequence ``[s_enc, b, h]`` — under sequence parallelism gather it
         first (``gather_from_sequence_parallel_region``), as
@@ -1008,7 +1168,9 @@ class ParallelTransformerLayer:
         ``enc_kv_lengths`` ([batch] valid encoder lengths) keeps padded
         cross-attention on the varlen flash path instead of a boolean
         ``enc_dec_attn_mask``. With ``kv_cache`` (incremental decoding) the
-        return becomes ``(out, new_cache)``."""
+        return becomes ``(out, new_cache)``. ``routing``: a
+        ``transformer.moe.RoutingStats`` that a routed layer's call
+        reports its counts to."""
         c = self.config
         decoder = self.layer_type == LayerType.decoder
         # decoder layers draw a 4th key; encoder layers keep the historical
@@ -1028,6 +1190,13 @@ class ParallelTransformerLayer:
         new_cache = None
         if kv_cache is not None:
             attn_out, new_cache = attn_out
+
+        def norm(name, x):
+            return _ln(params[name], x, c.layernorm_epsilon,
+                       c.sequence_parallel, c.axis_name, c.normalization)
+
+        if c.sandwich_norm:
+            attn_out = norm("post_attention_layernorm", attn_out)
         attn_out = _dropout(attn_out, c.hidden_dropout, rngs[0], deterministic,
                             model_parallel_region=c.sequence_parallel,
                             axis_name=c.axis_name)
@@ -1051,11 +1220,15 @@ class ParallelTransformerLayer:
             hidden = hidden + inter_out
             norm_name = "post_inter_attention_layernorm"
         else:
-            norm_name = "post_attention_layernorm"
-        x = _ln(params[norm_name], hidden,
-                c.layernorm_epsilon, c.sequence_parallel, c.axis_name,
-                c.normalization)
-        if c.num_moe_experts:
+            norm_name = ("pre_mlp_layernorm" if c.sandwich_norm
+                         else "post_attention_layernorm")
+        x = norm(norm_name, hidden)
+        aux = None
+        if self.routed:
+            # the serving layer: drop-free by construction, no aux loss
+            mlp_out = self.mlp.apply(params["mlp"],
+                                     x.astype(c.compute_dtype), routing)
+        elif c.num_moe_experts:
             moe_rng = (None if rngs[1] is None
                        else jax.random.fold_in(rngs[1], 1))
             # drop-free routing on the whole generation path (prefill AND
@@ -1081,7 +1254,8 @@ class ParallelTransformerLayer:
                     params["mlp"], x.astype(c.compute_dtype),
                     lora=None if lora is None
                     else lora.get("dense_h_to_4h"))
-            aux = None
+        if c.sandwich_norm:
+            mlp_out = norm("post_mlp_layernorm", mlp_out)
         mlp_out = _dropout(mlp_out, c.hidden_dropout, rngs[1], deterministic,
                            model_parallel_region=c.sequence_parallel,
                            axis_name=c.axis_name)
@@ -1107,21 +1281,38 @@ class ParallelTransformer:
     layer_type: Any = LayerType.encoder
 
     def __post_init__(self):
-        self.layer = ParallelTransformerLayer(self.config, self.layer_type)
+        # one layer object per KIND of layer; a model of one kind has one
+        # (``self.layer``) and stacks its parameters for ``lax.scan``, a
+        # mixed model holds a per-layer LIST of parameter trees
+        self.layer_kinds = self.config.layer_kinds
+        self.layers = {
+            kind: ParallelTransformerLayer(self.config, self.layer_type,
+                                           layer_kind=kind)
+            for kind in dict.fromkeys(self.layer_kinds)}
+        self.layer = self.layers[self.layer_kinds[0]]
+
+    def _layer(self, idx: int) -> ParallelTransformerLayer:
+        return self.layers[self.layer_kinds[idx]]
 
     def init(self, key):
         keys = jax.random.split(key, self.config.num_layers)
-        stacked = jax.vmap(self.layer.init)(keys)
+        if self.config.mixed_layers:
+            stacked = [self._layer(i).init(k) for i, k in enumerate(keys)]
+        else:
+            stacked = jax.vmap(self.layer.init)(keys)
         return {"layers": stacked,
                 "final_layernorm": _ln_params(
                     self.config.hidden_size, self.config.params_dtype,
                     self.config.normalization)}
 
     def spec(self):
-        layer_spec = self.layer.spec()
-        stacked = jax.tree.map(
-            lambda s: PartitionSpec(None, *s), layer_spec,
-            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        if self.config.mixed_layers:
+            stacked = [self._layer(i).spec()
+                       for i in range(self.config.num_layers)]
+        else:
+            stacked = jax.tree.map(
+                lambda s: PartitionSpec(None, *s), self.layer.spec(),
+                is_leaf=lambda x: isinstance(x, PartitionSpec))
         return {"layers": stacked,
                 "final_layernorm": _ln_spec(self.config.normalization)}
 
@@ -1130,7 +1321,7 @@ class ParallelTransformer:
               attention_mask=None, kv_lengths=None, kv_caches=None,
               cache_index=None, rng=None, deterministic=True,
               final_norm=True, moe_drop_free=None, paged_state=None,
-              lora=None):
+              lora=None, routing=None):
         """Returns ``hidden`` — or ``(hidden, moe_aux_loss)`` (aux summed
         over layers) when the config enables MoE, or ``(hidden, new_caches)``
         when decoding with ``kv_caches`` — either ``(k, v)`` stacked
@@ -1139,7 +1330,8 @@ class ParallelTransformer:
         is the fast decode path: scanning over a stacked cache pays
         full-cache slice/restack copies every step (measured 2.4x slower
         at bs8 — PERF.md round 4), while per-layer buffers update in
-        place."""
+        place. ``routing`` (list form): a ``transformer.moe.RoutingStats``
+        handed to every layer, for the routed layers' counts."""
         c = self.config
         moe = bool(c.num_moe_experts)
 
@@ -1233,7 +1425,7 @@ class ParallelTransformer:
                 # by the caller); slice this layer's factors
                 layer_lora = (None if lora is None
                               else jax.tree.map(lambda x: x[idx], lora))
-                h, new_cache = self.layer.apply(
+                h, new_cache = self._layer(idx).apply(
                     layer_params, h, encoder_output=encoder_output,
                     enc_dec_attn_mask=enc_dec_attn_mask,
                     enc_kv_lengths=enc_kv_lengths,
@@ -1243,12 +1435,44 @@ class ParallelTransformer:
                     deterministic=deterministic,
                     moe_drop_free=moe_drop_free,
                     attention_seed=_attn_seed(idx),
-                    paged_state=paged_state, lora=layer_lora)
+                    paged_state=paged_state, lora=layer_lora,
+                    routing=routing)
                 new_caches.append(new_cache)
             if final_norm:
                 h = _ln(params["final_layernorm"], h, c.layernorm_epsilon,
                         c.sequence_parallel, c.axis_name, c.normalization)
             return h, new_caches
+
+        if c.mixed_layers:
+            if kv_caches is not None:
+                raise NotImplementedError(
+                    "a model whose layers differ (attention_layer_types / "
+                    "num_dense_layers) decodes over the per-layer LIST "
+                    "cache form; the stacked (k, v) scan form needs layers "
+                    "of one kind")
+            if c.recompute:
+                raise NotImplementedError(
+                    "recompute runs inside the lax.scan layer stack, which "
+                    "needs layers of one kind; training a mixed model is "
+                    "not wired up (ROADMAP M1)")
+            # the cache-free forward of a mixed model: the same per-layer
+            # list, unrolled (what the serving programs run, minus a cache)
+            h = hidden
+            for idx, layer_params in enumerate(params["layers"]):
+                h = self._layer(idx).apply(
+                    layer_params, h, encoder_output=encoder_output,
+                    enc_dec_attn_mask=enc_dec_attn_mask,
+                    enc_kv_lengths=enc_kv_lengths,
+                    attention_mask=attention_mask, kv_lengths=kv_lengths,
+                    rng=(None if rng is None
+                         else jax.random.fold_in(rng, idx)),
+                    deterministic=deterministic,
+                    moe_drop_free=moe_drop_free,
+                    attention_seed=_attn_seed(idx))
+            if final_norm:
+                h = _ln(params["final_layernorm"], h, c.layernorm_epsilon,
+                        c.sequence_parallel, c.axis_name, c.normalization)
+            return h
 
         def one_layer(carry, xs):
             h, aux_sum, idx = carry

@@ -730,6 +730,23 @@ def render_report(report: dict) -> str:
             lines.append(
                 f"  kv bytes/step (final): "
                 f"{int(gauges['kv_bytes_per_step']):,}")
+        hists = report.get("histograms") or {}
+        touched = hists.get("moe_experts_touched")
+        if isinstance(touched, dict) and touched.get("count"):
+            # routed expert layers: how much of the experts' weights a
+            # decode step streamed, the straggler, and the pages a
+            # window layer holds but will never read again
+            busiest = hists.get("moe_max_expert_rows") or {}
+            line = (f"  routed experts: rows={counters.get('moe_rows_routed', 0)}"
+                    f" touched/call mean={_fmt(touched.get('mean'))} "
+                    f"min={_fmt(touched.get('min'))} n={touched['count']}"
+                    f"  busiest expert rows mean={_fmt(busiest.get('mean'))} "
+                    f"max={_fmt(busiest.get('max'))}")
+            lines.append(line)
+        if "kv_pages_out_of_window" in gauges:
+            lines.append(
+                f"  kv pages out of window (final): "
+                f"{_fmt(gauges['kv_pages_out_of_window'])}")
         proposed = counters.get("draft_tokens_proposed", 0)
         if proposed:
             # speculative decoding: accepted/proposed is the fleet-wide

@@ -74,6 +74,13 @@ SCOPE_OPTIMIZER = "optimizer"                   # resilience.py: the update
 SCOPE_LOSS_SCALE = "loss_scale"                 # unscale + finite check
 SCOPE_TP_ALL_REDUCE = "tp_all_reduce"           # tensor_parallel/mappings.py
 SCOPE_DP_GRAD_ALL_REDUCE = "dp_grad_all_reduce"  # resilience.py
+SCOPE_MOE = "moe"                               # transformer/moe.py: the
+#                                                 whole routed layer
+SCOPE_MOE_ROUTER = "moe_router"                 # scores, top-k, sort, layout
+SCOPE_MOE_EXPERTS = "moe_experts"               # ops/grouped_matmul.py: the
+#                                                 routed products (Pallas
+#                                                 calls moe_experts_up/_down)
+SCOPE_MOE_SHARED = "moe_shared"                 # the shared expert
 
 
 @contextlib.contextmanager

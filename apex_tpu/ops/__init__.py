@@ -26,6 +26,7 @@ from apex_tpu.ops.decode_attention import (
     fused_paged_decode_attention,
     paged_pages_for,
 )
+from apex_tpu.ops.grouped_matmul import grouped_gated_ffn, routed_layout
 from apex_tpu.ops.rope import (
     fused_rope,
     fused_rope_cached,
@@ -55,4 +56,6 @@ __all__ = [
     "ulysses_attention",
     "fused_paged_decode_attention",
     "paged_pages_for",
+    "grouped_gated_ffn",
+    "routed_layout",
 ]

@@ -301,7 +301,11 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     its row equals dequantizing that head. Pages past the slot's valid
     length are skipped (their DMA is the residual cost of the
     rectangular grid — one page per slot, since consecutive sentinel
-    entries clamp to the same block and Pallas does not re-fetch it)."""
+    entries clamp to the same block and Pallas does not re-fetch it).
+    Under a ``sliding_window`` a page that lies wholly before the window
+    of the slot's FIRST query is skipped the same way, and is never
+    fetched: the index map (:func:`_page_index`) pins those grid steps
+    to the first page in the window."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -317,7 +321,13 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * page_size <= pos + (window - 1))
+    live = j * page_size <= pos + (window - 1)
+    if sliding_window is not None:
+        # the page's last row is still inside the first query's window
+        live = jnp.logical_and(
+            live, (j + 1) * page_size > pos - sliding_window + 1)
+
+    @pl.when(live)
     def _accumulate():
         qb = q_ref[0]                                     # [m, f]
         kb = k_ref[0].astype(qb.dtype)                    # [ps, f]
@@ -369,6 +379,22 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _page_index(page_size, sliding_window):
+    """The K/V block index map: grid step ``(r, j)`` reads pool row
+    ``page_table[r, j]``. Under a window, steps before the first page
+    that holds a visible row read THAT page instead (the same block as
+    the step that will use it, so the pipeline fetches it once and the
+    pages before the window are never read)."""
+    if sliding_window is None:
+        return lambda r, j, pt, pos: (pt[r, j], 0, 0)
+
+    def index(r, j, pt, pos):
+        first = jnp.maximum(pos[r] - sliding_window + 1, 0) // page_size
+        return (pt[r, jnp.maximum(j, first)], 0, 0)
+
+    return index
+
+
 def _query_block(q, kv_head, kvh):
     """``q`` ``[b, w, hl, dh]`` -> the block-masked ``[b, w*hl, kvh*dh]``
     query matrix: each query's vector sits in the lane block of its K/V
@@ -411,12 +437,11 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
         _decode_kernel, page_size=page_size, heads=hl, window=w,
         quantized=quantized, sliding_window=sliding_window,
         scale=1.0 / float(dh) ** 0.5)
+    page = _page_index(page_size, sliding_window)
     in_specs = [
         pl.BlockSpec((1, m, f), lambda r, j, pt, pos: (r, 0, 0)),
-        pl.BlockSpec((1, page_size, f),
-                     lambda r, j, pt, pos: (pt[r, j], 0, 0)),
-        pl.BlockSpec((1, page_size, f),
-                     lambda r, j, pt, pos: (pt[r, j], 0, 0)),
+        pl.BlockSpec((1, page_size, f), page),
+        pl.BlockSpec((1, page_size, f), page),
     ]
     inputs = [pt, positions.astype(jnp.int32),
               _query_block(q, kv_head, kvh), k_pages, v_pages]

@@ -118,6 +118,7 @@ from apex_tpu.serving.scheduler import (
     prefill_buckets,
 )
 from apex_tpu.serving.slots import PagePool, SlotPool
+from apex_tpu.transformer.moe import RoutingStats
 from apex_tpu.serving.speculation import propose_draft
 from apex_tpu.utils.logging import get_logger, log_event
 from apex_tpu.utils.profiling import nvtx_range
@@ -439,6 +440,18 @@ class InferenceEngine:
             self.metrics.declare_counters(
                 *(f"adapter{ix}_requests"
                   for ix in range(self.adapters.max_adapters)))
+        #: routed expert layers (transformer/moe.py RoutedExperts): the
+        #: decode program then appends each layer call's routing counts
+        #: to the token vector it returns, one read-back for both
+        #: (docs/serving.md#routed-experts-and-mixed-layer-kinds)
+        self._routed = bool(getattr(c, "num_routed_experts", None))
+        if self._routed:
+            self.metrics.declare_counters("moe_rows_routed")
+        #: share of the layers that are window layers, for the
+        #: kv_pages_out_of_window gauge (0 = one kind of layer)
+        kinds = getattr(c, "attention_layer_types", None) or ()
+        self._window_share = (kinds.count("sliding") / len(kinds)
+                              if kinds else 0.0)
         self.scheduler = FCFSScheduler(self.config.scheduler)
         self.slots = SlotPool(self.config.max_slots)
         self.buckets = prefill_buckets(self.config.max_len)
@@ -580,16 +593,41 @@ class InferenceEngine:
     # -- step programs (overridable: ShardedEngine wraps these bodies in
     # -- shard_map over the device mesh) ----------------------------------
 
+    def _routing(self, positions) -> Optional[RoutingStats]:
+        """The collector a decode body hands down its forward: None for a
+        model without routed layers. An idle slot is fed position 0
+        (``_clear_slot``) and a live one is past its prompt, so
+        ``positions > 0`` are the rows that count."""
+        return RoutingStats(active=positions > 0) if self._routed else None
+
+    @staticmethod
+    def _with_routing(nxt, stats: Optional[RoutingStats]):
+        """The tokens a decode body returns; with routed layers the int32
+        ``[calls, 3]`` counts (``transformer.moe.ROUTING_STATS``) ride
+        behind them, flattened, so the tick reads both back at once
+        (``_split_routing`` parts them on the host)."""
+        if stats is None:
+            return nxt
+        return jnp.concatenate([nxt.reshape(-1),
+                                stats.stacked().reshape(-1)])
+
+    def _split_routing(self, nxt: np.ndarray):
+        shape = (self._window_h if self._spec else self._tokens_h).shape
+        size = int(np.prod(shape))
+        return nxt[:size].reshape(shape), nxt[size:].reshape(-1, 3)
+
     def _decode_body(self, params, caches, tokens, positions, temps,
                      topks, seeds, adapter_ix, lora):
+        stats = self._routing(positions)
         logits, caches = decode_step(self.model, params, caches, tokens,
                                      positions,
-                                     lora=_select_adapters(lora, adapter_ix))
+                                     lora=_select_adapters(lora, adapter_ix),
+                                     routing=stats)
         nxt = _sample_tokens(logits, temps, topks, seeds, positions + 1)
         # per-slot integrity flag: one cheap in-jit reduction so the
         # host can quarantine a poisoned row without fetching logits
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        return nxt, finite, caches
+        return self._with_routing(nxt, stats), finite, caches
 
     def _scrub_body(self, caches, slot):
         # zero one slot's KV rows across every layer — quarantine
@@ -628,12 +666,14 @@ class InferenceEngine:
         # row scatter + masked read; with the pool donated the appends
         # are in-place row writes, so per step the KV traffic is one
         # read of the mapped stream plus one row
+        stats = self._routing(positions)
         logits, caches = decode_step(self.model, params, caches, tokens,
                                      positions, paged_state=page_table,
-                                     lora=_select_adapters(lora, adapter_ix))
+                                     lora=_select_adapters(lora, adapter_ix),
+                                     routing=stats)
         nxt = _sample_tokens(logits, temps, topks, seeds, positions + 1)
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        return nxt, finite, caches
+        return self._with_routing(nxt, stats), finite, caches
 
     def _spec_decode_body(self, params, caches, page_table, windows,
                           positions, temps, topks, seeds, adapter_ix,
@@ -649,16 +689,18 @@ class InferenceEngine:
         # acceptance loop then consumes exactly the prefix the
         # sequential engine would have produced.
         n, k = windows.shape
+        stats = self._routing(positions)
         logits, caches = _cached_forward(
             self.model, params, caches, windows, positions,
             paged_state=page_table,
-            lora=_select_adapters(lora, adapter_ix))      # [k, n, V]
+            lora=_select_adapters(lora, adapter_ix),
+            routing=stats)                                # [k, n, V]
         lf = logits.transpose(1, 0, 2).reshape(n * k, -1)
         steps = (positions[:, None] + 1 + jnp.arange(k)[None, :]).reshape(-1)
         nxt = _sample_tokens(lf, jnp.repeat(temps, k), jnp.repeat(topks, k),
                              jnp.repeat(seeds, k), steps)
         finite = jnp.all(jnp.isfinite(logits), axis=-1).T  # [n, k]
-        return nxt.reshape(n, k), finite, caches
+        return self._with_routing(nxt.reshape(n, k), stats), finite, caches
 
     def _paged_scrub_body(self, caches, page_row):
         # zero exactly the quarantined slot's mapped pages across every
@@ -1169,6 +1211,10 @@ class InferenceEngine:
                                            self.pages.free_count)
                     self.metrics.observe("kv_page_occupancy",
                                          self.pages.occupancy)
+                    if self._window_share:
+                        self.metrics.set_gauge(
+                            "kv_pages_out_of_window",
+                            self._pages_out_of_window())
                     delta = self.pages.evictions - self._evictions_seen
                     if delta:
                         self.metrics.inc("prefix_evictions", delta)
@@ -1948,6 +1994,17 @@ class InferenceEngine:
             self._window_h[slot] = window
             self._wlen_h[slot] = wl
 
+    def _pages_out_of_window(self) -> float:
+        """Pages mapped whose every position lies before ``position -
+        window`` of their slot, times the share of layers that are window
+        layers: what an allocator with a second, windowed kind of page row
+        would free (one page row per slot backs every layer today)."""
+        window = self.model.config.sliding_window
+        ps = self.config.page_size
+        dead = sum(max(0, (rec.position - window) // ps)
+                   for rec in self._active.values())
+        return dead * self._window_share
+
     def _decode_args(self) -> tuple:
         """The decode program's arguments from the current host arrays
         (paged: the page table rides right after the pool; with
@@ -2002,6 +2059,16 @@ class InferenceEngine:
             back.set_metadata(bytes=nxt.nbytes + finite.nbytes)
         with span(TICK_COMMIT) as commit:
             retired = len(finished)
+            if self._routed:
+                nxt, routing = self._split_routing(nxt)
+                for rows, touched, busiest in routing:
+                    # one routed layer call of this step: how many of the
+                    # experts' weights it had to stream, and the straggler
+                    self.metrics.inc("moe_rows_routed", int(rows))
+                    self.metrics.observe("moe_experts_touched",
+                                         int(touched))
+                    self.metrics.observe("moe_max_expert_rows",
+                                         int(busiest))
             if self._faults is not None:
                 nxt, finite = self._faults.corrupt_decode(nxt, finite)
             self.metrics.inc("decode_steps")

@@ -43,7 +43,22 @@ def prefix_salt(config) -> str:
             f"{getattr(config, 'num_attention_heads', 0)}:"
             f"{getattr(config, 'kv_heads', 0)}:"
             f"{getattr(config, 'vocab_size', 0)}:"
-            f"{getattr(config, 'position_embedding_type', '')}")
+            f"{getattr(config, 'position_embedding_type', '')}"
+            + _kinds_salt(config))
+
+
+def _kinds_salt(config) -> str:
+    """What the fingerprint adds for a stated head size and for layers of
+    more than one kind (both shape the cached K/V); empty for every model
+    that has neither, whose salt stays as it was."""
+    extra = ""
+    if getattr(config, "kv_channels", None) is not None:
+        extra += f":dh{config.kv_channels}"
+    kinds = getattr(config, "attention_layer_types", None)
+    if kinds is not None:
+        extra += ":" + "".join(k[0] for k in kinds) \
+            + f"w{config.sliding_window}"
+    return extra
 
 
 def adapter_salt(salt: str, adapter_id=None) -> str:

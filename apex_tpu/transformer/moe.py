@@ -20,6 +20,22 @@
 Layout follows the transformer stack: ``[s, b, h]`` activations, functional
 ``init/apply``, works inside ``shard_map`` next to
 :class:`~apex_tpu.models.transformer.ParallelTransformerLayer`.
+
+Two layers live here, for two jobs:
+
+- :class:`SwitchMLP` is the TRAINING layer: softmax top-1/top-2 with a
+  capacity factor, dropped tokens, the load-balancing loss and the
+  ``all_to_all`` exchange over an expert axis. Its drop-free mode (every
+  local expert over every token) exists so that a model trained with it
+  can be decoded without capacity drops; it is not a serving kernel.
+- :class:`RoutedExperts` is the SERVING layer of a sparse-expert model
+  (docs/moe.md): any ``top_k``, sigmoid scores with a selection bias,
+  normalised and scaled weights, a shared expert, no capacity and no
+  dropped token. Rows are sorted by expert and multiplied by the weights of
+  the experts they chose and no others
+  (:mod:`apex_tpu.ops.grouped_matmul`). It is told which experts of the
+  layer it holds (``expert_range``) and computes their part of the result;
+  on one chip it holds all and runs without an exchange.
 """
 
 from __future__ import annotations
@@ -32,6 +48,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
+from apex_tpu.observability.tracing import (SCOPE_MOE, SCOPE_MOE_ROUTER,
+                                            SCOPE_MOE_SHARED)
 from apex_tpu.transformer.parallel_state import DATA_AXIS
 from apex_tpu.transformer.tensor_parallel.mappings import axis_bound, axis_size
 from apex_tpu.transformer.tensor_parallel.utils import divide
@@ -40,8 +58,10 @@ from apex_tpu.utils.activations import (
     is_gated,
     validate_activation,
 )
+from apex_tpu.utils.profiling import nvtx_range
 
-__all__ = ["MoEConfig", "SwitchMLP"]
+__all__ = ["MoEConfig", "SwitchMLP", "RoutedMoEConfig", "RoutedExperts",
+           "RoutingStats", "ROUTING_STATS"]
 
 
 @dataclass(frozen=True)
@@ -49,7 +69,11 @@ class MoEConfig:
     hidden_size: int
     ffn_hidden_size: int
     num_experts: int
-    top_k: int = 1                      # 1 = switch, 2 = GShard-style
+    # 1 = switch, 2 = GShard-style. The capacity dispatch below builds one
+    # [tokens, experts, cap] one-hot per k: fine for the 1 or 2 a model is
+    # trained with here; more experts a token at serving time is
+    # RoutedExperts' job (sorted rows, grouped products, nothing dropped)
+    top_k: int = 1
     capacity_factor: float = 1.25
     aux_loss_weight: float = 1e-2
     router_jitter: float = 0.0          # multiplicative input jitter at train
@@ -299,3 +323,202 @@ class SwitchMLP:
             y = lax.psum(y, c.expert_axis)
             y = lax.dynamic_slice_in_dim(y, idx * tokens, tokens, axis=0)
         return y
+
+
+# -- the serving layer: drop-free, any top_k, sorted rows ---------------------
+
+#: what one call of :class:`RoutedExperts` reports of its routing, in order:
+#: assignments routed to experts held here, held experts that got at least
+#: one row, rows of the busiest held expert
+ROUTING_STATS = ("rows_routed", "experts_touched", "max_expert_rows")
+
+
+class RoutingStats:
+    """What the :class:`RoutedExperts` calls of one traced program report
+    of their routing. The caller makes one, hands it down the forward
+    (``decode_step(..., routing=stats)``) and returns ``stats.stacked()``
+    from its program: the serving engine's decode programs do, so the
+    counters ride the tick's read-back (docs/serving.md).
+
+    ``active``: bool ``[b]``, the batch rows that count (a serving
+    engine's idle slots route like any row and are left out).
+    """
+
+    def __init__(self, active: jax.Array):
+        self.active = active
+        self.calls = []
+
+    def record(self, expert_of, n_held: int, top_k: int) -> None:
+        """One layer call: ``expert_of [s * b * top_k]`` is each
+        assignment's held expert (``n_held`` = held elsewhere), rows in
+        ``[s, b]`` order."""
+        per_row = jnp.tile(self.active, expert_of.size // top_k
+                           // self.active.shape[0])
+        sizes = jnp.zeros((n_held + 1,), jnp.int32).at[expert_of].add(
+            jnp.repeat(per_row, top_k).astype(jnp.int32))[:n_held]
+        self.calls.append(jnp.stack(
+            [jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)]))
+
+    def stacked(self) -> jax.Array:
+        """int32 ``[calls, 3]``, columns :data:`ROUTING_STATS`."""
+        return jnp.stack(self.calls).astype(jnp.int32)
+
+
+@dataclass(frozen=True)
+class RoutedMoEConfig:
+    hidden_size: int
+    ffn_hidden_size: int                # one routed expert's width
+    num_experts: int                    # the router's width: the whole layer
+    top_k: int
+    route_scale: float = 1.0            # times the normalised weights
+    num_shared_experts: int = 0         # as one expert of that many widths
+    # the experts of the layer held here, ``(lo, hi)``; None = all. The
+    # router still scores all ``num_experts``; rows that chose an expert
+    # outside the range add nothing here (another holder computes them)
+    expert_range: Optional[Tuple[int, int]] = None
+    params_dtype: Any = jnp.float32
+    compute_dtype: Any = jnp.float32
+    init_method_std: float = 0.02
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k ({self.top_k}) must lie in 1.."
+                             f"num_experts ({self.num_experts})")
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(
+                f"expert_range {self.expert_range} must be (lo, hi) with "
+                f"0 <= lo < hi <= num_experts ({self.num_experts})")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.expert_range or (0, self.num_experts)
+
+
+class RoutedExperts:
+    """Drop-free routed expert layer with a shared expert.
+
+    ``apply(params, x[s, b, h]) -> y[s, b, h]``:
+    ``y = FFN_shared(x) + sum_{e in sel} w_e FFN_e(x)`` over the experts
+    held here, each ``FFN`` the gated form ``(silu(x Wg) * (x Wu)) Wd``.
+    ``s = sigmoid(x W_r)`` in float32; ``sel = top_k(s + bias)`` (the bias
+    selects only); ``w = s[sel] / (sum(s[sel]) + 1e-20) * route_scale``.
+    The two halves
+    are also callable apart (:meth:`routed`, :meth:`shared`): what every
+    holder of a share computes alike is counted once by the caller that
+    adds shares up.
+    """
+
+    def __init__(self, config: RoutedMoEConfig):
+        self.config = config
+
+    def init(self, key: jax.Array) -> Dict[str, Any]:
+        c = self.config
+        kr, k1, k2, k3, k4 = jax.random.split(key, 5)
+        std, dt = c.init_method_std, c.params_dtype
+        lo, hi = c.held
+        h, f = c.hidden_size, c.ffn_hidden_size
+        p = {
+            # float32 always: rounding the router would let two runs of
+            # one model choose different experts (cast_decode_params keeps
+            # leaves under a "router" key as they are)
+            "router": {
+                "weight": jax.random.normal(
+                    kr, (h, c.num_experts), jnp.float32) * std,
+                "bias": jnp.zeros((c.num_experts,), jnp.float32)},
+            "w_in": jax.random.normal(k1, (hi - lo, h, 2 * f), dt) * std,
+            "w_out": jax.random.normal(k2, (hi - lo, f, h), dt) * std,
+        }
+        if c.num_shared_experts:
+            fs = f * c.num_shared_experts
+            p["shared"] = {
+                "w_in": jax.random.normal(k3, (h, 2 * fs), dt) * std,
+                "w_out": jax.random.normal(k4, (fs, h), dt) * std}
+        return p
+
+    def spec(self) -> Dict[str, Any]:
+        s = {"router": {"weight": PartitionSpec(), "bias": PartitionSpec()},
+             "w_in": PartitionSpec(), "w_out": PartitionSpec()}
+        if self.config.num_shared_experts:
+            s["shared"] = {"w_in": PartitionSpec(), "w_out": PartitionSpec()}
+        return s
+
+    def route(self, params, x2d):
+        """``x2d [T, h]`` -> ``(weights [T, k] float32, experts [T, k])``
+        over all ``num_experts``; the product, the scores and the
+        selection are float32 at the highest matmul precision."""
+        c = self.config
+        r = params["router"]
+        logits = jnp.dot(x2d.astype(jnp.float32),
+                         r["weight"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, experts = lax.top_k(scores + r["bias"].astype(jnp.float32),
+                               c.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        return weights * c.route_scale, experts
+
+    def routed(self, params, x2d, stats: Optional[RoutingStats] = None):
+        """The held experts' part of the result, float32 ``[T, h]``;
+        the call's routing counts go to ``stats`` where one is given."""
+        # (imported here: apex_tpu.ops pulls in modules that import this
+        # package)
+        from apex_tpu.ops.grouped_matmul import (grouped_gated_ffn,
+                                                 routed_layout,
+                                                 tile_rows_for)
+
+        c = self.config
+        tokens, h = x2d.shape
+        lo, hi = c.held
+        n_held = hi - lo
+        with nvtx_range(SCOPE_MOE_ROUTER):
+            weights, experts = self.route(params, x2d)
+            here = (experts >= lo) & (experts < hi)
+            expert_of = jnp.where(here, experts - lo, n_held).reshape(-1)
+            tile_rows = tile_rows_for(tokens * c.top_k, n_held)
+            dest, tile_expert, tiles_used = routed_layout(
+                expert_of.astype(jnp.int32), n_held, tile_rows)
+            rows = tile_expert.shape[0] * tile_rows
+            token_of = jnp.arange(tokens * c.top_k, dtype=jnp.int32) \
+                // c.top_k
+            # padded row -> the token it holds (``tokens``, out of range,
+            # for a padding row: gathered as zeros)
+            source = jnp.full((rows,), tokens, jnp.int32).at[dest].set(
+                token_of, mode="drop")
+            if stats is not None:
+                stats.record(expert_of, n_held, c.top_k)
+        xp = x2d.astype(c.compute_dtype).at[source].get(
+            mode="fill", fill_value=0)
+        out = grouped_gated_ffn(
+            xp, params["w_in"].astype(c.compute_dtype),
+            params["w_out"].astype(c.compute_dtype), tile_expert,
+            tiles_used, tile_rows=tile_rows)
+        # a row that chose an expert held elsewhere has ``dest`` out of
+        # range and gathers an exact zero
+        picked = out.at[dest].get(mode="fill", fill_value=0).reshape(
+            tokens, c.top_k, h).astype(jnp.float32)
+        return jnp.sum(picked * weights[..., None], axis=1)
+
+    def shared(self, params, x2d):
+        """The shared expert over every row, float32 ``[T, h]``."""
+        c = self.config
+        p = params["shared"]
+        fs = p["w_out"].shape[0]
+        with nvtx_range(SCOPE_MOE_SHARED):
+            xc = x2d.astype(c.compute_dtype)
+            gu = jnp.dot(xc, p["w_in"].astype(c.compute_dtype),
+                         preferred_element_type=jnp.float32)
+            mid = (jax.nn.silu(gu[:, :fs]) * gu[:, fs:]).astype(
+                c.compute_dtype)
+            return jnp.dot(mid, p["w_out"].astype(c.compute_dtype),
+                           preferred_element_type=jnp.float32)
+
+    def apply(self, params, x, stats: Optional[RoutingStats] = None):
+        s, b, h = x.shape
+        x2d = x.reshape(s * b, h)
+        with nvtx_range(SCOPE_MOE):
+            y = self.routed(params, x2d, stats)
+            if self.config.num_shared_experts:
+                y = y + self.shared(params, x2d)
+        return y.reshape(s, b, h).astype(x.dtype)

@@ -45,6 +45,11 @@ CHEAP_MODEL_TEST_MODULES = {
     # by every test of the module: 24 s for the whole file (PR 26)
     "test_tick_spans.py",
     "test_trace_fleet.py",
+    # PR 30: a 4-layer hidden-64 mixed model against its plain reference,
+    # every forward jitted once (55 s), and the routed layer at hidden 32
+    # (45 s); ISSUE 30 asks for them in tier-1
+    "test_afmoe.py",
+    "test_routed_experts.py",
 }
 
 

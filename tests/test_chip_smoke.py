@@ -12,7 +12,7 @@ import pytest
 
 import chip_smoke
 from apex_tpu.models import TransformerConfig
-from apex_tpu.ops import _support, decode_attention
+from apex_tpu.ops import _support, decode_attention, grouped_matmul
 from apex_tpu.serving import EngineConfig
 from apex_tpu.utils import compile_cache
 from apex_tpu.utils.flops import peak_flops_per_chip
@@ -168,3 +168,77 @@ def test_decode_kernel_compiles_under_mosaic(monkeypatch, window, quantized):
     finally:
         _support.pallas_mode.cache_clear()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _described_v5e(monkeypatch):
+    """A sharding on a described (not attached) v5e chip, with the Pallas
+    paths pinned to the real compiler; skips where libtpu cannot describe
+    one. Call from inside a test only (one process may hold libtpu)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("a chip run compiles the kernel for real")
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as e:   # no libtpu in this environment
+        pytest.skip(f"no TPU topology description available: {e}")
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "tpu")
+    _support.pallas_mode.cache_clear()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("window", [None, 2048])
+def test_windowed_decode_kernel_compiles_at_gqa_widths(monkeypatch, window):
+    """The decode kernel as ``trinitym.serve-decode-4k`` runs it (32 query
+    heads on 4 KV heads of 128, 96 slots x 64 pages of 64) through Mosaic:
+    the window's index map divides a prefetched scalar."""
+    on = _described_v5e(monkeypatch)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    b, heads, kvh, dh, ps, pps = 96, 32, 4, 128, 64, 64
+    f = kvh * dh
+    pool = arg((b * pps, ps, f), jnp.bfloat16)
+    try:
+        compiled = decode_attention._pallas.lower(
+            arg((b, 1, heads, dh), jnp.bfloat16),
+            arg((b, 1, f), jnp.bfloat16), arg((b, 1, f), jnp.bfloat16),
+            pool, pool, None, None, arg((b, pps), jnp.int32),
+            arg((b,), jnp.int32), group=heads // kvh,
+            sliding_window=window).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [96, 4096])
+def test_grouped_products_compile_at_published_widths(monkeypatch, tokens):
+    """The routed products of a decode step (96 rows x 8, tiles of 16) and
+    of the longest prefill (4,096 x 8, tiles of 128) over 128 experts of
+    2,048 x 1,024 through Mosaic: two calls, inside the VMEM limit."""
+    on = _described_v5e(monkeypatch)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    experts, h, f, k = 128, 2048, 1024, 8
+    tile = grouped_matmul.tile_rows_for(tokens * k, experts)
+    rows = grouped_matmul.padded_rows(tokens * k, experts, tile)
+    assert tile == (16 if tokens == 96 else 128)
+    try:
+        compiled = grouped_matmul._pallas.lower(
+            arg((rows, h), jnp.bfloat16),
+            arg((experts, h, 2 * f), jnp.bfloat16),
+            arg((experts, f, h), jnp.bfloat16),
+            arg((rows // tile,), jnp.int32), arg((), jnp.int32),
+            tile_rows=tile).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "moe_experts_up" in text and "moe_experts_down" in text

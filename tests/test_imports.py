@@ -39,6 +39,7 @@ MODULES = [
     "apex_tpu.observability.trace",
     "apex_tpu.ops",
     "apex_tpu.ops.decode_attention",
+    "apex_tpu.ops.grouped_matmul",
     "apex_tpu.optimizers",
     "apex_tpu.parallel",
     "apex_tpu.parallel.multiproc",
