@@ -38,7 +38,7 @@ import jax
 from apex_tpu.utils.logging import get_logger, log_event
 from apex_tpu.utils.profiling import profiler_start, profiler_stop
 
-__all__ = ["span", "ProfilerCapture", "TICK_LEAF_SPANS"]
+__all__ = ["span", "recording", "ProfilerCapture", "TICK_LEAF_SPANS"]
 
 # -- host spans of the serving tick (serving/engine.py, supervisor.py) ------
 # grouping spans
@@ -104,6 +104,13 @@ def span(name: str, registry=None, **attrs):
     if registry is None:
         return annotation
     return _observed(annotation, name, registry)
+
+
+def recording() -> bool:
+    """Whether a profiler trace is being taken now. A span costs next to
+    nothing when none is; an attribute that takes work to compute (a sum
+    over the batch) is worth computing only when this says yes."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 class ProfilerCapture:

@@ -13,10 +13,24 @@ decode fusion is the same territory):
 - :func:`fused_paged_decode_attention` — ONE jitted region per decode
   step and layer: the new K/V rows land as a donated in-place scatter,
   and attention is a single VMEM-resident flash pass over the slot's
-  mapped pages (Pallas kernel, page table scalar-prefetched so each
-  page block DMAs straight from its pool row). The KV stream is read
-  from HBM exactly once per step; the only HBM writes are the appended
-  rows. No gathered-cache temporary exists in any memory space.
+  live pages (Pallas kernel: one grid step a slot, the page table and
+  each slot's page range scalar-prefetched, the pools left in HBM and
+  the slot's pages copied by hand, several a DMA round, straight from
+  their pool rows). The KV stream is read from HBM exactly once per
+  step; the only HBM writes are the appended rows. No gathered-cache
+  temporary exists in any memory space.
+
+The walk over a slot's pages is a loop INSIDE the kernel, not a grid
+axis (PR 31; PERF.md section 6): its trip count is the slot's own
+``[first, stop)`` page range (:func:`paged_page_range`: from its
+position, the verify window and the layer's sliding window), so a page
+that holds no visible row — past the position, before the window, or
+of a slot that holds no request — costs nothing, not a grid step. A
+round brings ``_BUFFER_BYTES`` of pages (4 at GPT-2 medium's 128 KB
+page, 8 at Trinity-Mini's 64 KB, twice that from int8 pools) into one
+of two buffers while the flash recurrence runs once over the other;
+the shapes, and so the one compiled decode program, do not depend on
+any of it.
 
 Two extensions raise the effective bandwidth ceiling past the PR 9
 roofline (docs/serving.md#kv-quantization, #speculative-decoding):
@@ -61,12 +75,16 @@ against the flat engine on CPU (the tier-1 parity bar); the kernel's
 flash accumulation is validated against the reference to numerical
 tolerance in interpret mode, compiled by the real Mosaic compiler in
 tier-1 (``tests/test_chip_smoke.py``, no chip needed) and compared on
-the chip at GPT-2 124M widths by ``chip_smoke.py``.
+the chip at GPT-2 124M widths by ``chip_smoke.py``. A pool whose pages
+are not whole ``8 x 128`` tiles takes the reference on the chip too,
+and one ``paged_decode_kernel_refused`` event says so
+(:func:`_kernel_takes`).
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -75,14 +93,25 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.observability.tracing import SCOPE_PAGED_DECODE
 from apex_tpu.ops._support import cdiv, pallas_interpret, use_pallas
+from apex_tpu.utils.logging import log_event
 from apex_tpu.utils.profiling import nvtx_range
 
-__all__ = ["fused_paged_decode_attention", "paged_pages_for",
-           "paged_quant_fill", "paged_quant_scatter"]
+__all__ = ["fused_paged_decode_attention", "paged_page_range",
+           "paged_pages_for", "paged_quant_fill", "paged_quant_scatter"]
 
 #: the masked-score floor the flat decode path uses — shared so paged
 #: and flat softmax see bitwise-identical masked entries
 _NEG = -1e30
+
+#: bytes of one VMEM buffer of K (or V) pages in the decode kernel: a DMA
+#: round brings as many pages as fit (PERF.md section 6, PR 31: the probe)
+_BUFFER_BYTES = 512 * 1024
+
+_LOG = logging.getLogger(__name__)
+
+#: pool shapes ``(page_size, minor dim)`` already reported as refused by
+#: the compiled kernel (:func:`_kernel_takes`)
+_REFUSED: set = set()
 
 #: int8 quantization range: symmetric, -127..127 (keeping -128 out of
 #: the code domain makes the scale exactly absmax/127 and negation
@@ -274,17 +303,83 @@ def _reference(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
 # -- Pallas kernel -----------------------------------------------------------
 
 
-def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, heads, window, quantized, sliding_window,
-                   scale):
-    """One (slot, page-block) grid cell of the streaming decode pass.
+def paged_page_range(positions, w, page_size, sliding_window=None):
+    """The logical pages ``[first, stop)`` of each slot that hold a row
+    some query of its ``w``-row window may see: ``stop`` is one past the
+    page of the last window row ``positions + w - 1``; ``first`` is the
+    page of the oldest row inside the FIRST query's ``sliding_window``
+    (later window rows only see later rows), 0 without one. Pure
+    arithmetic on whatever array kind ``positions`` is (``numpy`` on the
+    host, ``jnp`` inside a program): the kernel's page walk, the engine's
+    ``pages`` span attribute and the tests all read the range here."""
+    stop = (positions + (w - 1)) // page_size + 1
+    if sliding_window is None:
+        return positions * 0, stop
+    return (positions - sliding_window + 1).clip(0) // page_size, stop
 
-    The page table is scalar-prefetched, so block ``(r, j)``'s K/V page
-    DMAs directly from pool row ``page_table[r, j]`` into VMEM — the
-    gather never exists as an array. Softmax is the standard flash
-    recurrence over page blocks (running max / normalizer / weighted
-    accumulator in VMEM scratch, carried across the slot's inner grid
-    iterations); the final block rescales and writes the context rows.
+
+def _pages_per_round(page_size, f, dtype, pages_per_slot):
+    """How many pages one DMA round brings: as many as fit one VMEM
+    buffer of :data:`_BUFFER_BYTES`, at least one, at most the table's
+    width (``pages_per_slot`` need not divide by it)."""
+    page_bytes = page_size * f * jnp.dtype(dtype).itemsize
+    return max(1, min(pages_per_slot, _BUFFER_BYTES // page_bytes))
+
+
+def _kernel_takes(pages) -> bool:
+    """Whether the kernel can be compiled for this pool. Mosaic (libtpu
+    0.0.34) slices a pool left in HBM only by whole ``8 x 128`` tiles,
+    the page's own two dims included, so a page has to be a whole number
+    of them: ``page_size`` a multiple of 8 and the fused minor dim a
+    multiple of 128 (every benchmarked width; measured chip-free). The
+    interpreter takes any shape. On a chip any other pool is served by
+    the reference, and SAYS so, once a shape: its gather reads the
+    stream about three times (a rank's slice of GPT-2 124M under
+    ``tp=4`` is 192 lanes wide, for one)."""
+    _, page_size, f = pages.shape
+    if pallas_interpret() or (page_size % 8 == 0 and f % 128 == 0):
+        return True
+    if (page_size, f) not in _REFUSED:
+        _REFUSED.add((page_size, f))
+        log_event(_LOG, "paged_decode_kernel_refused", page_size=page_size,
+                  minor_dim=f, served_by="reference",
+                  why="a page is not whole 8 x 128 tiles")
+    return False
+
+
+def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
+                   k_hbm, v_hbm, *rest, page_size, heads, window, quantized,
+                   sliding_window, scale, pages_per_round):
+    """One slot of the streaming decode pass: grid step ``r`` walks slot
+    ``r``'s live pages ``first_ref[r] .. stop_ref[r]`` in an in-kernel
+    loop whose trip count is data, ``pages_per_round`` pages a round.
+
+    Both pools stay in HBM; the page table, the positions, each slot's
+    page range (:func:`paged_page_range`; an idle slot's is empty) and
+    ``head_ref`` (``head_ref[r]`` is the first slot ``>= r`` with a page
+    to read, ``b`` if none) are scalar-prefetched. A round's pages are
+    copied by hand (``make_async_copy`` from pool row ``page_table[r,
+    j]``) into one of two VMEM buffers each of K and V, and every round
+    starts the NEXT round's copies into the other buffer before it waits
+    on its own: the next round of this slot, or, on a slot's last round,
+    the first round of the next slot that has one — so the copies of
+    ``pages_per_round`` pages are always in flight and a slot's first
+    page never waits on an idle pipeline. Which buffer the next slot
+    starts in is carried across grid steps in SMEM (the grid axis is
+    sequential). The gather never exists as an array, a page outside
+    ``[first, stop)`` is neither copied nor multiplied, and a slot with
+    no page (an idle one) costs one empty grid step and writes zeros.
+
+    Softmax is the standard flash recurrence over rounds (running max /
+    normalizer / weighted accumulator in VMEM scratch), once per
+    ``[pages_per_round * page_size, f]`` block. A round's pages past
+    ``stop`` are not copied, and their buffer rows keep an earlier
+    round's page — maybe ANOTHER slot's. Every such row lies past the
+    last window row, so the mask removes its score whatever the K rows
+    hold; its weight is 0, but ``0 x NaN`` is NaN on the MXU, so a
+    partial round zeroes those rows of its V buffer (and a quantized one
+    reads 0 for their scales) before ``P @ V``: a slot reads nothing but
+    its own pages, and one slot's non-finite rows cannot reach another.
 
     Everything is 2-D and full-lane — the form Mosaic compiles (an
     in-kernel ``[ps, f] -> [ps, kvh, dh]`` split of the lane dim, 4-D
@@ -292,107 +387,144 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     ``head_dim`` 64): the ``m = window * heads`` queries arrive as the
     block-masked ``[m, f]`` matrix :func:`_query_block` builds (each
     query's vector in its K/V head's lane block, zeros elsewhere), so
-    ``scores = Qblock @ page^T`` is one MXU GEMM over the whole fused
-    ``f = kvh * dh`` dim, and ``P @ page`` yields ``[m, f]`` rows whose
+    ``scores = Qblock @ block^T`` is one MXU GEMM over the whole fused
+    ``f = kvh * dh`` dim, and ``P @ block`` yields ``[m, f]`` rows whose
     own head's lane block holds that query's context (the caller
     selects it). Quantized pools stream int8 and fold each page's
-    per-kv-head scale into the scores / weighted values as a per-query
-    column — a query only ever reads its own head's lanes, so scaling
-    its row equals dequantizing that head. Pages past the slot's valid
-    length are skipped (their DMA is the residual cost of the
-    rectangular grid — one page per slot, since consecutive sentinel
-    entries clamp to the same block and Pallas does not re-fetch it).
-    Under a ``sliding_window`` a page that lies wholly before the window
-    of the slot's FIRST query is skipped the same way, and is never
-    fetched: the index map (:func:`_page_index`) pins those grid steps
-    to the first page in the window."""
+    per-kv-head scale into the scores / the weights as a per-query value
+    spread over that page's columns — a query only ever reads its own
+    head's lanes, so scaling its row equals dequantizing that head."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, o_ref, *scratch = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, *scratch = rest
+    kbuf, vbuf, sems, parity_ref, m_ref, l_ref, acc_ref = scratch
     r = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = pos_ref[r]                  # first window row's append index
+    b, pages_per_slot = pt_ref.shape
     m = window * heads
+    span = pages_per_round * page_size         # rows of one buffer
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def round_copies(slot, rnd, buf):
+        """(whether it is made, K copy, V copy) for each page of round
+        ``rnd`` of ``slot`` into buffer ``buf`` — built alike to start
+        a round and to wait on it."""
+        base = first_ref[slot] + rnd * pages_per_round
+        copies = []
+        for c in range(pages_per_round):
+            j = base + c
+            page = pt_ref[slot, jnp.minimum(j, pages_per_slot - 1)]
+            rows = pl.ds(c * page_size, page_size)
+            copies.append((
+                j < stop_ref[slot],
+                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, rows],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, rows],
+                                      sems.at[1, buf])))
+        return copies
 
-    live = j * page_size <= pos + (window - 1)
-    if sliding_window is not None:
-        # the page's last row is still inside the first query's window
-        live = jnp.logical_and(
-            live, (j + 1) * page_size > pos - sliding_window + 1)
+    def start_round(slot, rnd, buf):
+        """Start round ``rnd`` of ``slot`` (nothing if ``slot == b``)."""
+        for made, k_copy, v_copy in round_copies(
+                jnp.minimum(slot, b - 1), rnd, buf):
+            @pl.when(jnp.logical_and(slot < b, made))
+            def _start():
+                k_copy.start()
+                v_copy.start()
 
-    @pl.when(live)
-    def _accumulate():
+    @pl.when(r == 0)
+    def _first_round_of_the_call():
+        parity_ref[0] = 0
+        start_round(head_ref[0], 0, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    pos = pos_ref[r]                  # first window row's append index
+    first, stop = first_ref[r], stop_ref[r]
+    rounds = jax.lax.div(stop - first + (pages_per_round - 1),
+                         pages_per_round)
+    parity = parity_ref[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    # the last row each query sees: window row t of each query (queries
+    # are ordered [t, head]; a compare-and-add ladder, no vector integer
+    # division), never past the table (a window row there sees the
+    # table's rows, not what a round's uncopied pages left in the buffer)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+    lim = pos + sum(((qi >= t * heads).astype(jnp.int32)
+                     for t in range(1, window)),
+                    jnp.zeros((m, 1), jnp.int32))
+    newest = jnp.minimum(lim, stop * page_size - 1)
+
+    def one_round(i, carry):
+        buf = jax.lax.rem(parity + i, 2)
+        last = i + 1 == rounds
+        start_round(jnp.where(last, head_ref[r + 1], r),
+                    jnp.where(last, 0, i + 1), 1 - buf)
+        copies = round_copies(r, i, buf)
+        base = first + i * pages_per_round
+        for made, k_copy, _ in copies:
+            pl.when(made)(k_copy.wait)
         qb = q_ref[0]                                     # [m, f]
-        kb = k_ref[0].astype(qb.dtype)                    # [ps, f]
-        vb = v_ref[0].astype(qb.dtype)
+        kb = kbuf[buf].astype(qb.dtype)                   # [span, f]
         s_blk = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [m, ps]
+            preferred_element_type=jnp.float32) * scale   # [m, span]
         if quantized:
-            # this page's scale per query: column j of the slot's
-            # [m, pages_per_slot] table, picked with a lane mask
-            lane = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
-            k_col = jnp.sum(jnp.where(lane == j, ks_ref[0], 0.0),
-                            axis=1, keepdims=True)        # [m, 1]
-            v_col = jnp.sum(jnp.where(lane == j, vs_ref[0], 0.0),
-                            axis=1, keepdims=True)
-            s_blk = s_blk * k_col
-        row = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        # window row t of each query (queries are ordered [t, head]);
-        # a compare-and-add ladder, no vector integer division
-        qi = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
-        lim = pos + sum(((qi >= t * heads).astype(jnp.int32)
-                         for t in range(1, window)),
-                        jnp.zeros((m, 1), jnp.int32))
-        invalid = row > lim
+            def per_column(s_ref):
+                # each page's scale per query (column `base + c` of the
+                # slot's [m, pages_per_slot] table, picked with a lane
+                # mask; 0 past `stop`, where the table names no page of
+                # this slot) spread over that page's columns of the block
+                lane = jax.lax.broadcasted_iota(
+                    jnp.int32, s_ref.shape[1:], 1)
+                out = jnp.zeros((m, span), jnp.float32)
+                for c in range(pages_per_round):
+                    pick = jnp.sum(
+                        jnp.where(jnp.logical_and(lane == base + c,
+                                                  lane < stop),
+                                  s_ref[0], 0.0),
+                        axis=1, keepdims=True)            # [m, 1]
+                    mine = jnp.logical_and(col >= c * page_size,
+                                           col < (c + 1) * page_size)
+                    out = jnp.where(mine, pick, out)
+                return out
+
+            s_blk = s_blk * per_column(ks_ref)
+        row = base * page_size + col
+        invalid = row > newest
         if sliding_window is not None:
             invalid = jnp.logical_or(invalid, row <= lim - sliding_window)
         s_blk = jnp.where(invalid, _NEG, s_blk)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new)                        # [m, ps]
+        p = jnp.exp(s_blk - m_new)                        # [m, span]
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * per_column(vs_ref)
+        for c, (made, _, v_copy) in enumerate(copies):
+            pl.when(made)(v_copy.wait)
+
+            @pl.when(jnp.logical_not(made))
+            def _no_stale_rows():
+                vbuf[buf, pl.ds(c * page_size, page_size)] = jnp.zeros(
+                    (page_size, vbuf.shape[2]), vbuf.dtype)
+        vb = vbuf[buf].astype(qb.dtype)
         pv = jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # [m, f]
-        if quantized:
-            pv = pv * v_col
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        # l > 0 for every real window row: row `pos + t` itself is valid
-        # by construction (garbage rows past the slot's window are
-        # normalized over whatever survived the mask — the engine never
-        # reads them)
-        l = jnp.where(l_ref[...] > 0.0, l_ref[...], 1.0)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _page_index(page_size, sliding_window):
-    """The K/V block index map: grid step ``(r, j)`` reads pool row
-    ``page_table[r, j]``. Under a window, steps before the first page
-    that holds a visible row read THAT page instead (the same block as
-    the step that will use it, so the pipeline fetches it once and the
-    pages before the window are never read)."""
-    if sliding_window is None:
-        return lambda r, j, pt, pos: (pt[r, j], 0, 0)
-
-    def index(r, j, pt, pos):
-        first = jnp.maximum(pos[r] - sliding_window + 1, 0) // page_size
-        return (pt[r, jnp.maximum(j, first)], 0, 0)
-
-    return index
+    jax.lax.fori_loop(0, rounds, one_round, 0)
+    parity_ref[0] = jax.lax.rem(parity + rounds, 2)
+    # l > 0 for every real window row: row `pos + t` itself is valid by
+    # construction (garbage rows past the slot's window are normalized
+    # over whatever survived the mask — the engine never reads them); a
+    # slot with no page keeps l == 0, acc == 0 and writes zeros
+    l = jnp.where(l_ref[...] > 0.0, l_ref[...], 1.0)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _query_block(q, kv_head, kvh):
@@ -430,20 +562,34 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
         v_pages = _append_rows(v_pages, v_new, page_table, positions,
                                page_size)
     pt = jnp.minimum(page_table, n_pages - 1).astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    # the pages the kernel walks: none for a slot that holds no request
+    # (the engine leaves its table row at the sentinel), never past the
+    # table (an over-long window's rows there were dropped by the append)
+    first, stop = paged_page_range(positions, w, page_size, sliding_window)
+    stop = jnp.where(page_table[:, 0] >= n_pages, 0,
+                     jnp.minimum(stop, pages_per_slot)).astype(jnp.int32)
+    first = jnp.minimum(first, stop).astype(jnp.int32)
+    # head[r]: the first slot >= r with a page to read (b: none) — where
+    # the kernel's prefetch goes when slot r - 1 runs out of rounds
+    slot_ix = jnp.arange(b + 1, dtype=jnp.int32)
+    head = jax.lax.cummin(
+        jnp.where(jnp.append(stop > first, True), slot_ix, b),
+        reverse=True)
     # K/V head of each of the m queries (ordered [window row, head])
     kv_head = (jnp.arange(m) % hl) // group
 
+    pages_per_round = _pages_per_round(page_size, f, k_pages.dtype,
+                                       pages_per_slot)
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, heads=hl, window=w,
         quantized=quantized, sliding_window=sliding_window,
-        scale=1.0 / float(dh) ** 0.5)
-    page = _page_index(page_size, sliding_window)
-    in_specs = [
-        pl.BlockSpec((1, m, f), lambda r, j, pt, pos: (r, 0, 0)),
-        pl.BlockSpec((1, page_size, f), page),
-        pl.BlockSpec((1, page_size, f), page),
-    ]
-    inputs = [pt, positions.astype(jnp.int32),
+        scale=1.0 / float(dh) ** 0.5, pages_per_round=pages_per_round)
+    per_slot = pl.BlockSpec((1, m, f), lambda r, *_: (r, 0, 0))
+    in_specs = [per_slot,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    inputs = [pt, positions, first, stop, head,
               _query_block(q, kv_head, kvh), k_pages, v_pages]
     if quantized:
         # per-(slot, query, page) scales: the page's sidecar row gathered
@@ -453,16 +599,20 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
         def per_query(scales):
             return scales[pt][:, :, kv_head].transpose(0, 2, 1)
 
-        spec = pl.BlockSpec((1, m, pages_per_slot),
-                            lambda r, j, pt, pos: (r, 0, 0))
+        spec = pl.BlockSpec((1, m, pages_per_slot), lambda r, *_: (r, 0, 0))
         in_specs += [spec, spec]
         inputs += [per_query(k_scales), per_query(v_scales)]
+    buffers = pltpu.VMEM((2, pages_per_round * page_size, f), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, pages_per_slot),
+        num_scalar_prefetch=5,
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, m, f), lambda r, j, pt, pos: (r, 0, 0)),
+        out_specs=per_slot,
         scratch_shapes=[
+            buffers,                              # K rounds, two deep
+            buffers,                              # V rounds, two deep
+            pltpu.SemaphoreType.DMA((2, 2)),      # [K / V, buffer]
+            pltpu.SMEM((1,), jnp.int32),          # the next round's buffer
             pltpu.VMEM((m, 1), jnp.float32),      # running max
             pltpu.VMEM((m, 1), jnp.float32),      # normalizer
             pltpu.VMEM((m, f), jnp.float32),      # weighted accumulator
@@ -470,6 +620,9 @@ def _pallas(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
     ctx_big = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, m, f), q.dtype),
+        # the buffer parity and the prefetch run from one slot to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=pallas_interpret(),
         name="paged_decode_attention",
     )(*inputs)
@@ -548,7 +701,7 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
         raise ValueError(
             f"scales must be [n_pages, kv_heads] = "
             f"({k_pages.shape[0]}, {kvh}), got {k_scales.shape}")
-    fn = _pallas if use_pallas() else _reference
+    fn = _pallas if use_pallas() and _kernel_takes(k_pages) else _reference
     with nvtx_range(SCOPE_PAGED_DECODE):
         ctx, k_pages, v_pages, k_scales, v_scales = fn(
             q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,

@@ -77,6 +77,7 @@ from apex_tpu.observability.trace import (
     emit_span,
 )
 from apex_tpu.ops.decode_attention import (
+    paged_page_range,
     paged_quant_fill,
     paged_quant_scatter,
 )
@@ -107,6 +108,7 @@ from apex_tpu.observability.tracing import (
     TICK_READBACK,
     TICK_SCHEDULE,
     TICK_UPLOAD,
+    recording,
     span,
 )
 from apex_tpu.serving.scheduler import (
@@ -2005,6 +2007,22 @@ class InferenceEngine:
                    for rec in self._active.values())
         return dead * self._window_share
 
+    def _dispatch_pages(self) -> dict:
+        """``pages`` of the decode dispatch span: the page copies the
+        decode kernel of ONE full-attention layer makes this step, the
+        width of every active slot's page range summed (a window layer
+        copies no more) — how much the kernel's page walk is asked to
+        do, beside ``rows``. Nothing on the flat layout, and nothing
+        unless a trace is being taken: no tick pays for the sum
+        otherwise."""
+        if self.pages is None or not recording():
+            return {}
+        live = np.fromiter(self._active, np.intp, len(self._active))
+        first, stop = paged_page_range(
+            self._positions_h[live], self._spec or 1, self.config.page_size)
+        return {"pages": int((np.minimum(stop, self.config.pages_per_slot)
+                              - first).sum())}
+
     def _decode_args(self) -> tuple:
         """The decode program's arguments from the current host arrays
         (paged: the page table rides right after the pool; with
@@ -2050,7 +2068,8 @@ class InferenceEngine:
         with span(TICK_UPLOAD, arrays=self._decode_upload[0],
                   bytes=self._decode_upload[1]):
             args = self._decode_args()
-        with span(TICK_DISPATCH, program="decode", rows=len(self._active)):
+        with span(TICK_DISPATCH, program="decode", rows=len(self._active),
+                  **self._dispatch_pages()):
             nxt, finite, self._caches = self._decode_fn(*args)
         del args
         with span(TICK_READBACK, reads=2) as back:

@@ -195,7 +195,7 @@ def _described_v5e(monkeypatch):
 def test_windowed_decode_kernel_compiles_at_gqa_widths(monkeypatch, window):
     """The decode kernel as ``trinitym.serve-decode-4k`` runs it (32 query
     heads on 4 KV heads of 128, 96 slots x 64 pages of 64) through Mosaic:
-    the window's index map divides a prefetched scalar."""
+    eight pages a DMA round, the window's first page from the wrapper."""
     on = _described_v5e(monkeypatch)
 
     def arg(shape, dtype):
@@ -211,6 +211,97 @@ def test_windowed_decode_kernel_compiles_at_gqa_widths(monkeypatch, window):
             pool, pool, None, None, arg((b, pps), jnp.int32),
             arg((b,), jnp.int32), group=heads // kvh,
             sliding_window=window).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("page_size,f,takes", [
+    (64, 1024, True), (64, 512, True), (8, 128, True),
+    (64, 192, False),      # a rank's slice of GPT-2 124M under tp=4
+    (12, 128, False),      # a page that ends inside a tile
+])
+def test_a_refused_pool_takes_the_reference_and_says_so(
+        monkeypatch, caplog, page_size, f, takes):
+    """Mosaic slices a pool left in HBM by whole 8 x 128 tiles only: on
+    the chip a pool whose pages are not made of them is served by the
+    reference, and one ``paged_decode_kernel_refused`` event a shape says
+    so (the interpreter takes any shape and says nothing)."""
+    pool = jax.ShapeDtypeStruct((4, page_size, f), jnp.bfloat16)
+    monkeypatch.setattr(decode_attention, "_REFUSED", set())
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "tpu")
+    _support.pallas_mode.cache_clear()
+    try:
+        with caplog.at_level("WARNING", logger=decode_attention.__name__):
+            assert decode_attention._kernel_takes(pool) is takes
+            assert decode_attention._kernel_takes(pool) is takes
+            said = [r.getMessage() for r in caplog.records
+                    if "paged_decode_kernel_refused" in r.getMessage()]
+            assert len(said) == (0 if takes else 1)
+            assert all(f"minor_dim={f}" in line
+                       and f"page_size={page_size}" in line for line in said)
+            monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "interpret")
+            _support.pallas_mode.cache_clear()
+            assert decode_attention._kernel_takes(pool)
+            assert len(caplog.records) == len(said)
+    finally:
+        _support.pallas_mode.cache_clear()
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_decode_kernel_compiles_at_the_smallest_pool_it_takes(monkeypatch,
+                                                          quantized):
+    """The smallest pool the compiled kernel takes (pages of 8 rows x 128
+    lanes), as the interpret-mode parity tests of ``test_serving_paged``
+    and ``test_spec_quant`` run it: three pages a DMA round over 8-page
+    tables, a verify window, a sliding window, float32 and int8 pools.
+    What those tests check interpreted is what Mosaic compiles here."""
+    on = _described_v5e(monkeypatch)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    b, w, heads, kvh, dh, ps, pps = 3, 3, 4, 2, 64, 8, 8
+    f = kvh * dh
+    dtype = jnp.int8 if quantized else jnp.float32
+    monkeypatch.setattr(decode_attention, "_BUFFER_BYTES",
+                        3 * ps * f * jnp.dtype(dtype).itemsize)
+    decode_attention._pallas.clear_cache()
+    assert decode_attention._pages_per_round(ps, f, dtype, pps) == 3
+    pool = arg((b * pps + 2, ps, f), dtype)
+    scales = arg((b * pps + 2, kvh), jnp.float32) if quantized else None
+    try:
+        compiled = decode_attention._pallas.lower(
+            arg((b, w, heads, dh), jnp.float32),
+            arg((b, w, f), jnp.float32), arg((b, w, f), jnp.float32),
+            pool, pool, scales, scales, arg((b, pps), jnp.int32),
+            arg((b,), jnp.int32), group=heads // kvh,
+            sliding_window=None if quantized else 20).compile()
+    finally:
+        decode_attention._pallas.clear_cache()
+        _support.pallas_mode.cache_clear()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_kernel_compiles_at_the_gpt2m_cells_shape(monkeypatch):
+    """The decode kernel as ``gpt2m.serve-decode`` and ``gpt2m.serve-prefill``
+    run it (96 slots, 16 heads of 64, 16 pages of 64 a slot, bf16) through
+    Mosaic: four pages a DMA round, two buffers each of K and V."""
+    on = _described_v5e(monkeypatch)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    b, heads, dh, ps, pps = 96, 16, 64, 64, 16
+    f = heads * dh
+    assert decode_attention._pages_per_round(ps, f, jnp.bfloat16, pps) == 4
+    pool = arg((b * pps, ps, f), jnp.bfloat16)
+    try:
+        compiled = decode_attention._pallas.lower(
+            arg((b, 1, heads, dh), jnp.bfloat16),
+            arg((b, 1, f), jnp.bfloat16), arg((b, 1, f), jnp.bfloat16),
+            pool, pool, None, None, arg((b, pps), jnp.int32),
+            arg((b,), jnp.int32), group=1, sliding_window=None).compile()
     finally:
         _support.pallas_mode.cache_clear()
     assert "tpu_custom_call" in compiled.as_text()

@@ -160,7 +160,7 @@ def test_decode_kernel_with_a_window_matches_its_reference(window,
                                                            pallas_kernels):
     """Contexts of 61, 6 and 34 over pages of 8: up to six pages lie
     wholly before the window and are neither fetched nor multiplied."""
-    b, hl, kvh, dh, ps, pps = 3, 8, 2, 32, 8, 8
+    b, hl, kvh, dh, ps, pps = 3, 8, 2, 64, 8, 8    # pages of whole tiles
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     f = kvh * dh
     q = jax.random.normal(ks[0], (b, 1, hl, dh))
@@ -175,13 +175,16 @@ def test_decode_kernel_with_a_window_matches_its_reference(window,
                                    sliding_window=window)
     want = decode_attention._reference(*args, hl // kvh, window)
     np.testing.assert_allclose(got[0], want[0], atol=2e-6)
-    if window is not None:
-        # the index map never names a page before the window
-        index = decode_attention._page_index(ps, window)
-        for r in range(b):
-            first = max(int(pos[r]) - window + 1, 0) // ps
-            named = {int(index(r, j, table, pos)[0]) for j in range(pps)}
-            assert min(named) == int(table[r, first])
+    # the page walk names exactly the pages that hold a row the mask
+    # lets through: none before the window, none past the last visible
+    # row (without a window: every page up to the position's)
+    first, stop = decode_attention.paged_page_range(
+        np.asarray(pos), 1, ps, window)
+    for r in range(b):
+        seen = [s for s in range(pps * ps) if s <= int(pos[r])
+                and (window is None or s > int(pos[r]) - window)]
+        assert (int(first[r]), int(stop[r])) == (seen[0] // ps,
+                                                  seen[-1] // ps + 1)
 
 
 # -- the config ----------------------------------------------------------------

@@ -34,7 +34,12 @@ from apex_tpu.observability import (
 from apex_tpu.observability.report import SERVING_SHED_COUNTERS
 from apex_tpu.ops import _support, fused_paged_decode_attention, \
     paged_pages_for
-from apex_tpu.ops.decode_attention import _pallas, _reference
+from apex_tpu.ops import decode_attention
+from apex_tpu.ops.decode_attention import (
+    _pallas,
+    _reference,
+    paged_quant_fill,
+)
 from apex_tpu.serving import (
     EngineConfig,
     EngineSupervisor,
@@ -182,8 +187,11 @@ class TestPagePool:
 # fused kernel vs reference (interpret mode — the tier-1 hardware proxy)
 
 
-def _rand_paged_case(seed, b=3, kvh=2, group=2, dh=8, page_size=8, pps=4,
+def _rand_paged_case(seed, b=3, kvh=2, group=2, dh=64, page_size=8, pps=4,
                      dtype=jnp.float32):
+    # pages of whole 8 x 128 tiles (the smallest the compiled kernel
+    # takes), so the parity tests below run interpreted here and under
+    # Mosaic on the chip
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     n_pages = b * pps + 2
     hl = kvh * group
@@ -237,6 +245,141 @@ class TestFusedKernelParity:
         ctx_k, _, _ = _single(_pallas, case, group=1)
         ctx_r, _, _ = _single(_reference, case, group=1)
         np.testing.assert_allclose(ctx_k, ctx_r, atol=2e-5, rtol=2e-5)
+
+    # what the in-kernel page walk adds (PR 31): each case gives every
+    # slot's position (None: the slot holds no request, its table row is
+    # the sentinel), the window rows ``w`` and the layer's window. Pages
+    # of 8 rows, 8 pages a slot, THREE pages a DMA round (8 is no
+    # multiple of it).
+    @pytest.mark.parametrize("positions,w,sliding_window", [
+        ([0, 7, 8], 1, None),          # position 0; the page's last row;
+                                       # the next page's first
+        ([63, 5, 62], 1, None),        # slots on their last table page
+        ([33, 39, 56], 1, None),       # 5, 5 and 8 pages: not whole rounds
+        ([3, 12, 9], 1, None),         # fewer live pages than a round
+        ([20, None, 45], 1, None),     # an idle slot between live ones
+        ([None, None, 17], 1, None),   # the call opens on idle slots
+        ([41, 2, None], 1, None),      # ... and closes on one
+        ([None, None, None], 1, None),  # nothing to read at all
+        ([6, 22, 47], 3, None),        # a verify window over a page edge
+        ([60, None, 14], 3, None),     # ... on the last page, beside idle
+        ([62, 10, None], 3, None),     # ... running past the table
+        ([45, 30, 61], 1, 12),         # windows whose first page is not 0
+        ([45, 7, 58], 3, 20),          # ... under a verify window
+    ])
+    def test_interpret_page_walk_edges_match_reference(
+            self, pallas_kernels, monkeypatch, positions, w,
+            sliding_window):
+        b, kvh, group, dh, ps, pps = 3, 2, 2, 64, 8, 8
+        f, hl = kvh * dh, kvh * group
+        monkeypatch.setattr(decode_attention, "_BUFFER_BYTES",
+                            3 * ps * f * 4)
+        decode_attention._pallas.clear_cache()
+        assert decode_attention._pages_per_round(ps, f, jnp.float32,
+                                                 pps) == 3
+        keys = jax.random.split(jax.random.PRNGKey(11), 5)
+        n_pages = b * pps + 2
+        q = jax.random.normal(keys[0], (b, w, hl, dh))
+        k_new = jax.random.normal(keys[1], (b, w, f))
+        v_new = jax.random.normal(keys[2], (b, w, f))
+        k_pages = jax.random.normal(keys[3], (n_pages, ps, f))
+        v_pages = jax.random.normal(keys[4], (n_pages, ps, f))
+        live = np.array([p is not None for p in positions])
+        pos = np.array([p or 0 for p in positions], np.int32)
+        pt = np.full((b, pps), n_pages, np.int32)
+        perm = iter(np.random.RandomState(3).permutation(n_pages))
+        for r in np.flatnonzero(live):
+            for j in range(min(pps, paged_pages_for(int(pos[r]) + w, ps))):
+                pt[r, j] = next(perm)
+        args = (q, k_new, v_new, k_pages, v_pages, None, None,
+                jnp.asarray(pt), jnp.asarray(pos))
+        try:
+            got = _pallas(*args, group=group, sliding_window=sliding_window)
+        finally:
+            decode_attention._pallas.clear_cache()
+        want = _reference(*args, group, sliding_window)
+        np.testing.assert_allclose(np.asarray(got[0])[live],
+                                   np.asarray(want[0])[live],
+                                   atol=2e-5, rtol=2e-5)
+        # a slot that holds no request reads no page and writes zeros
+        assert not np.asarray(got[0])[~live].any()
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[2], want[2])
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_interpret_nonfinite_slot_stays_alone(self, pallas_kernels,
+                                                  monkeypatch, quantized):
+        """The engine's quarantine promise at the kernel: a slot whose
+        pages went non-finite (NaN K/V rows; NaN scales on int8 pools)
+        perturbs no co-tenant. A round's uncopied buffer rows still hold
+        an EARLIER slot's pages, the table's sentinel entries clamp to a
+        pool row the poisoned slot owns, and a masked weight of 0 times
+        NaN is NaN: the kernel has to keep both out of ``P @ V``. Three
+        pages a round; the poisoned slots' 7 pages leave theirs in both
+        buffers, the neighbours' last rounds are partial, idle slots lie
+        between."""
+        b, kvh, group, dh, ps, pps = 7, 2, 2, 64, 8, 8
+        f, hl = kvh * dh, kvh * group
+        itemsize = 1 if quantized else 4
+        monkeypatch.setattr(decode_attention, "_BUFFER_BYTES",
+                            3 * ps * f * itemsize)
+        decode_attention._pallas.clear_cache()
+        # slots 0 and 5 are poisoned; 1 and 3 hold no request
+        positions = [55, None, 36, None, 4, 55, 12]
+        poisoned = np.array([True, False, False, False, False, True, False])
+        live = np.array([p is not None for p in positions])
+        pos = np.array([p or 0 for p in positions], np.int32)
+        keys = jax.random.split(jax.random.PRNGKey(23), 5)
+        n_pages = b * pps + 2
+        q = jax.random.normal(keys[0], (b, 1, hl, dh))
+        k_new = jax.random.normal(keys[1], (b, 1, f))
+        v_new = jax.random.normal(keys[2], (b, 1, f))
+        k_pages = jax.random.normal(keys[3], (n_pages, ps, f))
+        v_pages = jax.random.normal(keys[4], (n_pages, ps, f))
+        pt = np.full((b, pps), n_pages, np.int32)
+        # the last pool row (where sentinel entries clamp) first: slot 0's
+        free = iter([n_pages - 1] + list(
+            np.random.RandomState(9).permutation(n_pages - 1)))
+        for r in np.flatnonzero(live):
+            for j in range(paged_pages_for(int(pos[r]) + 1, ps)):
+                pt[r, j] = next(free)
+        bad_pages = pt[poisoned][pt[poisoned] < n_pages]
+        k_scales = v_scales = None
+        if quantized:
+            zero = jnp.zeros((n_pages, ps, f), jnp.int8)
+            none = jnp.zeros((n_pages, kvh), jnp.float32)
+            dest = jnp.arange(n_pages, dtype=jnp.int32)
+            k_pages, k_scales = paged_quant_fill(zero, none, k_pages, dest)
+            v_pages, v_scales = paged_quant_fill(zero, none, v_pages, dest)
+
+        def call(fn, poison):
+            kp, vp, ks, vs, kn, vn = (k_pages, v_pages, k_scales, v_scales,
+                                      k_new, v_new)
+            if poison:
+                nan = jnp.float32(jnp.nan)
+                kn = jnp.where(poisoned[:, None, None], nan, kn)
+                vn = jnp.where(poisoned[:, None, None], nan, vn)
+                if quantized:
+                    ks = ks.at[bad_pages].set(nan)
+                    vs = vs.at[bad_pages].set(nan)
+                else:
+                    kp = kp.at[bad_pages].set(nan)
+                    vp = vp.at[bad_pages].set(nan)
+            return fn(q, kn, vn, kp, vp, ks, vs, jnp.asarray(pt),
+                      jnp.asarray(pos), group=group, sliding_window=None)
+
+        try:
+            got = np.asarray(call(_pallas, poison=True)[0])
+        finally:
+            decode_attention._pallas.clear_cache()
+        want = np.asarray(call(jax.jit(_reference, static_argnames=(
+            "group", "sliding_window")), poison=False)[0])
+        others = live & ~poisoned
+        assert np.isnan(got[poisoned]).all()      # the fault was planted
+        assert np.isfinite(got[others]).all()
+        np.testing.assert_allclose(got[others], want[others],
+                                   atol=2e-5, rtol=2e-5)
+        assert not got[~live].any()
 
     def test_cpu_dispatch_is_reference(self):
         """With pallas off (the CPU default) the public entry point IS

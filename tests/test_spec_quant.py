@@ -130,8 +130,10 @@ class TestProposeDraft:
 # the windowed / quantized op
 
 
-def _window_case(seed, b=3, kvh=2, group=2, dh=8, page_size=8, pps=4, w=3):
-    """A w-row window case: each slot's page table covers its window."""
+def _window_case(seed, b=3, kvh=2, group=2, dh=64, page_size=8, pps=4, w=3):
+    """A w-row window case: each slot's page table covers its window.
+    Pages are whole 8 x 128 tiles, the smallest the compiled kernel
+    takes: the kernel tests run interpreted here, under Mosaic on chip."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     n_pages = b * pps + 2
     hl = kvh * group
@@ -216,6 +218,47 @@ class TestWindowedOp:
                                    atol=2e-5, rtol=2e-5)
         for a, b in zip(out_k[1:], out_r[1:]):   # pools + scales
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("positions,w", [
+        ([0, 7, 19], 3),        # the window case's own positions
+        ([31, 4, 26], 1),       # 4, 1 and 4 pages in rounds of three
+        ([29, None, 12], 3),    # an idle slot between live ones
+    ])
+    def test_interpret_kernel_quantized_rounds_match_reference(
+            self, pallas_kernels, monkeypatch, positions, w):
+        """int8 pools through the in-kernel page walk at THREE pages a
+        DMA round (4 pages a slot is no multiple of it): a round spreads
+        three scale columns over its block, the last one fewer."""
+        from apex_tpu.ops import decode_attention
+
+        q, k_new, v_new, kp, vp, _, _ = _window_case(4, w=w)
+        b, ps, f = q.shape[0], kp.shape[1], kp.shape[2]
+        pps, n_pages = 4, kp.shape[0]
+        monkeypatch.setattr(decode_attention, "_BUFFER_BYTES", 3 * ps * f)
+        decode_attention._pallas.clear_cache()
+        assert decode_attention._pages_per_round(ps, f, jnp.int8, pps) == 3
+        live = np.array([p is not None for p in positions])
+        pos = np.array([p or 0 for p in positions], np.int32)
+        pt = np.full((b, pps), n_pages, np.int32)
+        perm = iter(np.random.RandomState(5).permutation(n_pages))
+        for r in np.flatnonzero(live):
+            for j in range(paged_pages_for(int(pos[r]) + w, ps)):
+                pt[r, j] = next(perm)
+        k_q, k_s, v_q, v_s = _quantize_pools(kp, vp)
+        args = (q, k_new, v_new, k_q, v_q, k_s, v_s, jnp.asarray(pt),
+                jnp.asarray(pos))
+        try:
+            out_k = _pallas(*args, group=2, sliding_window=None)
+        finally:
+            decode_attention._pallas.clear_cache()
+        out_r = jax.jit(_reference, static_argnames=(
+            "group", "sliding_window"))(*args, group=2, sliding_window=None)
+        np.testing.assert_allclose(np.asarray(out_k[0])[live],
+                                   np.asarray(out_r[0])[live],
+                                   atol=2e-5, rtol=2e-5)
+        assert not np.asarray(out_k[0])[~live].any()
+        for a, b_ in zip(out_k[1:], out_r[1:]):   # pools + scales
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
     def test_scale_grows_monotonically_and_rescales_residents(self):
         """Rescale-on-append: a page's scale only ever grows; resident

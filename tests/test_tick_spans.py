@@ -149,6 +149,13 @@ def test_leaf_spans_carry_their_counts(traced_ticks, name, keys):
     assert mine, f"no {name} span with {sorted(keys)}"
     if name == tracing.TICK_DISPATCH:
         assert {e[3]["program"] for e in mine} == {"decode", "paged_prefill"}
+        # a decode step says how many pages its kernel walks: at least
+        # one a row, never more than the rows' whole tables
+        for e in mine:
+            if e[3]["program"] == "decode":
+                rows = int(e[3]["rows"])
+                assert rows <= int(e[3]["pages"]) \
+                    <= rows * ENGINE.pages_per_slot
     if name == tracing.TICK_COMMIT:
         # the spans count the tokens the requests got, and each
         # retirement once
