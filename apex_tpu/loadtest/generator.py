@@ -1,10 +1,8 @@
 """Seeded open-loop traffic generation from a scenario.
 
-The generator is the single source of synthetic serving traffic — the
-loadtest runner replays its schedule against the supervised engine, and
-``benchmarks/generation_bench.py``'s serving mode draws its request set
-from the same code path (mirroring how FLOP math was unified into
-``apex_tpu/utils/flops.py``: one formula, many consumers).
+The generator is the source of the load test's synthetic serving
+traffic — the loadtest runner replays its schedule against the
+supervised engine.
 
 **Open loop**: arrival times are drawn up front as a Poisson process
 (exponential inter-arrival gaps at each phase's rate) and never react to

@@ -157,8 +157,7 @@ def _build_serving(scenario: Scenario, model, params,
                 model.config, knobs.lora_rank, keys[ix]))
     engine_cfg = EngineConfig(
         max_slots=knobs.max_slots, max_len=knobs.max_len,
-        kv_layout=knobs.kv_layout, page_size=knobs.page_size,
-        n_pages=knobs.n_pages,
+        page_size=knobs.page_size, n_pages=knobs.n_pages,
         prefix_cache=knobs.prefix_cache,
         prefix_lru_capacity=knobs.prefix_lru_capacity,
         kv_dtype=knobs.kv_dtype,

@@ -102,8 +102,8 @@ class EngineKnobs:
     """Engine/scheduler sizing — the subset of
     :class:`~apex_tpu.serving.EngineConfig` /
     :class:`~apex_tpu.serving.SchedulerConfig` a scenario varies.
-    ``kv_layout``/``page_size``/``n_pages`` select and size the paged KV
-    pool (docs/serving.md#paged-kv); ``n_pages=None`` fully backs every
+    ``page_size``/``n_pages`` size the paged KV pool
+    (docs/serving.md#paged-kv); ``n_pages=None`` fully backs every
     slot at ``max_len`` — set it lower to overcommit, which is how the
     ``long_context`` scenario expresses "this mix fits paged but could
     not fit dense rows in the same HBM". ``prefix_cache`` /
@@ -113,8 +113,8 @@ class EngineKnobs:
     ``kv_dtype="int8"`` serves from the quantized pool
     (docs/serving.md#kv-quantization) and ``speculation=k`` turns on
     k-row speculative verify windows
-    (docs/serving.md#speculative-decoding) — both paged-only, like the
-    engine knobs they mirror. ``lora_adapters``/``lora_rank`` > 0 serve
+    (docs/serving.md#speculative-decoding).
+    ``lora_adapters``/``lora_rank`` > 0 serve
     the traffic through a LoRA :class:`~apex_tpu.lora.AdapterStore` of
     that many seeded rank-``lora_rank`` adapters (ids ``"0"`` ..
     ``"n-1"``), which phases address via ``adapter_mix``
@@ -124,7 +124,6 @@ class EngineKnobs:
     max_len: int = 64
     max_queue: int = 64
     max_prefills_per_tick: int = 1
-    kv_layout: str = "paged"
     page_size: int = 64
     n_pages: Optional[int] = None
     prefix_cache: bool = True
@@ -138,10 +137,6 @@ class EngineKnobs:
     prefill_token_budget: Optional[int] = None
 
     def __post_init__(self):
-        if self.kv_layout not in ("flat", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'flat' or 'paged', got "
-                f"{self.kv_layout!r}")
         if self.prefix_lru_capacity < 0:
             raise ValueError(
                 f"prefix_lru_capacity must be >= 0, got "
@@ -152,18 +147,10 @@ class EngineKnobs:
             raise ValueError(
                 f"kv_dtype must be 'bf16' or 'int8', got "
                 f"{self.kv_dtype!r}")
-        if self.kv_dtype == "int8" and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype='int8' needs kv_layout='paged' (scales are "
-                "per-page)")
         if self.speculation < 0 or self.speculation == 1:
             raise ValueError(
                 f"speculation must be 0 (off) or a window >= 2, got "
                 f"{self.speculation}")
-        if self.speculation and self.kv_layout != "paged":
-            raise ValueError(
-                "speculation needs kv_layout='paged' (the windowed "
-                "verify rides the paged kernel)")
         if self.lora_rank < 0 or self.lora_adapters < 0:
             raise ValueError(
                 f"lora_rank/lora_adapters must be >= 0, got "
@@ -178,19 +165,19 @@ class EngineKnobs:
                 raise ValueError(
                     f"prefill_token_budget must be >= 1, got "
                     f"{self.prefill_token_budget}")
-            if self.kv_layout == "paged" \
-                    and self.prefill_token_budget < self.page_size:
+            if self.prefill_token_budget < self.page_size:
                 raise ValueError(
                     f"prefill_token_budget ({self.prefill_token_budget}) "
-                    f"must be >= page_size ({self.page_size}) under the "
-                    f"paged layout — chunk boundaries are page-aligned")
+                    f"must be >= page_size ({self.page_size}) — chunk "
+                    f"boundaries are page-aligned")
 
     @classmethod
     def from_dict(cls, data: Dict[str, Any]) -> "EngineKnobs":
         d = dict(data)
+        unknown = set(d) - set(cls.__dataclass_fields__)
+        if unknown:
+            raise ValueError(f"unknown engine keys {sorted(unknown)}")
         kw: Dict[str, Any] = {}
-        if "kv_layout" in d:
-            kw["kv_layout"] = str(d.pop("kv_layout"))
         if "kv_dtype" in d:
             kw["kv_dtype"] = str(d.pop("kv_dtype"))
         if "n_pages" in d:
@@ -209,7 +196,7 @@ class EngineKnobs:
             "max_slots": self.max_slots, "max_len": self.max_len,
             "max_queue": self.max_queue,
             "max_prefills_per_tick": self.max_prefills_per_tick,
-            "kv_layout": self.kv_layout, "page_size": self.page_size}
+            "page_size": self.page_size}
         if self.n_pages is not None:
             out["n_pages"] = self.n_pages
         if not self.prefix_cache:

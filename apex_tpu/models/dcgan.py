@@ -2,8 +2,8 @@
 
 Capability counterpart of the reference's mixed-precision GAN example
 (``/root/reference/examples/dcgan/main_amp.py``: 64x64 DCGAN trained with two
-optimizers and two loss scalers through ``amp.initialize(num_losses=3)``) —
-one of BASELINE.json's parity configs. The interesting apex capability it
+optimizers and two loss scalers through ``amp.initialize(num_losses=3)``).
+The interesting apex capability it
 exercises is *multiple models/optimizers/losses under one amp context*;
 here both nets are plain functional modules, and the multi-loss-scaler story
 is :class:`apex_tpu.amp.DynamicLossScaler` instances carried per loss.

@@ -126,8 +126,8 @@ def init_paged_kv_caches(model, n_pages: int, page_size: int, dtype=None,
                          *, quantized: bool = False):
     """Preallocate the PAGED decode cache: a list of per-layer
     ``(k_pages, v_pages)`` pairs, each ``[n_pages, page_size,
-    local_kv_heads * head_dim]`` — the serving engine's
-    ``kv_layout="paged"`` pool (docs/serving.md#paged-kv). The pool keeps
+    local_kv_heads * head_dim]`` — the serving engine's page pool
+    (docs/serving.md#paged-kv). The pool keeps
     the flat form's fused heads-minor dim (full-lane page reads, and the
     dim the sharded engine splits over the tensor axis); slots map onto
     pool rows through a host-owned page table, so HBM is committed to

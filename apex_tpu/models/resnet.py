@@ -5,8 +5,7 @@ ImageNet training under amp O2 + apex DDP
 (``/root/reference/examples/imagenet/main_amp.py``; the model itself comes
 from torchvision there, but the *capability* — a convnet exercising amp,
 SyncBN (``apex/parallel/optimized_sync_batchnorm.py``), fused optimizers and
-data parallelism — is apex's headline configuration and BASELINE.json's
-north-star config).
+data parallelism — is apex's headline configuration).
 
 TPU design (not a port):
 

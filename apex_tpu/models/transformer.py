@@ -965,7 +965,7 @@ class ParallelAttention:
                 # of the KV stream per step); its reference path replays
                 # the flat s==1 formulation below bit-for-bit on the
                 # gathered logical view, so paged serving stays
-                # token-exact against the flat engine. ``s > 1`` is the
+                # token-exact against generate(). ``s > 1`` is the
                 # speculative verify window: each slot appends/attends a
                 # window of ``s`` rows starting at its own cache_index
                 # (window query t masks to rows <= index + t). With an

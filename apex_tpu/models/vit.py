@@ -1,7 +1,8 @@
 """Vision Transformer (ViT-B/L/H) on the parallel transformer toolkit.
 
-BASELINE.json lists "ViT-L/16 SyncBatchNorm + FusedAdam across v5p-64" as a
-target config; the reference itself has no ViT, but its Megatron blocks are
+ViT-L/16 (24 layers, hidden 1024, 16 heads, 16x16 patches) under
+SyncBatchNorm + FusedAdam is a configuration apex users train; the
+reference itself has no ViT, but its Megatron blocks are
 the obvious substrate (the same way NeMo builds ViT on apex's
 ``apex/transformer``). Patch embedding is a single strided conv (an MXU
 matmul after im2col — XLA does this folding), then the standard

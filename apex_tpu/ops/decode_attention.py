@@ -1,7 +1,7 @@
 """Fused decode-step attention over a PAGED KV cache.
 
-The serving engine's decode roofline (PERF.md, BENCH_r05) showed the
-gap to the HBM read-bandwidth bound *growing* with batch — 78%/76%/65%
+The serving engine's decode roofline (a capture from before PR 1, on
+another jax) showed the gap to the HBM read-bandwidth bound *growing* with batch — 78%/76%/65%
 at bs 1/8/32 — which indicts the unfused chain, not the cache reads:
 XLA's paged-cache gather materializes a ``[b, S, f]`` temporary (read
 pool + write temp + re-read temp = ~3x the stream), and the per-slot
@@ -70,8 +70,9 @@ Dispatch follows the repo convention (:mod:`apex_tpu.ops._support`):
 the Pallas kernel on TPU (or under ``APEX_TPU_FORCE_PALLAS=interpret``
 for CI parity), and a pure-``jnp`` reference elsewhere. The reference
 reproduces the flat cache's single-token MXU formulation bit-for-bit on
-the gathered logical view, so the paged engine stays TOKEN-EXACT
-against the flat engine on CPU (the tier-1 parity bar); the kernel's
+the gathered logical view, so the engine stays TOKEN-EXACT against
+per-request decode on the flat cache on CPU (the tier-1 parity bar:
+``tests/serving_reference.py``); the kernel's
 flash accumulation is validated against the reference to numerical
 tolerance in interpret mode, compiled by the real Mosaic compiler in
 tier-1 (``tests/test_chip_smoke.py``, no chip needed) and compared on
@@ -242,7 +243,8 @@ def _reference(q, k_new, v_new, k_pages, v_pages, k_scales, v_scales,
     ``w`` sequential single-row calls — and ``w == 1`` is the PR 9
     reference unchanged). Real rows see the exact same operand values
     and reduction order as the flat path (padded rows mask to exact
-    zeros), so flat-vs-paged engine parity is bitwise, not approximate."""
+    zeros), so the engine's parity with decode on the flat cache is
+    bitwise, not approximate."""
     n_pages, page_size, f = k_pages.shape
     b, w, hl, dh = q.shape
     kvh = f // dh

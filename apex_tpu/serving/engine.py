@@ -9,25 +9,43 @@ step**, not per batch, over one fixed-shape jitted decode program.
 
 Architecture (docs/serving.md has the full walkthrough):
 
-- **Slot pool**: a ``[max_slots, max_len]`` batched FLAT KV cache
-  (``init_kv_caches(stacked=False, flat=True)``) whose rows are
-  independent requests; :class:`~apex_tpu.serving.slots.SlotPool` does
-  free-list allocation, eviction on EOS/length budget/cancel/timeout.
+- **Page pool**: every layer holds one ``[n_pages, page_size, kv_heads *
+  head_dim]`` K and V pool (``init_paged_kv_caches``; int8 with a
+  per-(page, kv-head) scale sidecar under ``kv_dtype="int8"``) shared
+  by all slots. :class:`~apex_tpu.serving.slots.SlotPool` hands out
+  slots, :class:`~apex_tpu.serving.slots.PagePool` the pages behind
+  them: a request maps the pages of its prompt at admission (its worst
+  case is reserved, so growth cannot fail), grows by a page when decode
+  crosses a boundary, and returns them on EOS/length budget/cancel/
+  timeout. A prompt's full pages are interned by content, so a later
+  prompt with the same prefix maps them refcounted.
+- **Page table**: a host ``[max_slots, pages_per_slot]`` int32 array,
+  uploaded with every step; an unmapped entry holds the sentinel
+  ``n_pages`` (reads clamp and mask, scatters drop). Arrivals,
+  retirements and page growth change host arrays only.
 - **One decode program**: a single ``jax.jit`` step over ALL slots with
-  per-slot position vectors (the vector ``cache_index`` capability of
-  the flat cache path — attention masks each row to its own length, rope
-  rotates each row at its own offset, and per-request sampling runs
-  in-jit from per-slot temperature/top-k/seed arrays). Arrivals and
-  retirements mutate host-side arrays only, so the decode step NEVER
-  retraces — asserted by a
-  :class:`~apex_tpu.analysis.retrace.RetraceWatchdog`, since the decode
-  roofline (PAPERS: arXiv 2502.17728) is only reachable when every step
-  is the same compiled program.
-- **Bucketed prefill**: prompts prefill one-at-a-time, right-padded to
-  power-of-two buckets, on the SAME 4D-list/flash path ``generate()``
-  uses (then flattened and scattered into the slot row) — compile count
-  is bounded by the bucket set and greedy outputs are token-exact
-  against per-request ``generate()`` calls.
+  per-slot position vectors. Each layer runs one fused append+attend
+  (:mod:`apex_tpu.ops.decode_attention`): the step's K/V row is written
+  into the slot's current page and the slot's mapped pages are read
+  once, each row masked to its own length, rope rotated at its own
+  offset; per-request sampling runs in-jit from per-slot
+  temperature/top-k/seed arrays. The step NEVER retraces — asserted by
+  a :class:`~apex_tpu.analysis.retrace.RetraceWatchdog`, since the
+  decode roofline (PAPERS: arXiv 2502.17728) is only reachable when
+  every step is the same compiled program. With ``speculation=k`` the
+  same step feeds a ``[n, k]`` verify window.
+- **Bucketed prefill that fills pages**: prompts prefill one at a time,
+  right-padded to power-of-two buckets, on the SAME 4D-list/flash path
+  ``generate()`` uses; the K/V rows are then flattened and scattered
+  into the slot's pages, whole pages at a time. Compile count is bounded
+  by the bucket set and outputs are token-exact against a per-request
+  reference (``tests/serving_reference.py``).
+- **Suffix prefill, doubling as the chunk program**: a prefix-cache hit
+  prefills only what its shared pages do not cover: the slot's pages
+  are gathered into a small 4D cache, the suffix runs at its absolute
+  offset (a traced scalar) and its rows are scattered back one by one.
+  Under ``prefill_token_budget`` a long prompt is a sequence of such
+  calls carried across ticks, so chunking adds no program and no shape.
 - **Scheduling**: FCFS bounded queue with a decode-starvation cap
   (:mod:`apex_tpu.serving.scheduler`); queue-full rejection, deadlines,
   and cancellation follow ``resilience``'s structured ``log_event``
@@ -39,10 +57,10 @@ Architecture (docs/serving.md has the full walkthrough):
   per-slot ``isfinite(logits)`` flag (one cheap in-jit reduction —
   resilience's off-critical-path watchdog idea applied per slot). A row
   with non-finite logits or an out-of-vocab token is **quarantined**:
-  its request retires with ``finish_reason="error"``, its KV row is
-  scrubbed and the slot released — co-tenant rows keep serving,
-  unperturbed (rows are independent through the vmap'd flat-cache
-  attention, so one poisoned row cannot contaminate the others).
+  its request retires with ``finish_reason="error"``, the pages it
+  frees are scrubbed and the slot released — co-tenant rows keep serving,
+  unperturbed (a slot reads no page but its own, so one poisoned row
+  cannot contaminate the others).
   Tick-level failures (decode/prefill exceptions, hung ticks) and
   admission control under overload are the
   :class:`~apex_tpu.serving.supervisor.EngineSupervisor`'s job —
@@ -177,18 +195,16 @@ class EngineConfig:
     in place on TPU; ``None`` auto-disables it on the CPU backend (which
     cannot donate and would warn every compile).
 
-    KV layout (docs/serving.md#paged-kv): ``kv_layout="paged"`` (the
-    default) backs slots with a shared page pool — ``n_pages`` pages of
-    ``page_size`` tokens per layer — so HBM is committed to actual
-    context length and ``max_slots`` can exceed what dense rows would
-    fit; decode runs the fused append+attend kernel. ``n_pages=None``
-    sizes the pool to fully back every slot at ``max_len`` (same
-    capacity as flat — no admission behavior change); size it below that
-    to overcommit, and the engine sheds ``pages_exhausted`` when a
-    request's worst case can never fit. ``kv_layout="flat"`` keeps the
-    dense ``[max_slots, max_len]`` rows for bisection.
+    KV pages (docs/serving.md#paged-kv): slots are backed by a shared
+    page pool — ``n_pages`` pages of ``page_size`` tokens per layer —
+    so HBM is committed to actual context length and ``max_slots`` can
+    exceed what dense rows would fit; decode runs the fused
+    append+attend kernel. ``n_pages=None`` sizes the pool to fully back
+    every slot at ``max_len`` (admission then waits on slots only);
+    size it below that to overcommit, and the engine sheds
+    ``pages_exhausted`` when a request's worst case can never fit.
 
-    Prefix cache (docs/serving.md#prefix-cache, paged layout only):
+    Prefix cache (docs/serving.md#prefix-cache):
     ``prefix_cache=True`` interns each prompt's page-aligned prefix into
     the pool's content-addressed index, so a later prompt sharing that
     prefix maps the interned pages refcounted and prefills ONLY its
@@ -198,7 +214,7 @@ class EngineConfig:
     LRU-first under page pressure). ``prefix_cache=False`` restores the
     PR 9 one-owner pool bit-for-bit.
 
-    Decode-roofline knobs (paged layout only):
+    Decode-roofline knobs:
     ``kv_dtype="int8"`` (docs/serving.md#kv-quantization) stores the
     page pools int8 with per-(page, kv-head) scale sidecars — half the
     decode HBM stream, dequantized inline in the fused kernel;
@@ -216,11 +232,11 @@ class EngineConfig:
     run — a long prompt prefills as a sequence of bucketed chunk
     programs carried across ticks, interleaved with the batched decode
     step, so co-tenant TPOT never stalls for more than one chunk's
-    compute. Internal chunk boundaries are page-aligned under the paged
-    layout (so int8 scales and prefix interning stay bitwise what the
-    monolithic fill produces) and outputs are token-exact, greedy and
-    sampled. ``None`` (default) keeps the one-shot prefill path
-    unchanged.
+    compute. Internal chunk boundaries are page-aligned (so int8 scales
+    and prefix interning stay bitwise what the monolithic fill
+    produces), hence ``prefill_token_budget >= page_size``, and outputs
+    are token-exact, greedy and sampled. ``None`` (default) keeps the
+    one-shot prefill path unchanged.
     """
 
     max_slots: int = 8
@@ -228,7 +244,6 @@ class EngineConfig:
     scheduler: SchedulerConfig = field(default_factory=SchedulerConfig)
     retrace_budget: Optional[int] = 0
     donate_caches: Optional[bool] = None
-    kv_layout: str = "paged"
     page_size: int = 64
     n_pages: Optional[int] = None
     prefix_cache: bool = True
@@ -244,10 +259,6 @@ class EngineConfig:
             raise ValueError(
                 f"max_len must be >= 2 (one prompt + one generated token), "
                 f"got {self.max_len}")
-        if self.kv_layout not in ("flat", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'flat' or 'paged', got "
-                f"{self.kv_layout!r}")
         if self.page_size < 1:
             raise ValueError(
                 f"page_size must be >= 1, got {self.page_size}")
@@ -261,31 +272,22 @@ class EngineConfig:
             raise ValueError(
                 f"kv_dtype must be 'bf16' or 'int8', got "
                 f"{self.kv_dtype!r}")
-        if self.kv_dtype == "int8" and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype='int8' needs kv_layout='paged' — the scales "
-                "are per-page sidecars")
         if self.speculation < 0 or self.speculation == 1:
             raise ValueError(
                 f"speculation is 0 (off) or a verify window >= 2, got "
                 f"{self.speculation}")
-        if self.speculation and self.kv_layout != "paged":
-            raise ValueError(
-                "speculation needs kv_layout='paged' — the verify "
-                "window rides the fused paged kernel")
         if self.prefill_token_budget is not None:
             if self.prefill_token_budget < 1:
                 raise ValueError(
                     f"prefill_token_budget must be >= 1 (or None to "
                     f"disable chunking), got {self.prefill_token_budget}")
-            if (self.kv_layout == "paged"
-                    and self.prefill_token_budget < self.page_size):
+            if self.prefill_token_budget < self.page_size:
                 raise ValueError(
                     f"prefill_token_budget ({self.prefill_token_budget}) "
-                    f"must be >= page_size ({self.page_size}) under the "
-                    f"paged layout — internal chunk boundaries are "
-                    f"page-aligned, so a smaller budget could never make "
-                    f"progress on a multi-page prompt")
+                    f"must be >= page_size ({self.page_size}) — internal "
+                    f"chunk boundaries are page-aligned, so a smaller "
+                    f"budget could never make progress on a multi-page "
+                    f"prompt")
 
     @property
     def pages_per_slot(self) -> int:
@@ -487,51 +489,43 @@ class InferenceEngine:
         if c.compute_dtype != jnp.float32:
             params = cast_decode_params(params, c.compute_dtype)
         self._params = preslice_layer_params(params, c.num_layers)
-        if self.config.kv_layout == "paged":
-            pps = self.config.pages_per_slot
-            n_pages = (self.config.n_pages if self.config.n_pages is not None
-                       else self.config.max_slots * pps)
-            self.pages: Optional[PagePool] = PagePool(
-                n_pages, self.config.page_size, pps,
-                lru_capacity=(self.config.prefix_lru_capacity
-                              if self.config.prefix_cache else 0))
-            #: salt for the prompt-prefix hash chains — keyed by the
-            #: model fingerprint (K/V are sampling-invariant), with each
-            #: request's adapter_id folded in at hash time: adapter
-            #: deltas write adapter-specific K/V, so tenants must never
-            #: alias pages across adapters (see prefix.adapter_salt)
-            self._prefix_salt = prefix_salt(c)
-            self._evictions_seen = 0
-            self._quantized = self.config.kv_dtype == "int8"
-            self._caches = init_paged_kv_caches(
-                model, n_pages, self.config.page_size,
-                quantized=self._quantized)
-            # HBM bytes one decode step streams per mapped page (K + V
-            # across all layers, plus the f32 scale sidecars when
-            # quantized) — the kv_bytes_per_step gauge's unit, computed
-            # from the GLOBAL head count so the number means the same
-            # thing sharded and unsharded
-            f_dim = c.kv_heads * c.head_dim
-            item = 1 if self._quantized else jnp.dtype(
-                c.compute_dtype).itemsize
-            self._page_read_bytes = 2 * c.num_layers * (
-                self.config.page_size * f_dim * item
-                + (c.kv_heads * 4 if self._quantized else 0))
-            # host page table; n_pages is the unmapped sentinel (reads
-            # clamp+mask, scatters drop — see ops/decode_attention.py)
-            self._page_table_h = np.full(
-                (self.config.max_slots, pps), n_pages, np.int32)
-            #: worst-case pages promised to admitted requests — admission
-            #: only lets a request in when its full total_len reservation
-            #: fits, so decode-time extends can NEVER exhaust the pool
-            #: (no mid-flight eviction policy needed; see _admit)
-            self._reserved_pages = 0
-        else:
-            self.pages = None
-            self._quantized = False
-            self._caches = init_kv_caches(
-                model, self.config.max_slots, self.config.max_len,
-                stacked=False, flat=True)
+        pps = self.config.pages_per_slot
+        n_pages = self.config.n_pages or self.config.max_slots * pps
+        self.pages = PagePool(
+            n_pages, self.config.page_size, pps,
+            lru_capacity=(self.config.prefix_lru_capacity
+                          if self.config.prefix_cache else 0))
+        #: salt for the prompt-prefix hash chains — keyed by the
+        #: model fingerprint (K/V are sampling-invariant), with each
+        #: request's adapter_id folded in at hash time: adapter
+        #: deltas write adapter-specific K/V, so tenants must never
+        #: alias pages across adapters (see prefix.adapter_salt)
+        self._prefix_salt = prefix_salt(c)
+        self._evictions_seen = 0
+        self._quantized = self.config.kv_dtype == "int8"
+        self._caches = init_paged_kv_caches(
+            model, n_pages, self.config.page_size,
+            quantized=self._quantized)
+        # HBM bytes one decode step streams per mapped page (K + V
+        # across all layers, plus the f32 scale sidecars when
+        # quantized) — the kv_bytes_per_step gauge's unit, computed
+        # from the GLOBAL head count so the number means the same
+        # thing sharded and unsharded
+        f_dim = c.kv_heads * c.head_dim
+        item = 1 if self._quantized else jnp.dtype(
+            c.compute_dtype).itemsize
+        self._page_read_bytes = 2 * c.num_layers * (
+            self.config.page_size * f_dim * item
+            + (c.kv_heads * 4 if self._quantized else 0))
+        # host page table; n_pages is the unmapped sentinel (reads
+        # clamp+mask, scatters drop — see ops/decode_attention.py)
+        self._page_table_h = np.full(
+            (self.config.max_slots, pps), n_pages, np.int32)
+        #: worst-case pages promised to admitted requests — admission
+        #: only lets a request in when its full total_len reservation
+        #: fits, so decode-time extends can NEVER exhaust the pool
+        #: (no mid-flight eviction policy needed; see _admit)
+        self._reserved_pages = 0
 
         n = self.config.max_slots
         self._tokens_h = np.zeros(n, np.int32)
@@ -556,16 +550,14 @@ class InferenceEngine:
         #: attributes): the host arrays of ``_decode_args``
         up = [self._window_h if self._spec else self._tokens_h,
               self._positions_h, self._temps_h, self._topks_h,
-              self._seeds_h, self._adapter_ix_h]
-        if self.pages is not None:
-            up.append(self._page_table_h)
+              self._seeds_h, self._adapter_ix_h, self._page_table_h]
         self._decode_upload = (len(up), sum(a.nbytes for a in up))
 
         donate = self.config.donate_caches
         if donate is None:
             donate = jax.default_backend() != "cpu"
 
-        decode_fn, prefill_fn, suffix_fn, chunk_fn, scrub_fn, reset_fn = \
+        decode_fn, prefill_fn, suffix_fn, scrub_fn, reset_fn = \
             self._build_step_fns(donate)
         self._decode_fn = RetraceWatchdog(
             decode_fn,
@@ -579,16 +571,11 @@ class InferenceEngine:
             name="serving_prefill", metrics=self.metrics)
         # suffix prefill (prefix-cache hits) buckets exactly like full
         # prefill, so its compile count has the same bound; under
-        # chunked prefill it doubles as the paged CHUNK program (the
+        # chunked prefill it doubles as the CHUNK program (the
         # chunk offset is a traced scalar, so chunking adds no shapes)
-        self._suffix_fn = None if suffix_fn is None else RetraceWatchdog(
+        self._suffix_fn = RetraceWatchdog(
             suffix_fn, budget=None, expected_compiles=len(self.buckets),
             name="serving_suffix_prefill", metrics=self.metrics)
-        # flat-layout chunk program (paged chunks ride _suffix_fn) —
-        # bucketed like prefill, so the same compile bound holds
-        self._chunk_fn = None if chunk_fn is None else RetraceWatchdog(
-            chunk_fn, budget=None, expected_compiles=len(self.buckets),
-            name="serving_chunk_prefill", metrics=self.metrics)
         self._scrub_fn = scrub_fn
         self._reset_scales_fn = reset_fn
 
@@ -618,56 +605,13 @@ class InferenceEngine:
         size = int(np.prod(shape))
         return nxt[:size].reshape(shape), nxt[size:].reshape(-1, 3)
 
-    def _decode_body(self, params, caches, tokens, positions, temps,
-                     topks, seeds, adapter_ix, lora):
-        stats = self._routing(positions)
-        logits, caches = decode_step(self.model, params, caches, tokens,
-                                     positions,
-                                     lora=_select_adapters(lora, adapter_ix),
-                                     routing=stats)
-        nxt = _sample_tokens(logits, temps, topks, seeds, positions + 1)
-        # per-slot integrity flag: one cheap in-jit reduction so the
-        # host can quarantine a poisoned row without fetching logits
-        finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        return self._with_routing(nxt, stats), finite, caches
-
-    def _scrub_body(self, caches, slot):
-        # zero one slot's KV rows across every layer — quarantine
-        # hygiene, so a poisoned row's NaNs can never reach a future
-        # occupant even through a masked-weight * NaN-value product
-        return [(k.at[slot].set(0.0), v.at[slot].set(0.0))
-                for k, v in caches]
-
-    def _prefill_body(self, params, caches, prompt, slot, prompt_len,
-                      temp, topk, seed, adapter_ix, lora):
-        # the EXACT prefill generate() runs (4D per-layer list -> the
-        # cache_index==0 causal-flash fast path), at the bucket-padded
-        # length; pad rows are causally invisible to real rows and
-        # their K/V land beyond the row's live length, so they are
-        # never read back
-        model = self.model
-        small = init_kv_caches(model, 1, prompt.shape[1], stacked=False)
-        logits, small = _cached_forward(model, params, small, prompt, 0,
-                                        last_index=prompt_len - 1,
-                                        lora=_select_adapters(lora,
-                                                              adapter_ix))
-        flat = flatten_decode_caches(small, model.config.num_layers)
-        new = [
-            (jax.lax.dynamic_update_slice(bk, fk, (slot, 0, 0)),
-             jax.lax.dynamic_update_slice(bv, fv, (slot, 0, 0)))
-            for (bk, bv), (fk, fv) in zip(caches, flat)]
-        first = _sample_tokens(logits[0], temp[None], topk[None],
-                               seed[None], prompt_len[None])
-        return first[0], new
-
     def _paged_decode_body(self, params, caches, page_table, tokens,
                            positions, temps, topks, seeds, adapter_ix,
                            lora):
-        # same decode step over the PAGED pool: one fused append+attend
-        # per layer (apex_tpu.ops.decode_attention) instead of the flat
-        # row scatter + masked read; with the pool donated the appends
-        # are in-place row writes, so per step the KV traffic is one
-        # read of the mapped stream plus one row
+        # one decode step over the page pool: one fused append+attend
+        # per layer (apex_tpu.ops.decode_attention); with the pool
+        # donated the appends are in-place row writes, so per step the
+        # KV traffic is one read of the mapped stream plus one row
         stats = self._routing(positions)
         logits, caches = decode_step(self.model, params, caches, tokens,
                                      positions, paged_state=page_table,
@@ -707,8 +651,9 @@ class InferenceEngine:
     def _paged_scrub_body(self, caches, page_row):
         # zero exactly the quarantined slot's mapped pages across every
         # layer (``page_row`` is its fixed-width table row; sentinel
-        # entries drop) — same NaN-hygiene contract as the flat scrub,
-        # but foreign slots' pages are never touched. Quantized pools
+        # entries drop), so its NaNs can never reach a future occupant
+        # even through a masked-weight * NaN-value product; foreign
+        # slots' pages are never touched. Quantized pools
         # zero the scale sidecar too, so a recycled page starts from a
         # clean rescale baseline (slots.PagePool.check asserts this).
         if self._quantized:
@@ -733,10 +678,11 @@ class InferenceEngine:
     def _paged_prefill_body(self, params, caches, page_row, prompt,
                             prompt_len, temp, topk, seed, adapter_ix,
                             lora):
-        # identical prefill compute to the flat body (same 4D small-cache
-        # forward, so greedy outputs stay token-exact); only the landing
-        # differs — the flattened rows scatter into this slot's freshly
-        # mapped pages. Chunks past the mapped count (bucket padding)
+        # the EXACT prefill generate() runs (4D per-layer list -> the
+        # cache_index==0 causal-flash fast path) at the bucket-padded
+        # length, so outputs stay token-exact; the flattened rows then
+        # scatter into this slot's freshly mapped pages, a page at a
+        # time. Chunks past the mapped count (bucket padding)
         # carry the sentinel and drop; garbage rows inside the last
         # mapped page are causally masked by the row's position forever.
         model = self.model
@@ -786,8 +732,7 @@ class InferenceEngine:
         The slot's page table already maps the shared prefix pages for
         tokens ``[0, start)``; this body gathers those rows into a
         small 4D cache, runs the suffix forward at ``cache_index=start``
-        (offset-causal mask + rope at the absolute offset — the same
-        mid-cache path the flat engine's vectorized decode uses), and
+        (offset-causal mask + rope at the absolute offset), and
         scatters the suffix K/V into the slot's PRIVATE pages row by
         row. Shared pages are never written: when ``skip_first`` is set
         (a fully page-aligned hit, whose one-token "suffix" is a
@@ -875,99 +820,32 @@ class InferenceEngine:
                                seed[None], prompt_len[None])
         return first[0], jnp.all(jnp.isfinite(logits)), new
 
-    def _flat_chunk_body(self, params, caches, slot, chunk, start,
-                         chunk_len, prompt_len, temp, topk, seed,
-                         adapter_ix, lora):
-        """Prefill ONE bucketed chunk of a prompt into a flat slot row.
-
-        The flat analogue of the suffix body: gather the slot's dense
-        row (tokens ``[0, start)`` are live, later rows garbage the
-        offset-causal mask never attends) into a small 4D cache, run
-        the chunk forward at ``cache_index=start`` — rope and sampling
-        keyed to the ABSOLUTE position, so the final chunk's sample is
-        bitwise the monolithic prefill's first token — and scatter the
-        chunk's K/V rows back (pad rows drop)."""
-        model = self.model
-        max_len = self.config.max_len
-        bucket = chunk.shape[1]
-        # static length max_len + bucket keeps the chunk update
-        # in-bounds for any traced start
-        small = init_kv_caches(model, 1, max_len + bucket, stacked=False)
-        filled = []
-        for (bk, bv), (sk, sv) in zip(caches, small):
-            h, d = sk.shape[1], sk.shape[3]
-            f = bk.shape[-1]
-
-            def place(big, sm):
-                g = jax.lax.dynamic_slice(big, (slot, 0, 0),
-                                          (1, max_len, f))[0]
-                g = g.reshape(max_len, h, d).transpose(1, 0, 2)[None]
-                return sm.at[:, :, :max_len, :].set(g.astype(sm.dtype))
-
-            filled.append((place(bk, sk), place(bv, sv)))
-        logits, filled = _cached_forward(model, params, filled, chunk,
-                                         start, last_index=chunk_len - 1,
-                                         lora=_select_adapters(lora,
-                                                               adapter_ix))
-        idx = jnp.arange(bucket)
-        # pad rows (idx >= chunk_len) target row max_len — out of bounds
-        # for the dense row, so the drop-mode scatter discards them
-        dest = jnp.where(idx < chunk_len, start + idx, max_len)
-        new = []
-        for (bk, bv), (fk, fv) in zip(caches, filled):
-            h, d = fk.shape[1], fk.shape[3]
-
-            def rows(f4):
-                r = jax.lax.dynamic_slice_in_dim(f4, start, bucket, axis=2)
-                return r[0].transpose(1, 0, 2).reshape(bucket, h * d)
-
-            new.append(
-                (bk.at[slot, dest].set(rows(fk).astype(bk.dtype),
-                                       mode="drop"),
-                 bv.at[slot, dest].set(rows(fv).astype(bv.dtype),
-                                       mode="drop")))
-        first = _sample_tokens(logits[0], temp[None], topk[None],
-                               seed[None], prompt_len[None])
-        return first[0], jnp.all(jnp.isfinite(logits)), new
-
     def _build_step_fns(self, donate: bool):
         """Compile the device programs:
-        ``(decode, prefill, suffix_prefill, chunk_prefill, scrub,
-        reset_scales)`` — ``suffix_prefill`` is None under the flat
-        layout (no pages, no prefix cache), ``chunk_prefill`` is None
-        under the paged layout (paged chunks reuse the suffix program —
-        the chunk offset is a traced scalar), and ``reset_scales`` is
+        ``(decode, prefill, suffix_prefill, scrub, reset_scales)``.
+        ``suffix_prefill`` is also the chunk program of chunked prefill
+        (the chunk offset is a traced scalar) and ``reset_scales`` is
         None unless the pool is quantized. The base engine jits the
         bodies directly (single-chip);
         :class:`~apex_tpu.serving.fleet.ShardedEngine` overrides this to
-        wrap each body in ``shard_map`` over the tensor axis first. The
-        bodies are picked by ``kv_layout`` — both layouts keep the
-        caches as argument 1 so donation and the watchdogs are shared.
-        With ``speculation`` on, the decode program is the windowed
-        verify body (same arity: the [n] token vector becomes the
-        [n, k] window matrix)."""
+        wrap each body in ``shard_map`` over the tensor axis first.
+        Every body that runs the model takes the caches as argument 1,
+        so donation and the watchdogs are shared. With ``speculation``
+        on, the decode program is the windowed verify body (same arity:
+        the [n] token vector becomes the [n, k] window matrix)."""
         donate_args = (1,) if donate else ()
-        if self.pages is not None:
-            decode_body = (self._spec_decode_body if self._spec
-                           else self._paged_decode_body)
-            return (jax.jit(decode_body, donate_argnums=donate_args),
-                    jax.jit(self._paged_prefill_body,
-                            donate_argnums=donate_args),
-                    jax.jit(self._suffix_prefill_body,
-                            donate_argnums=donate_args),
-                    None,
-                    jax.jit(self._paged_scrub_body,
-                            donate_argnums=(0,) if donate else ()),
-                    jax.jit(self._reset_scales_body,
-                            donate_argnums=(0,) if donate else ())
-                    if self._quantized else None)
-        return (jax.jit(self._decode_body, donate_argnums=donate_args),
-                jax.jit(self._prefill_body, donate_argnums=donate_args),
-                None,
-                jax.jit(self._flat_chunk_body, donate_argnums=donate_args),
-                jax.jit(self._scrub_body,
+        decode_body = (self._spec_decode_body if self._spec
+                       else self._paged_decode_body)
+        return (jax.jit(decode_body, donate_argnums=donate_args),
+                jax.jit(self._paged_prefill_body,
+                        donate_argnums=donate_args),
+                jax.jit(self._suffix_prefill_body,
+                        donate_argnums=donate_args),
+                jax.jit(self._paged_scrub_body,
                         donate_argnums=(0,) if donate else ()),
-                None)
+                jax.jit(self._reset_scales_body,
+                        donate_argnums=(0,) if donate else ())
+                if self._quantized else None)
 
     @property
     def _bank(self):
@@ -1008,12 +886,9 @@ class InferenceEngine:
     @property
     def chunk_compiles(self) -> int:
         """Distinct chunk-program shapes compiled under chunked prefill
-        — bounded by ``len(buckets)`` (on the paged layout the chunk
-        program IS the suffix program, so this counts its shapes)."""
-        if self.pages is not None:
-            return 0 if self._suffix_fn is None else \
-                self._suffix_fn.compiles
-        return 0 if self._chunk_fn is None else self._chunk_fn.compiles
+        — bounded by ``len(buckets)`` (the chunk program IS the suffix
+        program, so this counts its shapes)."""
+        return self._suffix_fn.compiles
 
     @property
     def decode_compiles(self) -> int:
@@ -1206,21 +1081,20 @@ class InferenceEngine:
             self._decode_tick(finished)
             with span(TICK_COMMIT):
                 self.metrics.observe("slot_occupancy", self.slots.occupancy)
-                if self.pages is not None:
-                    self.metrics.set_gauge("kv_pages_in_use",
-                                           self.pages.in_use_count)
-                    self.metrics.set_gauge("kv_pages_free",
-                                           self.pages.free_count)
-                    self.metrics.observe("kv_page_occupancy",
-                                         self.pages.occupancy)
-                    if self._window_share:
-                        self.metrics.set_gauge(
-                            "kv_pages_out_of_window",
-                            self._pages_out_of_window())
-                    delta = self.pages.evictions - self._evictions_seen
-                    if delta:
-                        self.metrics.inc("prefix_evictions", delta)
-                        self._evictions_seen = self.pages.evictions
+                self.metrics.set_gauge("kv_pages_in_use",
+                                       self.pages.in_use_count)
+                self.metrics.set_gauge("kv_pages_free",
+                                       self.pages.free_count)
+                self.metrics.observe("kv_page_occupancy",
+                                     self.pages.occupancy)
+                if self._window_share:
+                    self.metrics.set_gauge(
+                        "kv_pages_out_of_window",
+                        self._pages_out_of_window())
+                delta = self.pages.evictions - self._evictions_seen
+                if delta:
+                    self.metrics.inc("prefix_evictions", delta)
+                    self._evictions_seen = self.pages.evictions
         return finished
 
     def serve(self, requests: Sequence[Request], *,
@@ -1270,12 +1144,11 @@ class InferenceEngine:
         self._prefilling.clear()
         self._parked.clear()
         self.slots.reset()
-        if self.pages is not None:
-            # the page free list resets WITH the slot pool — a rebuild
-            # that reused this registry must start from a full pool
-            self.pages.reset()
-            self._reserved_pages = 0
-            self._page_table_h[:] = self.pages.n_pages
+        # the page free list resets WITH the slot pool — a rebuild
+        # that reused this registry must start from a full pool
+        self.pages.reset()
+        self._reserved_pages = 0
+        self._page_table_h[:] = self.pages.n_pages
         self.metrics.flush()
 
     def __enter__(self) -> "InferenceEngine":
@@ -1366,8 +1239,8 @@ class InferenceEngine:
             matched * ps == request.prompt_len
 
     def _make_page_predicate(self):
-        """Pages-aware admission predicate (None under the flat layout):
-        a request enters only when its WORST-CASE page need (total_len,
+        """Pages-aware admission predicate: a request enters only when
+        its WORST-CASE page need (total_len,
         minus the shared-prefix pages a cache hit maps refcounted) fits
         alongside every other admitted request's outstanding reservation
         — so decode-time on-demand extends can never exhaust the pool.
@@ -1380,8 +1253,6 @@ class InferenceEngine:
         head-blocking). The ``planned`` tallies accumulate across the
         pops of ONE call — chunked admission builds a fresh predicate
         per single-head pop because it maps pages between pops."""
-        if self.pages is None:
-            return None
         planned = 0          # private pages promised this tick
         planned_shared = 0   # reclaimable pages pinned this tick
 
@@ -1423,9 +1294,8 @@ class InferenceEngine:
             return
         head_rank = PRIORITY_RANK[head[0].sampling.priority]
         blocked = self.slots.free_count == 0
-        if not blocked and self.pages is not None:
-            pred = self._make_page_predicate()
-            blocked = pred(head[0]) == "defer"
+        if not blocked:
+            blocked = self._make_page_predicate()(head[0]) == "defer"
         if not blocked:
             return
         victim, victim_key = None, None
@@ -1454,10 +1324,7 @@ class InferenceEngine:
         slot = rec.slot
         del self._active[slot]
         self.slots.release(slot)
-        if self.pages is not None:
-            self.pages.release_slot(slot)
-            self._reserved_pages -= rec.reserved_pages
-            self._page_table_h[slot, :] = self.pages.n_pages
+        self._release_pages(rec)
         self._clear_slot(slot)
         self._parked.append((rec.request, list(rec.tokens), rec.submit_ts))
         self.metrics.inc("requests_preempted")
@@ -1589,53 +1456,49 @@ class InferenceEngine:
                 bank = self._bank
                 topk = jnp.int32(sp.top_k if sp.top_k is not None
                                  else self._vocab)
-            chain, shared_pages, skip_first = (), [], False
-            shared_used = 0
-            if self.pages is not None:
-                with span(TICK_SCHEDULE) as sched:
-                    # re-match the prefix NOW (the predicate's match may have
-                    # been reshaped by a later head's intern eviction), commit
-                    # the worst-case reservation minus the shared pages, then
-                    # physically map only the prompt's pages (decode extends
-                    # on demand)
-                    chain, shared_pages, skip_first = \
-                        self._plan_prefix(request)
-                    shared_used = len(shared_pages)
-                    need = (self.pages.pages_for(request.total_len)
-                            - shared_used)
-                    mapped = self.pages.map_slot(slot, request.prompt_len,
-                                                 shared=shared_pages or None)
-                    if mapped is None:
-                        self.slots.release(slot)
-                        if self.config.prefix_cache:
-                            # an intern eviction between the admission
-                            # predicate and this map changed what's
-                            # reclaimable — FCFS honest, the request retries
-                            # from the FRONT of the queue on a later tick
-                            # (co-tenant retirements will unpin pages)
-                            self.scheduler.requeue_front(request, submit_ts)
-                            return
-                        raise RuntimeError(
-                            f"page pool exhausted at prefill despite "
-                            f"admission reservation (slot {slot}, "
-                            f"free={self.pages.free_count}) — reservation "
-                            f"accounting is broken")
-                    rec.reserved_pages = need
-                    self._reserved_pages += need
-                    row = self._page_table_h[slot]
-                    row[:] = self.pages.n_pages
-                    row[:len(mapped)] = mapped
-                    # freshly mapped PRIVATE pages may be recycled (e.g. from
-                    # a pressure-evicted intern run) with stale scales; zero
-                    # them so the rescale-on-append floor starts clean. Shared
-                    # pages keep their scales — that's their dequant key.
-                    self._reset_fresh_scales(mapped[shared_used:])
-                    sched.set_metadata(pages_mapped=len(mapped))
+            with span(TICK_SCHEDULE) as sched:
+                # re-match the prefix NOW (the predicate's match may have
+                # been reshaped by a later head's intern eviction), commit
+                # the worst-case reservation minus the shared pages, then
+                # physically map only the prompt's pages (decode extends
+                # on demand)
+                chain, shared_pages, skip_first = \
+                    self._plan_prefix(request)
+                shared_used = len(shared_pages)
+                need = (self.pages.pages_for(request.total_len)
+                        - shared_used)
+                mapped = self.pages.map_slot(slot, request.prompt_len,
+                                             shared=shared_pages or None)
+                if mapped is None:
+                    self.slots.release(slot)
+                    if self.config.prefix_cache:
+                        # an intern eviction between the admission
+                        # predicate and this map changed what's
+                        # reclaimable — FCFS honest, the request retries
+                        # from the FRONT of the queue on a later tick
+                        # (co-tenant retirements will unpin pages)
+                        self.scheduler.requeue_front(request, submit_ts)
+                        return
+                    raise RuntimeError(
+                        f"page pool exhausted at prefill despite "
+                        f"admission reservation (slot {slot}, "
+                        f"free={self.pages.free_count}) — reservation "
+                        f"accounting is broken")
+                rec.reserved_pages = need
+                self._reserved_pages += need
+                row = self._page_table_h[slot]
+                row[:] = self.pages.n_pages
+                row[:len(mapped)] = mapped
+                # freshly mapped PRIVATE pages may be recycled (e.g. from
+                # a pressure-evicted intern run) with stale scales; zero
+                # them so the rescale-on-append floor starts clean. Shared
+                # pages keep their scales — that's their dequant key.
+                self._reset_fresh_scales(mapped[shared_used:])
+                sched.set_metadata(pages_mapped=len(mapped))
             try:
                 if self._faults is not None:
                     self._faults.before_prefill()
-                finite = True
-                if self.pages is not None and shared_used:
+                if shared_used:
                     # prefix-cache hit: prefill ONLY the suffix (bucketed
                     # like a full prefill). start is the first token NOT
                     # covered by shared pages — or, fully covered, the
@@ -1663,7 +1526,7 @@ class InferenceEngine:
                     with span(TICK_DISPATCH, program="suffix_prefill",
                               rows=bucket):
                         first, finite, self._caches = self._suffix_fn(*args)
-                elif self.pages is not None:
+                else:
                     with span(TICK_UPLOAD) as up:
                         bucket = bucket_for(request.prompt_len,
                                             self.config.max_len)
@@ -1681,20 +1544,6 @@ class InferenceEngine:
                     with span(TICK_DISPATCH, program="paged_prefill",
                               rows=bucket):
                         first, finite, self._caches = self._prefill_fn(*args)
-                else:
-                    with span(TICK_UPLOAD) as up:
-                        bucket = bucket_for(request.prompt_len,
-                                            self.config.max_len)
-                        padded = np.zeros((1, bucket), np.int32)
-                        padded[0, :request.prompt_len] = request.prompt
-                        args = (
-                            self._params, self._caches, jnp.asarray(padded),
-                            jnp.int32(slot), jnp.int32(request.prompt_len),
-                            jnp.float32(sp.temperature), topk,
-                            jnp.int32(sp.seed), aix, bank)
-                        up.set_metadata(arrays=5, bytes=padded.nbytes)
-                    with span(TICK_DISPATCH, program="prefill", rows=bucket):
-                        first, self._caches = self._prefill_fn(*args)
                 del args
                 group.set_metadata(bucket=bucket)
                 with span(TICK_READBACK, reads=1, bytes=4):
@@ -1704,13 +1553,10 @@ class InferenceEngine:
                 # the slot never held committed state (nothing scattered, or
                 # the scatter's result was discarded with the raised call)
                 self.slots.release(slot)
-                if self.pages is not None:
-                    self.pages.release_slot(slot)
-                    self._reserved_pages -= rec.reserved_pages
-                    self._page_table_h[slot, :] = self.pages.n_pages
+                self._release_pages(rec)
                 raise
             with span(TICK_COMMIT, tokens=1) as commit:
-                if self.pages is not None and self.config.prefix_cache:
+                if self.config.prefix_cache:
                     if shared_used:
                         self.metrics.inc("prefix_hits")
                         self.metrics.inc("prefix_pages_shared", shared_used)
@@ -1758,44 +1604,33 @@ class InferenceEngine:
         rec.prefill_start = clock.now()
         rec.adapter_ix = self._adapter_index(request.sampling.adapter_id,
                                              strict=False)
-        if self.pages is not None:
-            chain, shared_pages, skip_first = self._plan_prefix(request)
-            shared_used = len(shared_pages)
-            need = self.pages.pages_for(request.total_len) - shared_used
-            mapped = self.pages.map_slot(slot, request.prompt_len,
-                                         shared=shared_pages or None)
-            if mapped is None:
-                self.slots.release(slot)
-                if self.config.prefix_cache:
-                    self.scheduler.requeue_front(request, submit_ts)
-                    return None
-                raise RuntimeError(
-                    f"page pool exhausted at prefill despite admission "
-                    f"reservation (slot {slot}, "
-                    f"free={self.pages.free_count}) — reservation "
-                    f"accounting is broken")
-            rec.reserved_pages = need
-            self._reserved_pages += need
-            row = np.full(self.config.pages_per_slot, self.pages.n_pages,
-                          np.int32)
-            row[:len(mapped)] = mapped
-            rec.page_row = row
-            rec.chain = chain
-            rec.shared_used = shared_used
-            rec.skip_first = skip_first
-            # shared prefix rows are already resident: chunking starts
-            # at the first uncovered token (page-aligned), or — fully
-            # covered — at the last-token recompute (the COW seam)
-            rec.prefill_pos = (request.prompt_len - 1 if skip_first
-                               else shared_used * self.config.page_size)
-            self._reset_fresh_scales(mapped[shared_used:])
-        else:
-            # park the position at the last row: the flat decode step
-            # appends unconditionally at _positions_h[slot], and row
-            # max_len-1 is never live (a request's final sampled token
-            # is never fed back), so co-tenant decode garbage cannot
-            # clobber already-prefilled chunk rows
-            self._positions_h[slot] = self.config.max_len - 1
+        chain, shared_pages, skip_first = self._plan_prefix(request)
+        shared_used = len(shared_pages)
+        need = self.pages.pages_for(request.total_len) - shared_used
+        mapped = self.pages.map_slot(slot, request.prompt_len,
+                                     shared=shared_pages or None)
+        if mapped is None:
+            self.slots.release(slot)
+            if self.config.prefix_cache:
+                self.scheduler.requeue_front(request, submit_ts)
+                return None
+            raise RuntimeError(
+                f"page pool exhausted at prefill despite admission "
+                f"reservation (slot {slot}, "
+                f"free={self.pages.free_count}) — reservation "
+                f"accounting is broken")
+        rec.reserved_pages = need
+        self._reserved_pages += need
+        rec.page_row = self._page_row(mapped)
+        rec.chain = chain
+        rec.shared_used = shared_used
+        rec.skip_first = skip_first
+        # shared prefix rows are already resident: chunking starts
+        # at the first uncovered token (page-aligned), or — fully
+        # covered — at the last-token recompute (the COW seam)
+        rec.prefill_pos = (request.prompt_len - 1 if skip_first
+                           else shared_used * self.config.page_size)
+        self._reset_fresh_scales(mapped[shared_used:])
         self._prefilling[slot] = rec
         self.admission_log.append(request.request_id)
         return rec
@@ -1804,9 +1639,9 @@ class InferenceEngine:
                    finished: List[RequestResult]) -> int:
         """Run ONE maximal prefill chunk for ``rec`` within
         ``budget_left`` tokens; returns the tokens consumed (0 = no
-        progress possible this tick). Paged chunks reuse the suffix
-        program (the slot's pages ARE the carried state); flat chunks
-        run the dedicated chunk body. The final chunk's sample — keyed
+        progress possible this tick). Chunks reuse the suffix program
+        (the slot's pages ARE the carried state). The final chunk's
+        sample — keyed
         at step ``prompt_len`` from the prompt's last-token logits —
         is the request's first token, bitwise what the monolithic
         prefill emits; intermediate chunks' samples are discarded."""
@@ -1817,7 +1652,7 @@ class InferenceEngine:
             with span(TICK_SCHEDULE):
                 remaining = request.prompt_len - rec.prefill_pos
                 chunk_len = min(remaining, budget_left)
-                if chunk_len < remaining and self.pages is not None:
+                if chunk_len < remaining:
                     # internal chunk boundaries stay page-aligned: every
                     # fresh page is then written whole in ONE scatter onto a
                     # zeroed scale, so int8 page contents (and the interned
@@ -1841,34 +1676,21 @@ class InferenceEngine:
             try:
                 if self._faults is not None:
                     self._faults.before_prefill()
-                if self.pages is not None:
-                    with span(TICK_UPLOAD, arrays=8, bytes=chunk.nbytes
-                              + rec.page_row.nbytes):
-                        args = (
-                            self._params, self._caches,
-                            jnp.asarray(rec.page_row), jnp.asarray(chunk),
-                            jnp.int32(start), jnp.int32(chunk_len),
-                            jnp.int32(request.prompt_len),
-                            jnp.float32(sp.temperature), topk,
-                            jnp.int32(sp.seed),
-                            jnp.bool_(rec.skip_first
-                                      and rec.prefill_chunks == 0),
-                            aix, self._bank)
-                    with span(TICK_DISPATCH, program="suffix_prefill",
-                              rows=bucket):
-                        first, finite, self._caches = self._suffix_fn(*args)
-                else:
-                    with span(TICK_UPLOAD, arrays=8, bytes=chunk.nbytes):
-                        args = (
-                            self._params, self._caches, jnp.int32(rec.slot),
-                            jnp.asarray(chunk), jnp.int32(start),
-                            jnp.int32(chunk_len),
-                            jnp.int32(request.prompt_len),
-                            jnp.float32(sp.temperature), topk,
-                            jnp.int32(sp.seed), aix, self._bank)
-                    with span(TICK_DISPATCH, program="flat_chunk",
-                              rows=bucket):
-                        first, finite, self._caches = self._chunk_fn(*args)
+                with span(TICK_UPLOAD, arrays=8, bytes=chunk.nbytes
+                          + rec.page_row.nbytes):
+                    args = (
+                        self._params, self._caches,
+                        jnp.asarray(rec.page_row), jnp.asarray(chunk),
+                        jnp.int32(start), jnp.int32(chunk_len),
+                        jnp.int32(request.prompt_len),
+                        jnp.float32(sp.temperature), topk,
+                        jnp.int32(sp.seed),
+                        jnp.bool_(rec.skip_first
+                                  and rec.prefill_chunks == 0),
+                        aix, self._bank)
+                with span(TICK_DISPATCH, program="suffix_prefill",
+                          rows=bucket):
+                    first, finite, self._caches = self._suffix_fn(*args)
                 del args
                 with span(TICK_READBACK, reads=2, bytes=5):
                     rec.finite_ok = rec.finite_ok and bool(np.asarray(finite))
@@ -1880,10 +1702,7 @@ class InferenceEngine:
                 # the request from its prompt through the same admit path
                 del self._prefilling[rec.slot]
                 self.slots.release(rec.slot)
-                if self.pages is not None:
-                    self.pages.release_slot(rec.slot)
-                    self._reserved_pages -= rec.reserved_pages
-                    self._page_table_h[rec.slot, :] = self.pages.n_pages
+                self._release_pages(rec)
                 self._clear_slot(rec.slot)
                 raise
             with span(TICK_COMMIT):
@@ -1906,22 +1725,21 @@ class InferenceEngine:
         request = rec.request
         slot = rec.slot
         del self._prefilling[slot]
-        if self.pages is not None:
-            self._page_table_h[slot] = rec.page_row
-            if self.config.prefix_cache:
-                # hit/miss accounting lands at COMPLETION so hits +
-                # misses stays == prefills even when a mid-prefill
-                # request times out or is cancelled
-                if rec.shared_used:
-                    self.metrics.inc("prefix_hits")
-                    self.metrics.inc("prefix_pages_shared",
-                                     rec.shared_used)
-                else:
-                    self.metrics.inc("prefix_misses")
-                if rec.chain and rec.finite_ok:
-                    self.pages.intern_prefix(
-                        rec.chain,
-                        [int(p) for p in rec.page_row[:len(rec.chain)]])
+        self._page_table_h[slot] = rec.page_row
+        if self.config.prefix_cache:
+            # hit/miss accounting lands at COMPLETION so hits +
+            # misses stays == prefills even when a mid-prefill
+            # request times out or is cancelled
+            if rec.shared_used:
+                self.metrics.inc("prefix_hits")
+                self.metrics.inc("prefix_pages_shared",
+                                 rec.shared_used)
+            else:
+                self.metrics.inc("prefix_misses")
+            if rec.chain and rec.finite_ok:
+                self.pages.intern_prefix(
+                    rec.chain,
+                    [int(p) for p in rec.page_row[:len(rec.chain)]])
         rec.prefill_end = clock.now()
         rec.tokens.append(first)
         rec.last_token = first
@@ -1946,17 +1764,7 @@ class InferenceEngine:
         exactly like bucket-padding rows."""
         del self._prefilling[rec.slot]
         self.slots.release(rec.slot)
-        if self.pages is not None:
-            freed = self.pages.release_slot(rec.slot)
-            self._reserved_pages -= rec.reserved_pages
-            self._page_table_h[rec.slot, :] = self.pages.n_pages
-            if not rec.finite_ok and freed:
-                row = np.full(self.config.pages_per_slot,
-                              self.pages.n_pages, np.int32)
-                row[:len(freed)] = freed
-                self._caches = self._scrub_fn(self._caches,
-                                              jnp.asarray(row))
-                self.pages.note_scrubbed(freed)
+        self._release_pages(rec, scrub=not rec.finite_ok)
         self._clear_slot(rec.slot)
         return self._finish(
             rec.request, [], reason, submit_ts=rec.submit_ts, now=now,
@@ -1964,17 +1772,38 @@ class InferenceEngine:
             prefill_segments=tuple(rec.chunk_marks),
             prefill_chunks=rec.prefill_chunks or None)
 
+    def _page_row(self, pages) -> np.ndarray:
+        """``pages`` as one fixed-width page-table row, sentinel-padded:
+        the shape every per-slot program takes, so none adds a compile
+        shape."""
+        row = np.full(self.config.pages_per_slot, self.pages.n_pages,
+                      np.int32)
+        row[:len(pages)] = pages
+        return row
+
+    def _release_pages(self, rec: _Active, *, scrub: bool = False) -> None:
+        """Give back ``rec``'s slot's pages, its reservation and its
+        table row, together. Only the pages whose LAST reference this
+        drop removed are freed — shared prefix pages outlive the slot —
+        and with ``scrub`` exactly those are zeroed."""
+        freed = self.pages.release_slot(rec.slot)
+        self._reserved_pages -= rec.reserved_pages
+        self._page_table_h[rec.slot, :] = self.pages.n_pages
+        if scrub and freed:
+            self._caches = self._scrub_fn(
+                self._caches, jnp.asarray(self._page_row(freed)))
+            # PagePool.check() can now assert these free pages hold
+            # zero scales until their next allocation
+            self.pages.note_scrubbed(freed)
+
     def _reset_fresh_scales(self, pages) -> None:
         """Zero the scale sidecar for freshly allocated ``pages``
         (quantized pools only) — one fixed-width sentinel-padded row
         through a dedicated program, so it never adds a compile shape."""
         if not self._quantized or len(pages) == 0:
             return
-        row = np.full(self.config.pages_per_slot, self.pages.n_pages,
-                      np.int32)
-        row[:len(pages)] = pages
-        self._caches = self._reset_scales_fn(self._caches,
-                                             jnp.asarray(row))
+        self._caches = self._reset_scales_fn(
+            self._caches, jnp.asarray(self._page_row(pages)))
 
     def _build_windows(self) -> None:
         """Fill the per-slot verify windows for the next speculative
@@ -2012,10 +1841,9 @@ class InferenceEngine:
         decode kernel of ONE full-attention layer makes this step, the
         width of every active slot's page range summed (a window layer
         copies no more) — how much the kernel's page walk is asked to
-        do, beside ``rows``. Nothing on the flat layout, and nothing
-        unless a trace is being taken: no tick pays for the sum
-        otherwise."""
-        if self.pages is None or not recording():
+        do, beside ``rows``. Nothing unless a trace is being taken: no
+        tick pays for the sum otherwise."""
+        if not recording():
             return {}
         live = np.fromiter(self._active, np.intp, len(self._active))
         first, stop = paged_page_range(
@@ -2025,13 +1853,12 @@ class InferenceEngine:
 
     def _decode_args(self) -> tuple:
         """The decode program's arguments from the current host arrays
-        (paged: the page table rides right after the pool; with
-        speculation the fed tokens are the ``[n, k]`` window matrix)."""
+        (the page table rides right after the pool; with speculation
+        the fed tokens are the ``[n, k]`` window matrix)."""
         fed = (jnp.asarray(self._window_h) if self._spec
                else jnp.asarray(self._tokens_h))
-        table = (() if self.pages is None
-                 else (jnp.asarray(self._page_table_h),))
-        return (self._params, self._caches, *table, fed,
+        return (self._params, self._caches,
+                jnp.asarray(self._page_table_h), fed,
                 jnp.asarray(self._positions_h), jnp.asarray(self._temps_h),
                 jnp.asarray(self._topks_h), jnp.asarray(self._seeds_h),
                 jnp.asarray(self._adapter_ix_h), self._bank)
@@ -2049,22 +1876,20 @@ class InferenceEngine:
         with span(TICK_SCHEDULE, active=len(self._active)) as sched:
             if self._spec and self._active:
                 self._build_windows()
-            if self.pages is not None:
-                self._extend_pages(finished)
+            self._extend_pages(finished)
             if not self._active:
                 return
             if self._faults is not None:
                 self._faults.before_decode()
-            if self.pages is not None:
-                # roofline gauge: bytes of KV stream one decode step
-                # reads (mapped pages of every active slot, dtype- and
-                # sidecar-aware) — THE denominator speculation and int8
-                # shrink
-                mapped = sum(len(self.pages.slot_pages(s))
-                             for s in self._active)
-                self.metrics.set_gauge("kv_bytes_per_step",
-                                       mapped * self._page_read_bytes)
-                sched.set_metadata(pages_mapped=mapped)
+            # roofline gauge: bytes of KV stream one decode step
+            # reads (mapped pages of every active slot, dtype- and
+            # sidecar-aware) — THE denominator speculation and int8
+            # shrink
+            mapped = sum(len(self.pages.slot_pages(s))
+                         for s in self._active)
+            self.metrics.set_gauge("kv_bytes_per_step",
+                                   mapped * self._page_read_bytes)
+            sched.set_metadata(pages_mapped=mapped)
         with span(TICK_UPLOAD, arrays=self._decode_upload[0],
                   bytes=self._decode_upload[1]):
             args = self._decode_args()
@@ -2215,14 +2040,14 @@ class InferenceEngine:
 
     def _quarantine(self, rec: _Active, cause: str,
                     now: float) -> RequestResult:
-        """Retire ONE poisoned slot and keep the batch serving: scrub the
-        row's KV (NaNs must not outlive the occupant — a masked attention
+        """Retire ONE poisoned slot and keep the batch serving: scrub its
+        KV (NaNs must not outlive the occupant — a masked attention
         weight times a NaN value is still NaN), release the slot, and
         finish the request with ``finish_reason="error"`` — co-tenants
         are untouched and the decode program never retraces.
 
-        Under the paged layout only the pages this release actually
-        FREES are scrubbed (``_retire(scrub=True)``): shared prefix
+        Only the pages this release actually FREES are scrubbed
+        (``_retire(scrub=True)``): shared prefix
         pages still referenced by co-tenant slots or the intern index
         hold exclusively pre-intern prefill data (interned pages are
         never written again — decode appends land past the prompt's full
@@ -2230,8 +2055,6 @@ class InferenceEngine:
         are clean by construction and co-tenants keep token-exact
         streams; they are zeroed when their LAST reference drops."""
         slot = rec.slot
-        if self.pages is None:
-            self._caches = self._scrub_fn(self._caches, jnp.int32(slot))
         self.metrics.inc("slots_quarantined")
         log_event(_LOG, "slot_quarantined", slot=slot,
                   request_id=rec.request.request_id, cause=cause)
@@ -2279,23 +2102,7 @@ class InferenceEngine:
                 scrub: bool = False) -> RequestResult:
         del self._active[rec.slot]
         self.slots.release(rec.slot)
-        if self.pages is not None:
-            # release returns only the pages whose LAST reference this
-            # drop removed — shared prefix pages outlive the slot
-            freed = self.pages.release_slot(rec.slot)
-            self._reserved_pages -= rec.reserved_pages
-            self._page_table_h[rec.slot, :] = self.pages.n_pages
-            if scrub and freed:
-                # fixed-width row (sentinel-padded) through the same
-                # scrub program — no new compile shapes
-                row = np.full(self.config.pages_per_slot,
-                              self.pages.n_pages, np.int32)
-                row[:len(freed)] = freed
-                self._caches = self._scrub_fn(self._caches,
-                                              jnp.asarray(row))
-                # PagePool.check() can now assert these free pages hold
-                # zero scales until their next allocation
-                self.pages.note_scrubbed(freed)
+        self._release_pages(rec, scrub=scrub)
         self._clear_slot(rec.slot)
         if rec.spec_proposed:
             # mark span over the decode stretch the verify windows rode:

@@ -9,7 +9,7 @@ breaker removes a replica from the dispatch set;
 restarts that migrate in-flight work token-exact to peers so a rebuild
 never drops capacity below N−1. :class:`ShardedEngine` is the
 scale-up counterpart: the same engine with its decode/prefill programs
-tensor-parallel over the device mesh and the flat KV slot pool sharded
+tensor-parallel over the device mesh and the KV page pools sharded
 on the heads axis. See docs/serving.md#fleet.
 
 On top of the fleet sit the two halves of the train->serve loop

@@ -366,10 +366,9 @@ class ReplicaFleet:
             affinity_weight=self.fleet.prefix_affinity_weight)
         self._engine_factory = engine_factory
         # affinity chains only mean something when replicas actually
-        # intern prefixes — flat layout / prefix_cache=False fleets
-        # route purely least-loaded (chain stays None)
-        self._route_chains = (self.config.kv_layout == "paged"
-                              and self.config.prefix_cache
+        # intern prefixes — prefix_cache=False fleets route purely
+        # least-loaded (chain stays None)
+        self._route_chains = (self.config.prefix_cache
                               and self.router.affinity_weight > 0.0)
         self._route_salt = prefix_salt(model.config)
         if faults is None:
@@ -640,11 +639,8 @@ deploy.Deployment`, or None if :meth:`deploy` was never called."""
 
     def _quota_pages(self, request: Request) -> int:
         """Worst-case KV page footprint the engine's admission will
-        reserve (0 on non-paged layouts — the page cap is then inert)."""
-        if self.config.kv_layout != "paged":
-            return 0
-        ps = self.config.page_size
-        return -(-request.total_len // ps)
+        reserve."""
+        return -(-request.total_len // self.config.page_size)
 
     def _quota_release(self, request_id: int) -> None:
         """Return a terminal request's quota holdings (idempotent)."""

@@ -3,21 +3,25 @@
 One :class:`~apex_tpu.serving.InferenceEngine` serves from one chip's
 HBM; a model too large (or a batch too hungry) for one chip needs the
 decode step itself spread over the mesh. :class:`ShardedEngine` is the
-same engine — same slot pool, same scheduler, same quarantine and
-telemetry, same host-side arrays — with its three device programs
-(decode / bucketed prefill / quarantine scrub) wrapped in ``shard_map``
-over the ``tensor`` mesh axis (via the :mod:`apex_tpu.utils.sharding`
+same engine — same slot and page pools, same scheduler, same
+quarantine and telemetry, same host-side arrays, page table included —
+with its five device programs (decode / bucketed prefill / suffix
+prefill, which is also the chunk program / quarantine scrub / scale
+reset) wrapped in ``shard_map`` over the ``tensor`` mesh axis (via the
+:mod:`apex_tpu.utils.sharding`
 shims), reusing the :mod:`apex_tpu.transformer` TP layers the multichip
 training dryruns already hold parity with:
 
 - **Parameters** shard by the model's own partition spec
   (``model.spec()``): column/row-parallel QKV and MLP blocks, the
   vocab-sharded embedding doubling as the LM head.
-- **The flat KV slot pool shards on the heads axis**: each rank owns
-  the ``[max_slots, max_len, local_kv_heads * head_dim]`` slice whose
-  head block its QKV projection computes, so prefill's scatter and
-  decode's one-row append stay rank-local — no KV traffic crosses the
-  mesh, exactly like the training-side cache layout under TP.
+- **The KV page pools shard on the heads axis**: each rank owns the
+  ``[n_pages, page_size, local_kv_heads * head_dim]`` slice whose head
+  block its QKV projection computes (and, for int8 pools, that block's
+  scales), so prefill's page scatter and the fused append+attend of
+  decode stay rank-local — no KV traffic crosses the mesh, exactly
+  like the training-side cache layout under TP. The page table is
+  replicated: the mapping is the same on every rank.
 - **Logits are gathered to full vocab inside the step** (the same
   ``all_gather`` the generation path uses), so sampling and the
   per-slot integrity flags run replicated and every rank agrees on the
@@ -96,18 +100,17 @@ class ShardedEngine(InferenceEngine):
         return spec
 
     def _cache_spec(self):
-        """Both KV pool layouts — flat ``[max_slots, max_len, kv_heads *
-        head_dim]`` rows and the paged ``[n_pages, page_size, kv_heads *
-        head_dim]`` pool — shard the same fused heads*head_dim minor dim
-        over the tensor axis: each rank's contiguous block is exactly
-        the head slice its QKV projection produces (page tables stay
-        host-side/replicated; the mapping is identical on every rank).
+        """The ``[n_pages, page_size, kv_heads * head_dim]`` pools shard
+        their fused heads*head_dim minor dim over the tensor axis: each
+        rank's contiguous block is exactly the head slice its QKV
+        projection produces (page tables stay host-side/replicated; the
+        mapping is identical on every rank).
         Quantized pools nest the per-page scale sidecar ``[n_pages,
         kv_heads]`` alongside each int8 pool, sharded on ITS heads dim —
         every rank holds exactly the scales of the head block it owns,
         so quantize/rescale/dequant stay rank-local too."""
         axis = self.model.config.axis_name
-        if getattr(self, "_quantized", False):
+        if self._quantized:
             half = (P(None, None, axis), P(None, axis))
             pair = (half, half)
         else:
@@ -133,85 +136,54 @@ class ShardedEngine(InferenceEngine):
 
     def _build_step_fns(self, donate: bool):
         """The base engine's step bodies, ``shard_map``-wrapped over the
-        mesh: params by ``model.spec()``, KV pool on the heads axis,
-        tokens/positions/sampling params — and, under ``kv_layout=
-        "paged"``, the page table — replicated. The bodies themselves
-        are INHERITED — this class changes where the math runs, not what
-        it computes."""
+        mesh: params by ``model.spec()``, KV pools on the heads axis,
+        the page table (or the slot's table row), tokens, positions and
+        sampling params replicated. The bodies themselves are INHERITED
+        — this class changes where the math runs, not what it
+        computes."""
         mesh = self.mesh
         pspec = self._param_spec()
         cspec = self._cache_spec()
         rep = P()
         lspec = self._lora_spec()
-        reset = None
-        if self.pages is not None:
-            # paged bodies take one extra replicated arg (the page
-            # table / the slot's table row) right after the pool. The
-            # speculative verify body has the SAME arity — the [n]
-            # token vector becomes the [n, k] window matrix, still
-            # replicated — so the spec structure is unchanged.
-            decode_body = (self._spec_decode_body if self._spec
-                           else self._paged_decode_body)
-            decode = shard_map(
-                decode_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          rep, lspec),
-                out_specs=(rep, rep, cspec))
-            prefill = shard_map(
-                self._paged_prefill_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          rep, lspec),
-                out_specs=(rep, rep, cspec))
-            # suffix prefill (prefix-cache hit): the gather/scatter of
-            # shared pages is rank-local on each rank's head slice, so
-            # sharding follows the pool spec; everything scalar — start,
-            # lengths, sampling, the skip_first flag — replicates
-            suffix = shard_map(
-                self._suffix_prefill_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          rep, rep, rep, rep, lspec),
-                out_specs=(rep, rep, cspec))
-            # chunked prefill (docs/serving.md#chunked-prefill) rides
-            # the suffix program on the paged layout — the chunk offset
-            # is a traced scalar, so no extra sharded wiring exists
-            chunk = None
-            scrub = shard_map(
-                self._paged_scrub_body, mesh=mesh,
-                in_specs=(cspec, rep), out_specs=cspec)
-            if self._quantized:
-                reset = shard_map(
-                    self._reset_scales_body, mesh=mesh,
-                    in_specs=(cspec, rep), out_specs=cspec)
-        else:
-            decode = shard_map(
-                self._decode_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          lspec),
-                out_specs=(rep, rep, cspec))
-            prefill = shard_map(
-                self._prefill_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          rep, lspec),
-                out_specs=(rep, cspec))
-            suffix = None
-            # the flat chunk program scatters a bucketed K/V slice into
-            # each rank's own head block of the slot row — rank-local,
-            # same spec shape as flat prefill plus the start offset
-            chunk = shard_map(
-                self._flat_chunk_body, mesh=mesh,
-                in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                          rep, rep, rep, lspec),
-                out_specs=(rep, rep, cspec))
-            scrub = shard_map(
-                self._scrub_body, mesh=mesh,
-                in_specs=(cspec, rep), out_specs=cspec)
+        # the speculative verify body has the SAME arity as the plain
+        # one — the [n] token vector becomes the [n, k] window matrix,
+        # still replicated — so the spec structure is unchanged
+        decode_body = (self._spec_decode_body if self._spec
+                       else self._paged_decode_body)
+        decode = shard_map(
+            decode_body, mesh=mesh,
+            in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
+                      rep, lspec),
+            out_specs=(rep, rep, cspec))
+        prefill = shard_map(
+            self._paged_prefill_body, mesh=mesh,
+            in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
+                      rep, lspec),
+            out_specs=(rep, rep, cspec))
+        # suffix prefill (prefix-cache hit, and every chunk of a chunked
+        # prefill): the gather/scatter of the slot's pages is rank-local
+        # on each rank's head slice, so sharding follows the pool spec;
+        # everything scalar — start, lengths, sampling, the skip_first
+        # flag — replicates
+        suffix = shard_map(
+            self._suffix_prefill_body, mesh=mesh,
+            in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
+                      rep, rep, rep, rep, lspec),
+            out_specs=(rep, rep, cspec))
+        scrub = shard_map(
+            self._paged_scrub_body, mesh=mesh,
+            in_specs=(cspec, rep), out_specs=cspec)
         donate_args = (1,) if donate else ()
+        donate_pool = (0,) if donate else ()
+        reset = None
+        if self._quantized:
+            reset = jax.jit(
+                shard_map(self._reset_scales_body, mesh=mesh,
+                          in_specs=(cspec, rep), out_specs=cspec),
+                donate_argnums=donate_pool)
         return (jax.jit(decode, donate_argnums=donate_args),
                 jax.jit(prefill, donate_argnums=donate_args),
-                None if suffix is None else
                 jax.jit(suffix, donate_argnums=donate_args),
-                None if chunk is None else
-                jax.jit(chunk, donate_argnums=donate_args),
-                jax.jit(scrub, donate_argnums=(0,) if donate else ()),
-                None if reset is None else
-                jax.jit(reset, donate_argnums=(0,) if donate else ()))
+                jax.jit(scrub, donate_argnums=donate_pool),
+                reset)

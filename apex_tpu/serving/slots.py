@@ -8,8 +8,8 @@ the LOWEST free slot id so runs are deterministic (the same arrival
 order always produces the same slot assignment, and therefore the same
 decode batch layout).
 
-Under the paged KV layout (``kv_layout="paged"``, docs/serving.md), a
-slot row no longer reserves ``max_len`` cache memory; instead each slot
+A slot reserves no ``max_len`` row of cache memory
+(docs/serving.md#paged-kv); instead each slot
 maps a variable number of fixed-size pages out of a shared
 :class:`PagePool`. Pages are REFCOUNTED: a page may back the shared
 prompt prefix of many slots at once (docs/serving.md#prefix-cache), so

@@ -1,8 +1,7 @@
 """Persistent XLA compile cache with a placeable, fixed location.
 
 Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, the ``benchmarks/*.py`` mains, ``python -m
-apex_tpu.loadtest``) calls :func:`enable_compile_cache` first. The
+``python -m cellbench.run``, ``python -m apex_tpu.loadtest``) calls :func:`enable_compile_cache` first. The
 directory is part of the cache key's world — a cache that moves never
 hits — so it is either the one the environment names or ONE fixed path
 inside the checkout, never a temporary directory.

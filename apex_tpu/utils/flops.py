@@ -1,9 +1,8 @@
 """FLOP accounting: chip peak FLOP/s table + model-FLOP estimators.
 
-One source of truth for MFU math, shared by the library's observability
+One source of truth for the MFU math of the library's observability
 layer (:mod:`apex_tpu.observability` — per-step MFU against the chip's
-bf16 peak) and the benchmark harness (``benchmarks/_harness.py``), which
-previously each would have had to carry their own copy of the peak table.
+bf16 peak).
 MFU here is *model*-FLOPs utilization (PaLM-style: the FLOPs the math
 requires, not the FLOPs the compiler executes), so numbers are comparable
 across implementations.

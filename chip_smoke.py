@@ -3,7 +3,7 @@
     python chip_smoke.py          # from the checkout root, ON THE CHIP
 
 One process (it holds the chip; nothing is forked), one model at full
-width — GPT-2 124M exactly as ``bench.py`` builds it — through the entry
+width — GPT-2 124M: 12 layers, hidden 768, 12 heads — through the entry
 points a user calls, in this order:
 
 1. device gate: no TPU => non-zero exit before anything is built;
@@ -102,8 +102,8 @@ def _require(ok: bool, what: str) -> None:
 
 
 def gpt2_124m():
-    """``bench.py``'s GPT-2 124M config (12 L, hidden 768, 12 x 64 heads,
-    vocab 50304, bf16 compute), no dropout."""
+    """GPT-2 124M (12 L, hidden 768, 12 x 64 heads, vocab 50304 = 50257
+    padded to a multiple of 128, bf16 compute), no dropout."""
     import jax.numpy as jnp
 
     from apex_tpu.models import TransformerConfig

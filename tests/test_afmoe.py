@@ -242,11 +242,25 @@ def _lowered_programs():
 
     m = GPTModel(TransformerConfig(**{**base, "compute_dtype": jnp.bfloat16}))
     params = m.init(key)
-    for layout in ("paged", "flat"):
-        eng = InferenceEngine(m, params, EngineConfig(
-            max_slots=4, max_len=32, page_size=8, kv_layout=layout))
-        out[f"engine/{layout}-decode"] = (eng._decode_fn._fn,
-                                          *eng._decode_args())
+    eng = InferenceEngine(m, params, EngineConfig(
+        max_slots=4, max_len=32, page_size=8))
+    out["engine/paged-decode"] = (eng._decode_fn._fn, *eng._decode_args())
+    # the prefill programs, one bucket each, with the arguments
+    # InferenceEngine._prefill_into builds for them
+    row = jnp.asarray(eng._page_table_h[0])
+    prompt = jnp.zeros((1, 16), jnp.int32)
+    sampling = (jnp.float32(0.0), jnp.int32(64), jnp.int32(0))
+    aix = jnp.zeros(1, jnp.int32)
+    out["engine/paged-prefill"] = (
+        eng._prefill_fn._fn, eng._params, eng._caches, row, prompt,
+        jnp.int32(11), *sampling, aix, None)
+    out["engine/suffix-prefill"] = (
+        eng._suffix_fn._fn, eng._params, eng._caches, row, prompt,
+        jnp.int32(8), jnp.int32(3), jnp.int32(11), *sampling,
+        jnp.bool_(False), aix, None)
+    spec = InferenceEngine(m, params, EngineConfig(
+        max_slots=4, max_len=32, page_size=8, speculation=3))
+    out["engine/spec-decode"] = (spec._decode_fn._fn, *spec._decode_args())
     return out
 
 
@@ -272,7 +286,9 @@ PARENT_PROGRAMS = {
     "bert/forward": "35e3f4c7245fd3e6",
     "encoder-decoder/loss": "f2746609c8484d9e",
     "engine/paged-decode": "2fda3dc55a57469f",
-    "engine/flat-decode": "b5e403d1d73e3948",
+    "engine/paged-prefill": "b33e75e42f8d0415",
+    "engine/suffix-prefill": "90c81dffea2e0d87",
+    "engine/spec-decode": "5d15c62e192aebbf",
 }
 
 

@@ -50,6 +50,9 @@ CHEAP_MODEL_TEST_MODULES = {
     # (45 s); ISSUE 30 asks for them in tier-1
     "test_afmoe.py",
     "test_routed_experts.py",
+    # not a test module: the per-request reference the serving tests
+    # import (one jitted prefill and one decode step a request)
+    "serving_reference.py",
 }
 
 

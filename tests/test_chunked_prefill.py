@@ -4,7 +4,7 @@ Correctness anchor: with ``prefill_token_budget`` set, a prompt
 prefills as a sequence of fixed-shape chunk programs interleaved with
 co-tenant decode steps — and the engine's output must stay TOKEN-EXACT
 against the monolithic (unchunked) engine, greedy AND sampled, across
-every KV configuration chunking composes with (flat, paged, int8,
+every KV configuration chunking composes with (bf16 and int8 pages,
 speculation, prefix cache, LoRA). The scheduling property rides along:
 a long prompt can no longer monopolize a tick, so co-tenant decode
 advances every tick while the long prompt is mid-prefill.
@@ -16,7 +16,6 @@ import pytest
 import jax
 
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import generate
 from apex_tpu.observability import (
     InMemorySink,
     MetricsRegistry,
@@ -34,6 +33,7 @@ from apex_tpu.serving import (
     SchedulerConfig,
 )
 from apex_tpu.testing_faults import ServingFaultInjector
+from serving_reference import reference_stream
 
 
 @pytest.fixture(scope="module")
@@ -77,39 +77,27 @@ class TestConfigValidation:
 
     def test_paged_budget_below_page_size_rejected(self):
         with pytest.raises(ValueError, match="page-aligned"):
-            EngineConfig(max_slots=2, max_len=32, kv_layout="paged",
-                         page_size=8, prefill_token_budget=4)
-
-    def test_flat_budget_one_allowed(self):
-        cfg = EngineConfig(max_slots=2, max_len=16, kv_layout="flat",
-                           prefill_token_budget=1)
-        assert cfg.prefill_token_budget == 1
+            EngineConfig(max_slots=2, max_len=32, page_size=8,
+                         prefill_token_budget=4)
 
 
 class TestTokenExactness:
-    """Chunked == monolithic, token for token, on both layouts."""
+    """Chunked == monolithic, token for token."""
 
-    @pytest.mark.parametrize("layout", [
-        # flat is the bisection opt-out layout; its exactness variant is
-        # slow-tier (ROADMAP), the default paged layout stays tier-1
-        pytest.param("flat", marks=pytest.mark.slow),
-        "paged",
-    ])
-    def test_greedy_and_sampled_exact(self, small, layout):
+    def test_greedy_and_sampled_exact(self, small):
         model, params = small
         prompts = _prompts((23, 5, 11, 17), seed=41)
-        extra = dict(page_size=4, n_pages=96) if layout == "paged" else {}
-        mono_cfg = EngineConfig(max_slots=4, max_len=64, kv_layout=layout,
-                                **extra)
-        chunk_cfg = EngineConfig(max_slots=4, max_len=64, kv_layout=layout,
-                                 prefill_token_budget=8, **extra)
+        mono_cfg = EngineConfig(max_slots=4, max_len=64, page_size=4,
+                                n_pages=96)
+        chunk_cfg = EngineConfig(max_slots=4, max_len=64, page_size=4,
+                                 n_pages=96, prefill_token_budget=8)
         _, mono = _serve(model, params, mono_cfg,
                          _mixed_requests(prompts, sampled=True))
         eng, chunked = _serve(model, params, chunk_cfg,
                               _mixed_requests(prompts, sampled=True))
         for rid, m in mono.items():
             c = chunked[rid]
-            assert c.tokens == m.tokens, (layout, rid)
+            assert c.tokens == m.tokens, rid
             assert c.finish_reason == m.finish_reason
         # the 23-token prompt could not fit one 8-token tick budget
         assert chunked[0].prefill_chunks and chunked[0].prefill_chunks > 1
@@ -118,26 +106,24 @@ class TestTokenExactness:
         assert eng.decode_retraces == 0
         assert eng.chunk_compiles <= len(eng.buckets)
 
-    @pytest.mark.slow  # parity vs generate(): slow-tier family (ROADMAP)
-    def test_flat_matches_generate_reference(self, small):
-        """Chunked greedy output equals the per-request ``generate()``
-        reference — not just the monolithic engine (guards against a
-        bug both engines share)."""
+    @pytest.mark.slow  # parity vs the reference: slow-tier family (ROADMAP)
+    def test_chunked_matches_per_request_reference(self, small):
+        """Chunked output, greedy and sampled, equals each request
+        served alone by the per-request reference — not just the
+        monolithic engine (guards against a bug both engines share)."""
         model, params = small
         prompts = _prompts((19, 6), seed=43)
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
-        _, out = _serve(model, params, cfg, _mixed_requests(prompts))
-        import jax.numpy as jnp
-        for rid, p in enumerate(prompts):
-            ref = generate(model, params, jnp.asarray([p], jnp.int32),
-                           6, max_len=64)
-            assert out[rid].tokens == \
-                np.asarray(ref[0, len(p):]).tolist(), rid
+        reqs = _mixed_requests(prompts, sampled=True)
+        _, out = _serve(model, params, cfg, reqs)
+        for req in reqs:
+            assert out[req.request_id].tokens == reference_stream(
+                model, params, req, 64), req.request_id
 
 
 @pytest.mark.slow  # compile-bound feature-cross parity: slow tier;
-# tier-1 keeps both layouts' chunked-vs-monolithic exactness above
+# tier-1 keeps the chunked-vs-monolithic exactness above
 class TestComposition:
     def test_int8_paged_exact(self, small):
         """Page-aligned chunk boundaries keep int8 quantization bitwise:
@@ -145,8 +131,8 @@ class TestComposition:
         and therefore tokens — match the monolithic engine."""
         model, params = small
         prompts = _prompts((21, 9), seed=47)
-        base = dict(max_slots=2, max_len=64, kv_layout="paged",
-                    page_size=4, n_pages=64, kv_dtype="int8")
+        base = dict(max_slots=2, max_len=64, page_size=4,
+                    n_pages=64, kv_dtype="int8")
         _, mono = _serve(model, params, EngineConfig(**base),
                          _mixed_requests(prompts, sampled=True))
         _, chunked = _serve(
@@ -165,8 +151,8 @@ class TestComposition:
         shared = rng.randint(0, 64, size=12).tolist()
         prompts = [shared + rng.randint(0, 64, size=6).tolist(),
                    shared + rng.randint(0, 64, size=9).tolist()]
-        base = dict(max_slots=2, max_len=64, kv_layout="paged",
-                    page_size=4, n_pages=64, prefix_cache=True,
+        base = dict(max_slots=2, max_len=64, page_size=4,
+                    n_pages=64, prefix_cache=True,
                     scheduler=SchedulerConfig(max_prefills_per_tick=1))
         _, mono = _serve(model, params, EngineConfig(**base),
                          _mixed_requests(prompts))
@@ -184,8 +170,8 @@ class TestComposition:
     def test_speculation_exact(self, small):
         model, params = small
         prompts = _prompts((18, 7), seed=59)
-        base = dict(max_slots=2, max_len=64, kv_layout="paged",
-                    page_size=4, n_pages=64, speculation=3)
+        base = dict(max_slots=2, max_len=64, page_size=4,
+                    n_pages=64, speculation=3)
         _, mono = _serve(model, params, EngineConfig(**base),
                          _mixed_requests(prompts))
         _, chunked = _serve(
@@ -212,8 +198,8 @@ class TestComposition:
                                 adapter_id="t0" if i == 0 else None))
                     for i, p in enumerate(prompts)]
 
-        base = dict(max_slots=2, max_len=64, kv_layout="paged",
-                    page_size=4, n_pages=64)
+        base = dict(max_slots=2, max_len=64, page_size=4,
+                    n_pages=64)
 
         def run(cfg):
             eng = InferenceEngine(model, params, cfg, adapters=adapters)
@@ -240,8 +226,8 @@ class TestMixedTicks:
                         max_new_tokens=20, request_id=0)
         long_p = Request(prompt=_prompts([40], seed=68)[0],
                          max_new_tokens=4, request_id=1)
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="paged",
-                           page_size=4, n_pages=64,
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
+                           n_pages=64,
                            prefill_token_budget=8)
         eng = InferenceEngine(model, params, cfg)
         try:
@@ -271,7 +257,7 @@ class TestMixedTicks:
     def test_budget_bounds_tokens_per_tick(self, small):
         model, params = small
         reg = MetricsRegistry()
-        cfg = EngineConfig(max_slots=4, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=4, max_len=64, page_size=4,
                            prefill_token_budget=8)
         _serve(model, params, cfg,
                _mixed_requests(_prompts((23, 11, 5, 9), seed=71)),
@@ -289,7 +275,7 @@ class TestMixedTicks:
         the FINAL chunk emits token #1 — it equals queue_s + prefill_s
         (which now spans several ticks), never just the first chunk."""
         model, params = small
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
         req = Request(prompt=_prompts([20], seed=73)[0],
                       max_new_tokens=3, request_id=0)
@@ -307,7 +293,7 @@ class TestMixedTicks:
         log lists requests in submit order even when budget starvation
         delays later heads by several ticks."""
         model, params = small
-        cfg = EngineConfig(max_slots=4, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=4, max_len=64, page_size=4,
                            prefill_token_budget=4)
         reqs = _mixed_requests(_prompts((15, 3, 9, 4), seed=79))
         eng, _ = _serve(model, params, cfg, reqs)
@@ -322,7 +308,7 @@ class TestTracing:
         model, params = small
         sink = InMemorySink()
         reg = MetricsRegistry([sink])
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
         req = Request(prompt=_prompts([13], seed=83)[0],
                       max_new_tokens=3, request_id=0)
@@ -342,7 +328,7 @@ class TestTracing:
         model, params = small
         sink = InMemorySink()
         reg = MetricsRegistry([sink])
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat")
+        cfg = EngineConfig(max_slots=2, max_len=64)
         req = Request(prompt=_prompts([13], seed=83)[0],
                       max_new_tokens=3, request_id=0)
         _serve(model, params, cfg, [req], metrics=reg)
@@ -359,7 +345,7 @@ class TestTracing:
         model, params = small
         log = tmp_path / "chunked.jsonl"
         reg = MetricsRegistry([JsonlSink(str(log))])
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
         _, out = _serve(model, params, cfg,
                         _mixed_requests(_prompts((13, 6), seed=89)),
@@ -382,8 +368,8 @@ class TestLifecycle:
         import time
 
         model, params = small
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="paged",
-                           page_size=4, n_pages=64,
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
+                           n_pages=64,
                            prefill_token_budget=4)
         eng = InferenceEngine(model, params, cfg)
         try:
@@ -412,7 +398,7 @@ class TestLifecycle:
 
     def test_cancel_mid_prefill(self, small):
         model, params = small
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
         eng = InferenceEngine(model, params, cfg)
         try:
@@ -440,7 +426,7 @@ class TestLifecycle:
         model, params = small
         req = Request(prompt=_prompts([20], seed=103)[0],
                       max_new_tokens=5, request_id=0)
-        cfg = EngineConfig(max_slots=2, max_len=64, kv_layout="flat",
+        cfg = EngineConfig(max_slots=2, max_len=64, page_size=4,
                            prefill_token_budget=4)
         # prefill call 2 = the long prompt's THIRD chunk: the crash
         # lands mid-chunked-prefill, with two chunks already resident
@@ -454,16 +440,8 @@ class TestLifecycle:
             sup.close()
         assert sup.restarts == 1
         assert ("prefill_raise", 2) in inj.log
-        mono = InferenceEngine(model, params,
-                               EngineConfig(max_slots=2, max_len=64,
-                                            kv_layout="flat"))
-        try:
-            ref = mono.serve([Request(prompt=req.prompt, max_new_tokens=5,
-                                      request_id=1)])
-        finally:
-            mono.close()
-        assert results[0].tokens == ref[0].tokens
-        assert results[0].finish_reason == ref[0].finish_reason
+        assert results[0].tokens == reference_stream(model, params, req, 64)
+        assert results[0].finish_reason == "length"
 
 
 @pytest.fixture
@@ -479,35 +457,32 @@ def tp2_mesh():
 
 @pytest.mark.slow  # TP model parity: the slow-tier class (ROADMAP)
 class TestShardedChunked:
-    @pytest.mark.parametrize("layout", ["flat", "paged"])
-    def test_tp2_chunked_token_exact(self, small, tp2_mesh, layout):
+    def test_tp2_chunked_token_exact(self, small, tp2_mesh):
         """Chunked prefill on a tp=2 mesh is token-exact vs the
-        unsharded MONOLITHIC engine — the chunk programs shard like
-        their parent bodies (paged chunks ride the suffix program's
-        existing wiring; flat chunks get their own shard_map)."""
+        unsharded MONOLITHIC engine and vs each request served alone by
+        the per-request reference — the chunks ride the suffix
+        program's sharded wiring."""
         from apex_tpu.serving.fleet import ShardedEngine
 
         model, params = small
         prompts = _prompts((19, 6, 11), seed=113)
-        extra = dict(page_size=4, n_pages=64) if layout == "paged" else {}
-        _, mono = _serve(
-            model, params,
-            EngineConfig(max_slots=4, max_len=64, kv_layout=layout,
-                         **extra),
-            _mixed_requests(prompts, sampled=True))
+        base = dict(max_slots=4, max_len=64, page_size=4, n_pages=64)
+        _, mono = _serve(model, params, EngineConfig(**base),
+                         _mixed_requests(prompts, sampled=True))
         sharded = ShardedEngine(
-            model, params,
-            EngineConfig(max_slots=4, max_len=64, kv_layout=layout,
-                         prefill_token_budget=8, **extra))
+            model, params, EngineConfig(prefill_token_budget=8, **base))
         with sharded:
             out = {r.request_id: r
                    for r in sharded.serve(
                        _mixed_requests(prompts, sampled=True))}
             assert sharded.decode_retraces == 0
             assert sharded.chunk_compiles <= len(sharded.buckets)
-        for rid, m in mono.items():
-            assert out[rid].tokens == m.tokens, (layout, rid)
-            assert out[rid].finish_reason == m.finish_reason
+        for req in _mixed_requests(prompts, sampled=True):
+            rid = req.request_id
+            assert out[rid].tokens == mono[rid].tokens, rid
+            assert out[rid].finish_reason == mono[rid].finish_reason
+            assert out[rid].tokens == reference_stream(
+                model, params, req, 64), rid
         assert out[0].prefill_chunks > 1
 
 

@@ -22,14 +22,12 @@ import json
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from apex_tpu.loadtest import Scenario, run_scenario
 from apex_tpu.loadtest.__main__ import EXIT_OK, main as loadtest_main
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import generate
 from apex_tpu.observability import (
     InMemorySink,
     JsonlSink,
@@ -62,6 +60,8 @@ from apex_tpu.serving.fleet import (
 )
 from apex_tpu.serving.fleet.router import _Replica
 from apex_tpu.testing_faults import ServingFaultInjector
+from serving_reference import reference_stream
+
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FLEET_SCENARIO = os.path.join(REPO, "benchmarks", "scenarios",
@@ -85,16 +85,6 @@ def small():
 def _prompts(lens, seed=7):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, 64, size=n).tolist() for n in lens]
-
-
-def _expected_greedy(model, params, request, max_len):
-    out = generate(model, params, jnp.asarray([request.prompt], jnp.int32),
-                   request.max_new_tokens, max_len=max_len,
-                   eos_token=request.eos_token)
-    toks = np.asarray(out[0, request.prompt_len:]).tolist()
-    if request.eos_token is not None and request.eos_token in toks:
-        toks = toks[:toks.index(request.eos_token) + 1]
-    return toks
 
 
 def _fleet(model, params, n=2, *, max_slots=2, max_len=32, faults=None,
@@ -333,7 +323,7 @@ class TestDrainingRestart:
             res = fleet.completed[victim.request_id]
             assert res.finish_reason == "length"
             assert res.replica_id == 1 - victim_home  # finished on peer
-            assert res.tokens == _expected_greedy(model, params, victim,
+            assert res.tokens == reference_stream(model, params, victim,
                                                   32)
             # the rebuilt replica rejoined with the carried estimate
             rebuilt = fleet.replicas[victim_home]
@@ -371,7 +361,7 @@ class TestDrainingRestart:
             res = fleet.completed[req.request_id]
             # finished on its ORIGINAL replica, then the rebuild happened
             assert res.replica_id == home
-            assert res.tokens == _expected_greedy(model, params, req, 32)
+            assert res.tokens == reference_stream(model, params, req, 32)
             assert fleet.replicas[home].state == REPLICA_ACTIVE
         counters = reg.counters()
         assert counters["requests_migrated"] == 0
@@ -618,7 +608,7 @@ class TestShardedEngine:
         with sup:
             results = sup.serve([req])
         assert sup.restarts == 1
-        assert results[0].tokens == _expected_greedy(model, params, req,
+        assert results[0].tokens == reference_stream(model, params, req,
                                                      32)
 
 

@@ -131,26 +131,27 @@ class TestScenarioSchema:
         with pytest.raises(ValueError, match="weight"):
             Scenario.from_dict(d)
 
-    def test_kv_layout_knobs_round_trip(self):
+    def test_page_knobs_round_trip(self):
         d = _scenario_dict(engine={
             "max_slots": 4, "max_len": 32, "max_queue": 16,
-            "kv_layout": "paged", "page_size": 8, "n_pages": 12})
+            "page_size": 8, "n_pages": 12})
         scn = Scenario.from_dict(d)
-        assert scn.engine.kv_layout == "paged"
         assert scn.engine.page_size == 8
         assert scn.engine.n_pages == 12
         again = Scenario.from_dict(scn.to_dict())
         assert again.to_dict() == scn.to_dict()
-        # flat opt-out survives too, and n_pages=None stays absent
-        flat = Scenario.from_dict(_scenario_dict(engine={
-            "max_slots": 4, "max_len": 32, "kv_layout": "flat"}))
-        assert flat.engine.kv_layout == "flat"
-        assert "n_pages" not in flat.to_dict()["engine"]
+        # n_pages=None stays absent
+        plain = Scenario.from_dict(_scenario_dict(engine={
+            "max_slots": 4, "max_len": 32}))
+        assert "n_pages" not in plain.to_dict()["engine"]
 
     def test_bad_kv_layout_rejected(self):
-        with pytest.raises(ValueError, match="kv_layout"):
+        """The engine has one KV layout: a scenario file that still
+        names the key is refused by name, as any unknown key is."""
+        with pytest.raises(ValueError,
+                           match=r"unknown engine keys \['kv_layout'\]"):
             Scenario.from_dict(_scenario_dict(engine={
-                "max_slots": 4, "max_len": 32, "kv_layout": "ragged"}))
+                "max_slots": 4, "max_len": 32, "kv_layout": "paged"}))
 
     def test_kv_dtype_and_speculation_round_trip(self):
         scn = Scenario.from_dict(_scenario_dict(engine={
@@ -170,17 +171,9 @@ class TestScenarioSchema:
         with pytest.raises(ValueError, match="kv_dtype"):
             Scenario.from_dict(_scenario_dict(engine={
                 "max_slots": 4, "max_len": 32, "kv_dtype": "fp4"}))
-        with pytest.raises(ValueError, match="needs kv_layout='paged'"):
-            Scenario.from_dict(_scenario_dict(engine={
-                "max_slots": 4, "max_len": 32, "kv_layout": "flat",
-                "kv_dtype": "int8"}))
         with pytest.raises(ValueError, match="speculation"):
             Scenario.from_dict(_scenario_dict(engine={
                 "max_slots": 4, "max_len": 32, "speculation": 1}))
-        with pytest.raises(ValueError, match="needs kv_layout='paged'"):
-            Scenario.from_dict(_scenario_dict(engine={
-                "max_slots": 4, "max_len": 32, "kv_layout": "flat",
-                "speculation": 2}))
 
     def test_prompt_period_round_trip_and_validation(self):
         d = _scenario_dict()

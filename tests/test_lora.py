@@ -461,16 +461,14 @@ class TestAdapterParity:
                 assert got[q.request_id] == rr.tokens, aid
 
     def test_variant_engines_token_exact(self, small):
-        """The adapter path composes with every serving variant: flat
-        KV, speculation, and int8+speculation all match their own
+        """The adapter path composes with the serving variants:
+        speculation and int8+speculation both match their own
         merged-weights reference under the same config."""
         model, params = small
         store, factors = _store(model.config, ids=("a",))
         merged = merge_adapter(params, factors["a"])
         prompts = _prompts([5, 9, 3], seed=3)
         for name, ec in [
-            ("flat", EngineConfig(max_slots=4, max_len=64,
-                                  kv_layout="flat", retrace_budget=0)),
             ("spec", EngineConfig(max_slots=4, max_len=64, speculation=3,
                                   retrace_budget=0)),
             ("int8+spec", EngineConfig(max_slots=4, max_len=64,

@@ -229,14 +229,6 @@ class TestFlops:
     def test_peak_flops_unknown_on_cpu(self):
         assert peak_flops_per_chip() is None  # tier-1 runs on CPU
 
-    def test_harness_shares_the_library_estimators(self):
-        # satellite: benchmarks/_harness re-exports, not redefines
-        from benchmarks import _harness
-
-        assert _harness.transformer_train_flops is transformer_train_flops
-        assert _harness.resnet50_train_flops is resnet50_train_flops
-        assert _harness.peak_flops_per_chip is peak_flops_per_chip
-
 
 class TestStepMetrics:
     def _clock(self, dt):

@@ -15,12 +15,10 @@ tier per the ROADMAP tier policy.
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import generate
 from apex_tpu.ops import _support
 from apex_tpu.serving import (
     EngineConfig,
@@ -40,6 +38,7 @@ from apex_tpu.serving.prefix import (
     prefix_salt,
 )
 from apex_tpu.testing_faults import ServingFaultInjector
+from serving_reference import reference_stream
 
 
 @pytest.fixture(autouse=True)
@@ -66,16 +65,6 @@ def small():
 def _prompts(lens, seed=7):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, 64, size=n).tolist() for n in lens]
-
-
-def _expected_greedy(model, params, request, max_len):
-    out = generate(model, params, jnp.asarray([request.prompt], jnp.int32),
-                   request.max_new_tokens, max_len=max_len,
-                   eos_token=request.eos_token)
-    toks = np.asarray(out[0, request.prompt_len:]).tolist()
-    if request.eos_token is not None and request.eos_token in toks:
-        toks = toks[:toks.index(request.eos_token) + 1]
-    return toks
 
 
 # ---------------------------------------------------------------------------
@@ -314,8 +303,7 @@ class TestPrefixEngine:
             assert a.finish_reason == b.finish_reason
             assert a.tokens == b.tokens, (a.request_id, a.tokens, b.tokens)
         for r, req in zip(out, reqs):
-            if req.sampling.temperature == 0.0:
-                assert r.tokens == _expected_greedy(model, params, req, 32)
+            assert r.tokens == reference_stream(model, params, req, 32)
 
     @pytest.mark.slow  # COW edge-seam sweep: slow tier (ROADMAP)
 
@@ -336,7 +324,7 @@ class TestPrefixEngine:
             for prompt in (base, fork, base):
                 req = Request(prompt=list(prompt), max_new_tokens=5)
                 res = eng.serve([req])
-                assert res[0].tokens == _expected_greedy(
+                assert res[0].tokens == reference_stream(
                     model, params, req, 32), prompt
             c = eng.metrics.counters()
             assert c["prefix_misses"] == 1 and c["prefix_hits"] == 2
@@ -365,7 +353,7 @@ class TestPrefixEngine:
             results = {r.request_id: r
                        for r in eng.serve([survivor, victim])}
             assert results[victim.request_id].finish_reason == "error"
-            assert results[survivor.request_id].tokens == _expected_greedy(
+            assert results[survivor.request_id].tokens == reference_stream(
                 model, params, survivor, 32)
             assert eng.metrics.counters()["slots_quarantined"] == 1
             eng.pages.check()
@@ -373,7 +361,7 @@ class TestPrefixEngine:
                 eng.pages.n_pages
             late = Request(prompt=prefix + [7, 8, 9], max_new_tokens=5)
             res = eng.serve([late])
-            assert res[0].tokens == _expected_greedy(model, params,
+            assert res[0].tokens == reference_stream(model, params,
                                                      late, 32)
             c = eng.metrics.counters()
             assert c["prefix_hits"] >= 2                  # victim + late
@@ -395,7 +383,7 @@ class TestPrefixEngine:
             for p in prefixes:                            # distinct misses
                 req = Request(prompt=list(p), max_new_tokens=4)
                 res = eng.serve([req])
-                assert res[0].tokens == _expected_greedy(
+                assert res[0].tokens == reference_stream(
                     model, params, req, 16)
             c = eng.metrics.counters()
             assert c["prefix_evictions"] >= 1             # pressure evicted
@@ -407,11 +395,11 @@ class TestPrefixEngine:
             # and an immediate second repeat hits
             again = Request(prompt=list(prefixes[0]), max_new_tokens=4)
             res = eng.serve([again])
-            assert res[0].tokens == _expected_greedy(
+            assert res[0].tokens == reference_stream(
                 model, params, again, 16)
             hit = Request(prompt=list(prefixes[0]), max_new_tokens=4)
             res = eng.serve([hit])
-            assert res[0].tokens == _expected_greedy(model, params, hit, 16)
+            assert res[0].tokens == reference_stream(model, params, hit, 16)
             c = eng.metrics.counters()
             assert c["prefix_hits"] >= 1
             assert eng.decode_retraces == 0
@@ -519,7 +507,7 @@ class TestPrefixResilience:
             results = {r.request_id: r for r in sup.serve(reqs)}
         assert sup.restarts == 1
         for req in reqs:
-            assert results[req.request_id].tokens == _expected_greedy(
+            assert results[req.request_id].tokens == reference_stream(
                 model, params, req, 32)
         eng = sup.engine
         assert eng.pages.free_count + eng.pages.reclaimable_count == \
@@ -527,28 +515,29 @@ class TestPrefixResilience:
         eng.pages.check()
 
     @pytest.mark.slow
-    def test_tp2_sharded_prefix_hits_vs_unsharded_flat(self, small):
+    def test_tp2_sharded_prefix_hits_vs_unsharded(self, small):
         """ShardedEngine (tp=2, prefix cache ON, suffix prefill
-        shard_mapped) against the unsharded FLAT engine on shared-prefix
-        traffic: token-exact with real prefix hits on the sharded side —
-        the mesh cannot hide in the reuse path nor vice versa."""
+        shard_mapped) against the unsharded engine with the prefix cache
+        OFF (every prefill whole) and against each request served alone
+        by the per-request reference, on shared-prefix traffic:
+        token-exact with real prefix hits on the sharded side — the mesh
+        cannot hide in the reuse path nor vice versa."""
         from apex_tpu.serving import ShardedEngine
         from apex_tpu.transformer import parallel_state
 
         model, params = small
         _, reqs = _shared_prefix_requests(seed=59)
-        flat_eng = InferenceEngine(model, params, EngineConfig(
-            max_slots=2, max_len=32, kv_layout="flat"))
-        with flat_eng:
-            _, flat_reqs = _shared_prefix_requests(seed=59)
-            ref = flat_eng.serve(flat_reqs)
+        cold = InferenceEngine(model, params, EngineConfig(
+            max_slots=2, max_len=32, page_size=4, prefix_cache=False))
+        with cold:
+            ref = cold.serve(_shared_prefix_requests(seed=59)[1])
 
         parallel_state.destroy_model_parallel()
         try:
             parallel_state.initialize_model_parallel(
                 tensor_model_parallel_size=2)
             sharded = ShardedEngine(model, params, EngineConfig(
-                max_slots=2, max_len=32, kv_layout="paged", page_size=4))
+                max_slots=2, max_len=32, page_size=4))
             with sharded:
                 out = sharded.serve(reqs)
                 assert sharded.decode_retraces == 0
@@ -561,6 +550,7 @@ class TestPrefixResilience:
                 sharded.pages.check()
         finally:
             parallel_state.destroy_model_parallel()
-        for a, b in zip(ref, out):
+        for a, b, req in zip(ref, out, reqs):
             assert a.finish_reason == b.finish_reason
             assert a.tokens == b.tokens, (a.request_id, a.tokens, b.tokens)
+            assert a.tokens == reference_stream(model, params, req, 32)

@@ -822,7 +822,7 @@ class TestEnginePreemptResume:
         store = AdapterStore(model.config, 4, max_adapters=2)
         store.load("a", random_adapter(model.config, 4,
                                        jax.random.PRNGKey(3)))
-        cfg = self._cfg(kv_layout="paged", kv_dtype="int8")
+        cfg = self._cfg(kv_dtype="int8")
         mk = lambda: _req([6, 2, 9], max_new=10, priority=PRIORITY_BATCH,
                           adapter="a", temperature=0.8, top_k=8,
                           seed=77)

@@ -43,6 +43,8 @@ from apex_tpu.serving import (
     bucket_for,
     prefill_buckets,
 )
+from apex_tpu.serving.engine import _sample_tokens
+from serving_reference import pick_token, reference_stream
 
 
 @pytest.fixture(scope="module")
@@ -60,16 +62,45 @@ def _prompts(lens, seed=7):
     return [rng.randint(0, 64, size=n).tolist() for n in lens]
 
 
-def _expected_greedy(model, params, request, max_len):
-    """Per-request generate() reference, truncated at the first EOS —
-    exactly what the engine's result.tokens promises."""
-    out = generate(model, params, jnp.asarray([request.prompt], jnp.int32),
-                   request.max_new_tokens, max_len=max_len,
-                   eos_token=request.eos_token)
-    toks = np.asarray(out[0, request.prompt_len:]).tolist()
-    if request.eos_token is not None and request.eos_token in toks:
-        toks = toks[:toks.index(request.eos_token) + 1]
-    return toks
+# ---------------------------------------------------------------------------
+# the per-request reference every token-exactness test compares with
+# (tests/serving_reference.py), checked itself
+
+
+class TestReference:
+    def test_greedy_stream_is_generates(self, small):
+        model, params = small
+        (prompt,) = _prompts([5])
+        out = np.asarray(generate(model, params, jnp.asarray([prompt]), 8,
+                                  max_len=16))[0, 5:].tolist()
+        req = Request(prompt=prompt, max_new_tokens=8)
+        assert reference_stream(model, params, req, 16) == out
+        # and it stops at, and keeps, the first eos_token
+        eos = Request(prompt=prompt, max_new_tokens=8, eos_token=out[2])
+        assert reference_stream(model, params, eos, 16) == \
+            out[:out.index(out[2]) + 1]
+
+    @pytest.mark.parametrize("temperature,top_k", [
+        (0.0, None), (0.8, 8), (1.1, None)],
+        ids=["greedy", "top-k", "no-top-k"])
+    def test_pick_token_is_the_engines_sampler(self, temperature, top_k):
+        """Over a seeded [8, 64] block of logits, row by row at 8 seeds
+        and positions: the rule written with a sort and
+        ``_sample_tokens`` (which finds the k-th largest without one,
+        batched under vmap) pick the same."""
+        logits = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (8, 64))
+        seeds = np.arange(8, dtype=np.int32) * 7 + 1
+        steps = np.arange(8, dtype=np.int32) + 5
+        engine = np.asarray(_sample_tokens(
+            logits, jnp.full(8, temperature, jnp.float32),
+            jnp.full(8, top_k or 64, jnp.int32), jnp.asarray(seeds),
+            jnp.asarray(steps)))
+        plain = [pick_token(logits[i], SamplingParams(
+            temperature=temperature, top_k=top_k, seed=int(seeds[i])),
+            int(steps[i])) for i in range(8)]
+        assert plain == engine.tolist()
+        if temperature:
+            assert plain != np.asarray(jnp.argmax(logits, -1)).tolist()
 
 
 class TestRequestValidation:
@@ -188,7 +219,7 @@ class TestEngine:
             [r.request_id for r in reqs]
         for req, res in zip(reqs, results):
             assert res.finish_reason == "length"
-            assert res.tokens == _expected_greedy(model, params, req, 16)
+            assert res.tokens == reference_stream(model, params, req, 16)
         # FCFS admission, no slot leaks, bounded compile count, and the
         # one-compile decode invariant straight from the watchdog
         assert eng.admission_log == [r.request_id for r in reqs]
@@ -210,7 +241,7 @@ class TestEngine:
                               EngineConfig(max_slots=2, max_len=16))
         (res,) = eng.serve([req])
         assert res.finish_reason == "eos"
-        assert res.tokens == _expected_greedy(model, params, req, 16)
+        assert res.tokens == reference_stream(model, params, req, 16)
         assert res.tokens[-1] == eos
         assert eng.slots.free_count == eng.config.max_slots
 
@@ -276,10 +307,10 @@ class TestEngine:
         cancelled, survivor = results
         assert cancelled.finish_reason == "cancelled"
         assert 0 < cancelled.new_tokens < 12
-        expected = _expected_greedy(model, params, reqs[0], 16)
+        expected = reference_stream(model, params, reqs[0], 16)
         assert cancelled.tokens == expected[:cancelled.new_tokens]
         assert survivor.finish_reason == "length"
-        assert survivor.tokens == _expected_greedy(model, params,
+        assert survivor.tokens == reference_stream(model, params,
                                                    reqs[1], 16)
         eng.slots.check()
         assert eng.slots.free_count == 2
@@ -328,7 +359,7 @@ class TestEngine:
             eng.tick()
         assert eng.decode_retraces == 0
         res = eng.completed[late.request_id]
-        assert res.tokens == _expected_greedy(model, params, late, 16)
+        assert res.tokens == reference_stream(model, params, late, 16)
 
     def test_overflowing_request_rejected_at_submit(self, small):
         model, params = small
@@ -498,7 +529,7 @@ class TestServingSweep:
             if r.request_id not in queue_cancelled]
         assert len(results) == len(reqs)
         for req, res in zip(reqs, results):
-            expected = _expected_greedy(model, params, req, max_len)
+            expected = reference_stream(model, params, req, max_len)
             if res.finish_reason in ("eos", "length"):
                 assert res.tokens == expected, req.request_id
             elif res.finish_reason == "cancelled":

@@ -3,13 +3,13 @@
 The ISSUE-10 contract: the paged engine is a memory-layout optimization,
 never an approximation. Tier-1 pins (a) PagePool free-list invariants
 (conservation asserted like slot leaks), (b) fused-kernel-vs-reference
-attention parity in interpret mode, (c) paged-vs-flat engine TOKEN
-EXACTNESS — greedy and sampled — with zero decode retraces, (d) the
+attention parity in interpret mode, (c) engine TOKEN EXACTNESS against
+the per-request reference (``serving_reference``) — greedy and sampled
+— with zero decode retraces, (d) the
 ``pages_exhausted`` admission shed + kv-page gauges reconciling in the
 monitor report, and (e) quarantine scrubbing and releasing pages. The
-compile-bound cases (supervisor restart on paged, tp=2 sharded paged
-crossed against unsharded flat) sit in the slow tier per the ROADMAP
-tier policy.
+compile-bound cases (supervisor restart, tp=2 sharded against
+unsharded) sit in the slow tier per the ROADMAP tier policy.
 """
 
 import json
@@ -23,7 +23,6 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import generate
 from apex_tpu.observability import (
     InMemorySink,
     JsonlSink,
@@ -50,13 +49,14 @@ from apex_tpu.serving import (
     SamplingParams,
 )
 from apex_tpu.testing_faults import ServingFaultInjector
+from serving_reference import reference_stream
 
 
 @pytest.fixture(autouse=True)
 def _pallas_off(monkeypatch):
     """Pin the jnp reference path: other test modules export
     ``APEX_TPU_FORCE_PALLAS=interpret`` process-wide at import, and the
-    bitwise paged-vs-flat claims below hold for the reference dispatch
+    bitwise token-exactness claims below hold for the reference dispatch
     (the interpret-mode kernel is compared to tolerance, explicitly)."""
     monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "off")
     _support.pallas_mode.cache_clear()
@@ -77,16 +77,6 @@ def small():
 def _prompts(lens, seed=7):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, 64, size=n).tolist() for n in lens]
-
-
-def _expected_greedy(model, params, request, max_len):
-    out = generate(model, params, jnp.asarray([request.prompt], jnp.int32),
-                   request.max_new_tokens, max_len=max_len,
-                   eos_token=request.eos_token)
-    toks = np.asarray(out[0, request.prompt_len:]).tolist()
-    if request.eos_token is not None and request.eos_token in toks:
-        toks = toks[:toks.index(request.eos_token) + 1]
-    return toks
 
 
 # ---------------------------------------------------------------------------
@@ -383,7 +373,8 @@ class TestFusedKernelParity:
 
     def test_cpu_dispatch_is_reference(self):
         """With pallas off (the CPU default) the public entry point IS
-        the reference — what makes paged-vs-flat engine parity bitwise."""
+        the reference — what makes the engine's parity with the per-request
+        reference bitwise."""
         case = _rand_paged_case(3)
         ctx, kk, vk = fused_paged_decode_attention(
             *case, queries_per_group=2)
@@ -427,36 +418,29 @@ class TestPagedEngine:
         return [Request(prompt=p, max_new_tokens=m, sampling=s)
                 for p, (_, m, s) in zip(prompts, specs)]
 
-    def test_paged_vs_flat_token_exact(self, small):
-        """The acceptance bar: identical mixed greedy/sampled traffic
-        through ``kv_layout="flat"`` and ``kv_layout="paged"`` engines is
-        TOKEN-EXACT, with zero decode retraces on both, and the paged
-        run returns every page. max_len divisible by page_size keeps the
-        logical reduction lengths identical, so parity is bitwise."""
+    def test_paged_token_exact_vs_reference(self, small):
+        """The acceptance bar: mixed greedy/sampled traffic through the
+        engine is TOKEN-EXACT, every row, against each request served
+        alone by the per-request reference (``serving_reference``), with
+        zero decode retraces, and the run returns every page. max_len
+        divisible by page_size keeps the logical reduction lengths
+        identical, so parity is bitwise."""
         model, params = small
-        flat_eng = InferenceEngine(model, params, EngineConfig(
-            max_slots=3, max_len=16, kv_layout="flat"))
-        with flat_eng:
-            ref = flat_eng.serve(self._requests())
-            assert flat_eng.decode_retraces == 0
-        paged_eng = InferenceEngine(model, params, EngineConfig(
-            max_slots=3, max_len=16, kv_layout="paged", page_size=4))
-        with paged_eng:
-            out = paged_eng.serve(self._requests())
-            assert paged_eng.decode_retraces == 0
+        eng = InferenceEngine(model, params, EngineConfig(
+            max_slots=3, max_len=16, page_size=4))
+        with eng:
+            out = eng.serve(self._requests())
+            assert eng.decode_retraces == 0
             # drained: every page is free or held only by the prefix
             # intern index (entries survive their writer for reuse)
-            assert paged_eng.pages.free_count + \
-                paged_eng.pages.reclaimable_count == paged_eng.pages.n_pages
-            paged_eng.pages.check()
-            paged_eng.slots.check()
-        for a, b in zip(ref, out):
-            assert a.finish_reason == b.finish_reason
-            assert a.tokens == b.tokens, (a.request_id, a.tokens, b.tokens)
-        # greedy rows also match the per-request generate() anchor
+            assert eng.pages.free_count + \
+                eng.pages.reclaimable_count == eng.pages.n_pages
+            eng.pages.check()
+            eng.slots.check()
         for r, req in zip(out, self._requests()):
-            if req.sampling.temperature == 0.0:
-                assert r.tokens == _expected_greedy(model, params, req, 16)
+            assert r.finish_reason == "length"
+            assert r.tokens == reference_stream(model, params, req, 16), \
+                (r.request_id, req.sampling)
 
     def test_close_resets_page_pool(self, small):
         model, params = small
@@ -486,7 +470,7 @@ class TestPagedEngine:
             results = {r.request_id: r for r in eng.serve([fits, doomed])}
         assert results[doomed.request_id].finish_reason == "rejected"
         assert results[fits.request_id].finish_reason == "length"
-        assert results[fits.request_id].tokens == _expected_greedy(
+        assert results[fits.request_id].tokens == reference_stream(
             model, params, fits, 16)
         counters = reg.counters()
         assert counters["requests_shed_pages"] == 1
@@ -541,7 +525,7 @@ class TestPagedEngine:
             clean = Request(prompt=_prompts([4], seed=31)[0],
                             max_new_tokens=5)
             res2 = eng.serve([clean])
-        assert res2[0].tokens == _expected_greedy(model, params, clean, 16)
+        assert res2[0].tokens == reference_stream(model, params, clean, 16)
         assert eng.decode_retraces == 0
 
     def test_randomized_arrivals_cancellations_no_page_leaks(self, small):
@@ -592,8 +576,7 @@ class TestPagedResilience:
     def test_supervisor_restart_token_exact_on_paged(self, small):
         """A decode exception mid-flight on the PAGED engine: the
         supervisor rebuild (fresh PagePool + page tables + jit) and
-        prompt+tokens re-prefill stays token-exact — recovery semantics
-        are layout-independent by construction."""
+        prompt+tokens re-prefill stays token-exact."""
         model, params = small
         reqs = [Request(prompt=p, max_new_tokens=n)
                 for p, n in zip(_prompts([3, 5], seed=31), (6, 8))]
@@ -606,7 +589,7 @@ class TestPagedResilience:
             results = {r.request_id: r for r in sup.serve(reqs)}
         assert sup.restarts == 1
         for req in reqs:
-            assert results[req.request_id].tokens == _expected_greedy(
+            assert results[req.request_id].tokens == reference_stream(
                 model, params, req, 16)
         eng = sup.engine
         assert eng.pages.free_count + eng.pages.reclaimable_count == \
@@ -614,12 +597,11 @@ class TestPagedResilience:
         eng.pages.check()
 
     @pytest.mark.slow
-    def test_tp2_sharded_paged_vs_unsharded_flat(self, small):
-        """The strongest cross: ShardedEngine (tp=2, paged pool sharded
-        on the heads-minor dim, page table replicated) against the
-        UNSHARDED FLAT engine — token-exact, greedy and sampled, zero
-        decode retraces. Crossing both the layout and the mesh axis in
-        one assertion means neither can be hiding in the other."""
+    def test_tp2_sharded_vs_unsharded_paged(self, small):
+        """ShardedEngine (tp=2, page pools sharded on the heads-minor
+        dim, page table replicated) against the UNSHARDED engine and
+        against each request served alone by the per-request reference
+        — token-exact, greedy and sampled, zero decode retraces."""
         from apex_tpu.serving import ShardedEngine
         from apex_tpu.transformer import parallel_state
 
@@ -635,17 +617,17 @@ class TestPagedResilience:
             return [Request(prompt=p, max_new_tokens=m, sampling=s)
                     for p, (_, m, s) in zip(prompts, specs)]
 
-        flat_eng = InferenceEngine(model, params, EngineConfig(
-            max_slots=4, max_len=32, kv_layout="flat"))
-        with flat_eng:
-            ref = flat_eng.serve(requests())
+        unsharded = InferenceEngine(model, params, EngineConfig(
+            max_slots=4, max_len=32, page_size=8))
+        with unsharded:
+            ref = unsharded.serve(requests())
 
         parallel_state.destroy_model_parallel()
         try:
             parallel_state.initialize_model_parallel(
                 tensor_model_parallel_size=2)
             sharded = ShardedEngine(model, params, EngineConfig(
-                max_slots=4, max_len=32, kv_layout="paged", page_size=8))
+                max_slots=4, max_len=32, page_size=8))
             with sharded:
                 out = sharded.serve(requests())
                 assert sharded.decode_retraces == 0
@@ -654,6 +636,7 @@ class TestPagedResilience:
                 sharded.pages.check()
         finally:
             parallel_state.destroy_model_parallel()
-        for a, b in zip(ref, out):
+        for a, b, req in zip(ref, out, requests()):
             assert a.finish_reason == b.finish_reason
             assert a.tokens == b.tokens, (a.request_id, a.tokens, b.tokens)
+            assert a.tokens == reference_stream(model, params, req, 32)

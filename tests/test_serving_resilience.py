@@ -13,12 +13,10 @@ key-for-key between the monitor report and the registry counters.
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import generate
 from apex_tpu.observability import (
     InMemorySink,
     JsonlSink,
@@ -46,7 +44,9 @@ from apex_tpu.serving import (
     SlotPool,
     SupervisorConfig,
 )
+from apex_tpu.serving.clock import VirtualClock, use_clock
 from apex_tpu.testing_faults import InjectedEngineFault, ServingFaultInjector
+from serving_reference import reference_stream
 
 
 @pytest.fixture(scope="module")
@@ -65,16 +65,6 @@ def small():
 def _prompts(lens, seed=7):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, 64, size=n).tolist() for n in lens]
-
-
-def _expected_greedy(model, params, request, max_len):
-    out = generate(model, params, jnp.asarray([request.prompt], jnp.int32),
-                   request.max_new_tokens, max_len=max_len,
-                   eos_token=request.eos_token)
-    toks = np.asarray(out[0, request.prompt_len:]).tolist()
-    if request.eos_token is not None and request.eos_token in toks:
-        toks = toks[:toks.index(request.eos_token) + 1]
-    return toks
 
 
 class TestSlotPoolReset:
@@ -190,14 +180,14 @@ class TestQuarantine:
                               EngineConfig(max_slots=2, max_len=16),
                               metrics=MetricsRegistry([sink]), faults=inj)
         victim, cotenant = eng.serve(reqs)
-        expected0 = _expected_greedy(model, params, reqs[0], 16)
+        expected0 = reference_stream(model, params, reqs[0], 16)
         assert victim.finish_reason == "error"
         # prefill token + decode call 0's token survived; the poisoned
         # token was never appended
         assert victim.tokens == expected0[:victim.new_tokens]
         assert 0 < victim.new_tokens < 6
         assert cotenant.finish_reason == "length"
-        assert cotenant.tokens == _expected_greedy(model, params,
+        assert cotenant.tokens == reference_stream(model, params,
                                                    reqs[1], 16)
         eng.slots.check()
         assert eng.slots.free_count == 2
@@ -226,7 +216,7 @@ class TestQuarantine:
         res = eng.serve([first, second])
         assert res[0].finish_reason == "error"
         assert res[1].finish_reason == "length"
-        assert res[1].tokens == _expected_greedy(model, params, second, 16)
+        assert res[1].tokens == reference_stream(model, params, second, 16)
         eng.slots.check()
 
 
@@ -247,7 +237,7 @@ class TestSupervisorRecovery:
         results = sup.serve(reqs)
         for req, res in zip(reqs, results):
             assert res.finish_reason == "length"
-            assert res.tokens == _expected_greedy(model, params, req, 16)
+            assert res.tokens == reference_stream(model, params, req, 16)
             assert res.prompt_len == req.prompt_len   # original, stitched
         counters = sup.metrics.counters()
         assert counters["engine_restarts"] == 1
@@ -289,7 +279,7 @@ class TestSupervisorRecovery:
         results = sup.serve(reqs)
         for req, res in zip(reqs, results):
             assert res.finish_reason == "length"
-            assert res.tokens == _expected_greedy(model, params, req, 16)
+            assert res.tokens == reference_stream(model, params, req, 16)
         sup.engine.slots.check()
         assert sup.metrics.counters()["engine_restarts"] == 1
 
@@ -303,7 +293,7 @@ class TestSupervisorRecovery:
         (res,) = sup.serve([Request(prompt=prompt, max_new_tokens=6)])
         req = Request(prompt=prompt, max_new_tokens=6)
         assert res.finish_reason == "length"
-        assert res.tokens == _expected_greedy(model, params, req, 16)
+        assert res.tokens == reference_stream(model, params, req, 16)
         assert sup.restarts == 1                 # exactly the hung tick;
         #                                          compile warmups exempt
         assert sup.metrics.counters()["tick_failures"] == 1
@@ -331,37 +321,43 @@ class TestSupervisorRecovery:
 class TestCircuitBreaker:
     def test_open_half_open_close_cycle(self, small):
         model, params = small
-        inj = ServingFaultInjector(decode_raise_calls={0, 1})
-        sup = EngineSupervisor(
-            model, params, EngineConfig(max_slots=2, max_len=16),
-            supervisor=SupervisorConfig(breaker_threshold=2,
-                                        breaker_cooldown_s=0.05,
-                                        max_restarts_per_request=5),
-            faults=inj)
-        victim = Request(prompt=_prompts([3], seed=53)[0], max_new_tokens=6)
-        sup.submit(victim)
-        sup.tick()
-        assert sup.breaker_state == BREAKER_CLOSED   # 1 failure < threshold
-        sup.tick()
-        assert sup.breaker_state == BREAKER_OPEN     # 2nd consecutive
-        # fast-fail while open: terminal immediately, engine untouched
-        shed = Request(prompt=_prompts([4], seed=54)[0], max_new_tokens=3)
-        with pytest.raises(EngineUnavailableError):
-            sup.submit(shed)
-        assert sup.completed[shed.request_id].finish_reason == "rejected"
-        time.sleep(0.06)                             # cooldown elapses
-        sup.tick()                                   # half-open probe: clean
-        assert sup.breaker_state == BREAKER_CLOSED
-        while sup.inflight_count:
+        # virtual time: the cool-down is waited out by advancing the
+        # clock the supervisor reads, not by racing a real sleep against
+        # a rebuild
+        with use_clock(VirtualClock()) as vc:
+            inj = ServingFaultInjector(decode_raise_calls={0, 1})
+            sup = EngineSupervisor(
+                model, params, EngineConfig(max_slots=2, max_len=16),
+                supervisor=SupervisorConfig(breaker_threshold=2,
+                                            breaker_cooldown_s=0.05,
+                                            max_restarts_per_request=5),
+                faults=inj)
+            victim = Request(prompt=_prompts([3], seed=53)[0],
+                             max_new_tokens=6)
+            sup.submit(victim)
             sup.tick()
-        # the victim survived the whole episode, token-exact
-        res = sup.completed[victim.request_id]
-        assert res.tokens == _expected_greedy(model, params, victim, 16)
-        counters = sup.metrics.counters()
-        assert counters["breaker_opens"] == 1
-        assert counters["breaker_half_opens"] == 1
-        assert counters["breaker_closes"] == 1
-        assert counters["requests_shed_breaker"] == 1
+            assert sup.breaker_state == BREAKER_CLOSED  # 1 failure < threshold
+            sup.tick()
+            assert sup.breaker_state == BREAKER_OPEN     # 2nd consecutive
+            # fast-fail while open: terminal immediately, engine untouched
+            shed = Request(prompt=_prompts([4], seed=54)[0],
+                           max_new_tokens=3)
+            with pytest.raises(EngineUnavailableError):
+                sup.submit(shed)
+            assert sup.completed[shed.request_id].finish_reason == "rejected"
+            vc.advance(0.06)                             # cooldown elapses
+            sup.tick()                                # half-open probe: clean
+            assert sup.breaker_state == BREAKER_CLOSED
+            while sup.inflight_count:
+                sup.tick()
+            # the victim survived the whole episode, token-exact
+            res = sup.completed[victim.request_id]
+            assert res.tokens == reference_stream(model, params, victim, 16)
+            counters = sup.metrics.counters()
+            assert counters["breaker_opens"] == 1
+            assert counters["breaker_half_opens"] == 1
+            assert counters["breaker_closes"] == 1
+            assert counters["requests_shed_breaker"] == 1
 
     @pytest.mark.slow
     def test_failed_probe_reopens(self, small):
