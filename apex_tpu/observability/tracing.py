@@ -53,11 +53,16 @@ TICK_SCHEDULE = "tick.schedule"      # expire, evict, preempt, pop the
 #                                      windows: queued, active, pages_mapped
 TICK_UPLOAD = "tick.upload"          # host arrays -> device: arrays, bytes
 TICK_DISPATCH = "tick.dispatch"      # the jitted call, until it returns:
-#                                      program, rows
-TICK_READBACK = "tick.readback"      # blocking np.asarray: reads, bytes
+#                                      program, rows; a decode step also
+#                                      pages, in_flight (0 / 1: the step
+#                                      before not read yet)
+TICK_READBACK = "tick.readback"      # blocking np.asarray: reads, bytes;
+#                                      of a decode step also lag (steps
+#                                      dispatched since the one read)
 TICK_COMMIT = "tick.commit"          # tokens into records, retirements,
 #                                      gauges, the supervisor's harvest:
-#                                      tokens, retired
+#                                      tokens, retired; of a decode step
+#                                      also dropped
 TICK_LEAF_SPANS = (TICK_SCHEDULE, TICK_UPLOAD, TICK_DISPATCH, TICK_READBACK,
                    TICK_COMMIT)
 

@@ -34,6 +34,16 @@ Architecture (docs/serving.md has the full walkthrough):
   decode roofline (PAPERS: arXiv 2502.17728) is only reachable when
   every step is the same compiled program. With ``speculation=k`` the
   same step feeds a ``[n, k]`` verify window.
+- **One step in flight**: the plain decode path dispatches step k
+  before it reads step k-1. Positions advance by one a step, page
+  growth depends on position only and the sampler is keyed by ``(seed,
+  position)``, so the host can build step k without step k-1's tokens;
+  the one true dependence, the fed token, stays on the device (the
+  program selects it from the vector the step before returned). The
+  tick's host work then runs behind the device step; EOS, a poisoned
+  row, a cancel are learned one step late, at the price of at most one
+  dropped row a slot (docs/serving.md#one-step-in-flight).
+  Speculation reads each step in the tick that dispatched it.
 - **Bucketed prefill that fills pages**: prompts prefill one at a time,
   right-padded to power-of-two buckets, on the SAME 4D-list/flash path
   ``generate()`` uses; the K/V rows are then flattened and scattered
@@ -70,7 +80,7 @@ Architecture (docs/serving.md has the full walkthrough):
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -153,6 +163,14 @@ _LOG = get_logger(__name__)
 _COUNTERS = ("requests_submitted", "requests_eos", "requests_length",
              "requests_cancelled", "requests_timeout", "requests_rejected",
              "requests_error", "prefills", "decode_steps",
+             # one decode step in flight (docs/serving.md#one-step-in-
+             # flight): steps dispatched while the step before had not
+             # been read (beside decode_steps: the share of steps the
+             # host path ran behind), and rows whose result was thrown
+             # away because the slot's request had left by the time it
+             # was read (the price of learning EOS, a cancel or a
+             # poisoned row one step late)
+             "decode_steps_overlapped", "decode_rows_dropped",
              "tokens_generated", "slots_quarantined",
              "requests_shed_pages",
              # multi-LoRA (docs/serving.md#multi-lora): submits whose
@@ -334,6 +352,19 @@ class _Active:
         self.shared_used = 0     # prefix-hit pages mapped at admission
         self.skip_first = False  # fully page-aligned hit (COW seam)
         self.finite_ok = True    # AND of every chunk's isfinite flag
+
+
+class _Flight(NamedTuple):
+    """One dispatched decode step whose result the host has not read:
+    the rows as dispatched (``(slot, rec)``: a row counts at commit only
+    if the slot still holds that record), the two device results (their
+    copies to the host started at dispatch) and the fault injector's
+    index of the decode call (None without one)."""
+
+    rows: List
+    nxt: jax.Array
+    finite: jax.Array
+    call: Optional[int]
 
 
 def _kth_largest(rows, k):
@@ -546,11 +577,27 @@ class InferenceEngine:
         if self._spec:
             self._window_h = np.zeros((n, self._spec), np.int32)
             self._wlen_h = np.ones(n, np.int32)
+        #: the plain decode path keeps ONE step in flight
+        #: (docs/serving.md#one-step-in-flight): the step dispatched
+        #: last tick, read and committed this tick AFTER the next step
+        #: went out, and the token vector that step returned, which the
+        #: next step takes its continuing slots' fed tokens from on the
+        #: device (its shape is the program's output: the routing counts
+        #: of a routed model ride behind the tokens, three a routed
+        #: layer). Speculation reads every step in the tick that
+        #: dispatched it: its draft is built from the host's tokens.
+        self._flight: Optional[_Flight] = None
+        if not self._spec:
+            routed_calls = (c.num_layers - c.num_dense_layers
+                            if self._routed else 0)
+            self._carry = jnp.zeros(n + 3 * routed_calls, jnp.int32)
         #: what one decode step uploads (the ``tick.upload`` span's
         #: attributes): the host arrays of ``_decode_args``
         up = [self._window_h if self._spec else self._tokens_h,
               self._positions_h, self._temps_h, self._topks_h,
               self._seeds_h, self._adapter_ix_h, self._page_table_h]
+        if not self._spec:
+            up.append(np.zeros(n, np.bool_))     # the take-the-host's mask
         self._decode_upload = (len(up), sum(a.nbytes for a in up))
 
         donate = self.config.donate_caches
@@ -606,12 +653,18 @@ class InferenceEngine:
         return nxt[:size].reshape(shape), nxt[size:].reshape(-1, 3)
 
     def _paged_decode_body(self, params, caches, page_table, tokens,
-                           positions, temps, topks, seeds, adapter_ix,
-                           lora):
+                           carry, from_host, positions, temps, topks,
+                           seeds, adapter_ix, lora):
         # one decode step over the page pool: one fused append+attend
         # per layer (apex_tpu.ops.decode_attention); with the pool
         # donated the appends are in-place row writes, so per step the
-        # KV traffic is one read of the mapped stream plus one row
+        # KV traffic is one read of the mapped stream plus one row.
+        # A slot with a row in the step before is fed that row's sampled
+        # token straight from ``carry``, the vector that step returned
+        # (tokens first), so the host need not have read it yet; the
+        # host's ``tokens`` feed the slots it wrote since (a prefill's
+        # first token, a cleared slot)
+        tokens = jnp.where(from_host, tokens, carry[:tokens.shape[0]])
         stats = self._routing(positions)
         logits, caches = decode_step(self.model, params, caches, tokens,
                                      positions, paged_state=page_table,
@@ -831,8 +884,9 @@ class InferenceEngine:
         wrap each body in ``shard_map`` over the tensor axis first.
         Every body that runs the model takes the caches as argument 1,
         so donation and the watchdogs are shared. With ``speculation``
-        on, the decode program is the windowed verify body (same arity:
-        the [n] token vector becomes the [n, k] window matrix)."""
+        on, the decode program is the windowed verify body: the [n]
+        token vector becomes the [n, k] window matrix, and the carried
+        token vector and its mask are not among its arguments."""
         donate_args = (1,) if donate else ()
         decode_body = (self._spec_decode_body if self._spec
                        else self._paged_decode_body)
@@ -943,7 +997,10 @@ class InferenceEngine:
     def inflight(self) -> List:
         """Snapshot of active (admitted, non-terminal) requests as
         ``(request, generated_tokens, submit_ts)`` tuples in slot order —
-        what the supervisor re-prefills after an engine restart.
+        what the supervisor re-prefills after an engine restart. The
+        tokens are the COMMITTED ones: a row still in flight is not
+        among them (whoever continues the request samples that token
+        again, bit for bit, from its ``(seed, position)``).
         Mid-chunked-prefill requests are included with NO tokens: a
         restart re-prefills them from the prompt through the same admit
         path (their chunk progress died with the engine's pages).
@@ -1054,9 +1111,11 @@ class InferenceEngine:
 
     def tick(self) -> List[RequestResult]:
         """One scheduler iteration: expire deadlines, evict cancellations,
-        admit+prefill FCFS (decode-starvation capped), then one batched
-        decode step over all active slots. Returns the requests that
-        reached a terminal state during this tick."""
+        admit+prefill FCFS (decode-starvation capped), then dispatch one
+        batched decode step over the active slots and read and commit
+        the step dispatched a tick ago (with speculation: this tick's).
+        Returns the requests that reached a terminal state during this
+        tick."""
         if self._closed:
             raise RuntimeError("engine is closed")
         finished: List[RequestResult] = []
@@ -1110,7 +1169,7 @@ class InferenceEngine:
         ids = [r.request_id for r in pending]
         ticks = 0
         while pending or self.scheduler.depth or self._active \
-                or self._prefilling:
+                or self._prefilling or self._flight is not None:
             while pending and \
                     self.scheduler.depth < self.config.scheduler.max_queue:
                 self.submit(pending.pop(0))
@@ -1140,6 +1199,10 @@ class InferenceEngine:
         if self._closed:
             return
         self._closed = True
+        if self._flight is not None:
+            # a step nobody will read: its rows are thrown away
+            self.metrics.inc("decode_rows_dropped", len(self._flight.rows))
+            self._flight = None
         self._active.clear()
         self._prefilling.clear()
         self._parked.clear()
@@ -1836,30 +1899,41 @@ class InferenceEngine:
                    for rec in self._active.values())
         return dead * self._window_share
 
-    def _dispatch_pages(self) -> dict:
+    def _dispatch_pages(self, slots, positions) -> dict:
         """``pages`` of the decode dispatch span: the page copies the
         decode kernel of ONE full-attention layer makes this step, the
-        width of every active slot's page range summed (a window layer
-        copies no more) — how much the kernel's page walk is asked to
-        do, beside ``rows``. Nothing unless a trace is being taken: no
+        width of every dispatched slot's page range summed (a window
+        layer copies no more) — how much the kernel's page walk is asked
+        to do, beside ``rows``. Nothing unless a trace is being taken: no
         tick pays for the sum otherwise."""
         if not recording():
             return {}
-        live = np.fromiter(self._active, np.intp, len(self._active))
         first, stop = paged_page_range(
-            self._positions_h[live], self._spec or 1, self.config.page_size)
+            positions[slots], self._spec or 1, self.config.page_size)
         return {"pages": int((np.minimum(stop, self.config.pages_per_slot)
                               - first).sum())}
 
-    def _decode_args(self) -> tuple:
-        """The decode program's arguments from the current host arrays
-        (the page table rides right after the pool; with speculation
-        the fed tokens are the ``[n, k]`` window matrix)."""
-        fed = (jnp.asarray(self._window_h) if self._spec
-               else jnp.asarray(self._tokens_h))
-        return (self._params, self._caches,
-                jnp.asarray(self._page_table_h), fed,
-                jnp.asarray(self._positions_h), jnp.asarray(self._temps_h),
+    def _decode_args(self, table=None, positions=None,
+                     from_host=None) -> tuple:
+        """The decode program's arguments (the page table rides right
+        after the pool). By default from the host arrays as they stand,
+        every fed token the host's; a step dispatched behind another
+        hands in its own view: ``table`` and ``positions`` with the
+        slots that sit the step out blanked and the others one row on,
+        ``from_host`` false where the fed token is the carried one. With
+        speculation the fed tokens are the ``[n, k]`` window matrix and
+        nothing is carried."""
+        if table is None:
+            table, positions = self._page_table_h, self._positions_h
+        if self._spec:
+            fed = (jnp.asarray(self._window_h),)
+        else:
+            if from_host is None:
+                from_host = np.ones(self.config.max_slots, np.bool_)
+            fed = (jnp.asarray(self._tokens_h), self._carry,
+                   jnp.asarray(from_host))
+        return (self._params, self._caches, jnp.asarray(table), *fed,
+                jnp.asarray(positions), jnp.asarray(self._temps_h),
                 jnp.asarray(self._topks_h), jnp.asarray(self._seeds_h),
                 jnp.asarray(self._adapter_ix_h), self._bank)
 
@@ -1873,33 +1947,107 @@ class InferenceEngine:
                 .compile().as_text())
 
     def _decode_tick(self, finished: List[RequestResult]) -> None:
+        """The decode half of a tick. Plain decode keeps one step in
+        flight: dispatch step k, built from what the host knows without
+        step k-1's tokens, THEN read and commit step k-1, so the host's
+        work on a tick runs while the device computes. A tick with a
+        step in flight and nothing to dispatch still commits it. With
+        speculation the step is read in the tick that dispatched it
+        (the draft needs the host's newest tokens)."""
+        step = self._dispatch_step(finished, self._flight)
+        if self._spec:
+            if step is not None:
+                self._commit_step(step, finished, lag=0)
+            return
+        ahead, self._flight = self._flight, step
+        if ahead is not None:
+            self._commit_step(ahead, finished, lag=int(step is not None))
+
+    def _dispatch_step(self, finished: List[RequestResult],
+                       ahead: Optional[_Flight]) -> Optional[_Flight]:
+        """Schedule, upload and dispatch one decode step behind
+        ``ahead`` (the step still unread, or None). A slot with a row in
+        ``ahead`` is one position further than the host has committed,
+        and its fed token is that row's, taken on the device. A slot
+        whose row in flight delivers its last token (``max_new_tokens``
+        reached, or the next position would be ``max_len``) sits the
+        step out: its finish is known without the token. Returns None
+        when no slot takes part."""
+        n = self.config.max_slots
         with span(TICK_SCHEDULE, active=len(self._active)) as sched:
+            lead = np.zeros(n, np.int32)    # rows in flight, slot by slot
+            if ahead is not None:
+                for slot, rec in ahead.rows:
+                    if self._active.get(slot) is rec:
+                        lead[slot] = 1
             if self._spec and self._active:
                 self._build_windows()
-            self._extend_pages(finished)
-            if not self._active:
-                return
+            now = clock.now()
+            rows = []
+            for slot in sorted(self._active):
+                rec = self._active[slot]
+                flying = int(lead[slot])
+                at = rec.position + flying
+                if len(rec.tokens) + flying >= rec.request.max_new_tokens \
+                        or at >= self.config.max_len:
+                    continue            # its row in flight is its last
+                # a speculative step appends K/V for the whole verify
+                # window (positions at..at+wl-1); wl is clipped to the
+                # request's max_new_tokens, so the target stays within
+                # the admission reservation
+                grow = int(self._wlen_h[slot]) if self._spec else 1
+                if self._extend_pages(rec, at + grow, now, finished):
+                    rows.append((slot, rec))
+            if not rows:
+                return None
+            call = None
             if self._faults is not None:
-                self._faults.before_decode()
+                call = self._faults.before_decode()
             # roofline gauge: bytes of KV stream one decode step
-            # reads (mapped pages of every active slot, dtype- and
+            # reads (mapped pages of every dispatched slot, dtype- and
             # sidecar-aware) — THE denominator speculation and int8
             # shrink
-            mapped = sum(len(self.pages.slot_pages(s))
-                         for s in self._active)
+            mapped = sum(len(self.pages.slot_pages(s)) for s, _ in rows)
             self.metrics.set_gauge("kv_bytes_per_step",
                                    mapped * self._page_read_bytes)
             sched.set_metadata(pages_mapped=mapped)
         with span(TICK_UPLOAD, arrays=self._decode_upload[0],
                   bytes=self._decode_upload[1]):
-            args = self._decode_args()
-        with span(TICK_DISPATCH, program="decode", rows=len(self._active),
-                  **self._dispatch_pages()):
+            slots = np.fromiter((s for s, _ in rows), np.intp, len(rows))
+            part = np.zeros(n, np.bool_)
+            part[slots] = True
+            table = np.where(part[:, None], self._page_table_h,
+                             np.int32(self.pages.n_pages))
+            positions = np.where(part, self._positions_h + lead,
+                                 np.int32(0))
+            args = self._decode_args(table, positions, lead == 0)
+        with span(TICK_DISPATCH, program="decode", rows=len(rows),
+                  in_flight=int(ahead is not None),
+                  **self._dispatch_pages(slots, positions)):
             nxt, finite, self._caches = self._decode_fn(*args)
+            # the copies to the host start now, so the read a tick
+            # later finds the results there
+            nxt.copy_to_host_async()
+            finite.copy_to_host_async()
         del args
-        with span(TICK_READBACK, reads=2) as back:
-            nxt = np.asarray(nxt)
-            finite = np.asarray(finite)
+        if not self._spec:
+            self._carry = nxt
+        self.metrics.inc("decode_steps")
+        if ahead is not None:
+            self.metrics.inc("decode_steps_overlapped")
+        self.metrics.observe("decode_batch_size", len(rows))
+        return _Flight(rows, nxt, finite, call)
+
+    def _commit_step(self, step: _Flight, finished: List[RequestResult],
+                     *, lag: int) -> None:
+        """Read ``step``'s results and commit its rows as dispatched. A
+        row whose slot no longer holds the record it was dispatched for
+        (expired, cancelled, parked, quarantined or retired since, EOS
+        learned a step late) is dropped and counted. ``lag``: decode
+        steps dispatched since this one."""
+        with span(TICK_READBACK, reads=2, lag=lag) as back:
+            nxt = np.asarray(step.nxt)
+            finite = np.asarray(step.finite)
             back.set_metadata(bytes=nxt.nbytes + finite.nbytes)
         with span(TICK_COMMIT) as commit:
             retired = len(finished)
@@ -1914,17 +2062,18 @@ class InferenceEngine:
                     self.metrics.observe("moe_max_expert_rows",
                                          int(busiest))
             if self._faults is not None:
-                nxt, finite = self._faults.corrupt_decode(nxt, finite)
-            self.metrics.inc("decode_steps")
-            self.metrics.observe("decode_batch_size", len(self._active))
+                nxt, finite = self._faults.corrupt_decode(
+                    nxt, finite, step.call)
             now = clock.now()
             if self._spec:
                 self._accept_windows(nxt, finite, now, finished)
                 commit.set_metadata(retired=len(finished) - retired)
                 return
-            emitted = 0
-            for slot in sorted(self._active):
-                rec = self._active[slot]
+            emitted = dropped = 0
+            for slot, rec in step.rows:
+                if self._active.get(slot) is not rec:
+                    dropped += 1
+                    continue
                 token = int(nxt[slot])
                 # integrity check, off the critical path: non-finite
                 # logits or an out-of-vocab token mean THIS row is
@@ -1945,7 +2094,9 @@ class InferenceEngine:
                 done = self._finish_reason(rec, token)
                 if done is not None:
                     finished.append(self._retire(rec, done, now))
-            commit.set_metadata(tokens=emitted,
+            if dropped:
+                self.metrics.inc("decode_rows_dropped", dropped)
+            commit.set_metadata(tokens=emitted, dropped=dropped,
                                 retired=len(finished) - retired)
 
     def _accept_windows(self, nxt, finite, now: float,
@@ -2003,38 +2154,33 @@ class InferenceEngine:
             if done is not None:
                 finished.append(self._retire(rec, done, now))
 
-    def _extend_pages(self, finished: List[RequestResult]) -> None:
-        """On-demand page growth before the decode step: every active
-        slot must have the page backing row ``position`` mapped (the
-        fused kernel appends there). Admission reserved each request's
-        worst case, so the extend cannot fail — the defensive branch
-        retires the slot as an error rather than corrupting a foreign
-        page, and counts the shed so the monitor surfaces it."""
-        now = clock.now()
-        for slot in sorted(self._active):
-            rec = self._active[slot]
-            # a speculative step appends K/V for the whole verify
-            # window (positions position..position+wl-1); wl is clipped
-            # to the request's max_new_tokens, so the target stays
-            # within the admission reservation
-            grow = int(self._wlen_h[slot]) if self._spec else 1
-            fresh = self.pages.extend_slot(slot, rec.position + grow)
-            if fresh is None:
-                self.metrics.inc("requests_shed_pages")
-                log_event(_LOG, "request_shed",
-                          request_id=rec.request.request_id,
-                          reason="pages_exhausted", mid_flight=True)
-                self.metrics.event("request_shed",
-                                   request_id=rec.request.request_id,
-                                   reason="pages_exhausted",
-                                   mid_flight=True)
-                finished.append(self._retire(rec, FINISH_ERROR, now))
-                continue
-            if fresh:
-                row = self._page_table_h[slot]
-                pages = self.pages.slot_pages(slot)
-                row[len(pages) - len(fresh):len(pages)] = fresh
-                self._reset_fresh_scales(fresh)
+    def _extend_pages(self, rec: _Active, rows: int, now: float,
+                      finished: List[RequestResult]) -> bool:
+        """On-demand page growth before the decode step: the slot must
+        have the pages backing its first ``rows`` rows mapped (the fused
+        kernel appends there). Admission reserved each request's worst
+        case, so the extend cannot fail — the defensive branch retires
+        the slot as an error rather than corrupting a foreign page,
+        counts the shed so the monitor surfaces it, and returns False."""
+        slot = rec.slot
+        fresh = self.pages.extend_slot(slot, rows)
+        if fresh is None:
+            self.metrics.inc("requests_shed_pages")
+            log_event(_LOG, "request_shed",
+                      request_id=rec.request.request_id,
+                      reason="pages_exhausted", mid_flight=True)
+            self.metrics.event("request_shed",
+                               request_id=rec.request.request_id,
+                               reason="pages_exhausted",
+                               mid_flight=True)
+            finished.append(self._retire(rec, FINISH_ERROR, now))
+            return False
+        if fresh:
+            row = self._page_table_h[slot]
+            pages = self.pages.slot_pages(slot)
+            row[len(pages) - len(fresh):len(pages)] = fresh
+            self._reset_fresh_scales(fresh)
+        return True
 
     # -- retirement & bookkeeping ----------------------------------------
 

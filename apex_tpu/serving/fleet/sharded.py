@@ -38,7 +38,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.serving.engine import EngineConfig, InferenceEngine
 from apex_tpu.transformer import parallel_state
@@ -78,6 +78,13 @@ class ShardedEngine(InferenceEngine):
         super().__init__(model, params, config, metrics=metrics,
                          faults=faults, replica_id=replica_id,
                          adapters=adapters)
+        if not self._spec:
+            # the carried token vector is a decode step's own output
+            # from the second step on: place the first one as the
+            # program will return it (replicated over the mesh), or the
+            # second call would meet another sharding and compile again
+            self._carry = jax.device_put(
+                self._carry, NamedSharding(self.mesh, P()))
 
     # -- sharding specs ---------------------------------------------------
 
@@ -146,14 +153,16 @@ class ShardedEngine(InferenceEngine):
         cspec = self._cache_spec()
         rep = P()
         lspec = self._lora_spec()
-        # the speculative verify body has the SAME arity as the plain
-        # one — the [n] token vector becomes the [n, k] window matrix,
-        # still replicated — so the spec structure is unchanged
+        # the plain body takes the fed tokens as three replicated
+        # arguments (the host's vector, the carried one of the step
+        # before, the mask that chooses between them); the speculative
+        # verify body takes one, the [n, k] window matrix
         decode_body = (self._spec_decode_body if self._spec
                        else self._paged_decode_body)
+        fed = (rep,) if self._spec else (rep, rep, rep)
         decode = shard_map(
             decode_body, mesh=mesh,
-            in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
+            in_specs=(pspec, cspec, rep, *fed, rep, rep, rep, rep,
                       rep, lspec),
             out_specs=(rep, rep, cspec))
         prefill = shard_map(

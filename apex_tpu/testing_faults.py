@@ -271,8 +271,10 @@ class ServingFaultInjector:
     convention).
 
     Args:
-      poison_decode: ``{decode_call: (slot, kind)}`` — corrupt the decode
-        OUTPUT for one slot after the jitted step returns. ``kind``
+      poison_decode: ``{decode_call: (slot, kind)}`` — corrupt that
+        decode call's OUTPUT for one slot as the host reads it (a tick
+        after the call went out: the engine keeps one step in flight).
+        ``kind``
         ``"nonfinite"`` clears the slot's in-jit ``isfinite`` flag (what
         NaN logits look like to the host); ``"oov"`` replaces the sampled
         token with an out-of-vocab id. Both drive the engine's
@@ -305,9 +307,11 @@ class ServingFaultInjector:
         self.log = []   # what actually fired, in order, for tests
 
     # -- engine hook points ------------------------------------------------
-    def before_decode(self) -> None:
+    def before_decode(self) -> int:
         """Called right before the jitted decode step; may sleep (hung
-        tick) or raise (decode failure)."""
+        tick) or raise (decode failure). Returns the index of this
+        decode call, which the engine hands back with the step's outputs
+        (it reads them a tick after the dispatch)."""
         call = self.decode_calls
         self.decode_calls += 1
         hang = self.decode_hang.get(call)
@@ -318,12 +322,14 @@ class ServingFaultInjector:
             self.log.append(("decode_raise", call))
             raise InjectedEngineFault(
                 f"injected decode failure at decode call {call}")
+        return call
 
-    def corrupt_decode(self, tokens: np.ndarray, finite: np.ndarray
-                       ) -> Tuple[np.ndarray, np.ndarray]:
-        """Called with the decode step's host-side outputs; returns the
-        (possibly corrupted) pair the engine's integrity check consumes."""
-        spec = self.poison_decode.get(self.decode_calls - 1)
+    def corrupt_decode(self, tokens: np.ndarray, finite: np.ndarray,
+                       call: int) -> Tuple[np.ndarray, np.ndarray]:
+        """Called with the host-side outputs of decode call ``call``;
+        returns the (possibly corrupted) pair the engine's integrity
+        check consumes."""
+        spec = self.poison_decode.get(call)
         if spec is not None:
             slot, kind = spec
             tokens = np.array(tokens)    # device views are read-only
@@ -332,7 +338,7 @@ class ServingFaultInjector:
                 finite[slot] = False
             else:
                 tokens[slot] = -1        # out-of-vocab sentinel
-            self.log.append(("poison", self.decode_calls - 1, slot, kind))
+            self.log.append(("poison", call, slot, kind))
         return tokens, finite
 
     def before_prefill(self) -> None:

@@ -182,6 +182,53 @@ def test_engine_serves_the_mixed_model_token_exact_in_float32(weights):
     # contexts pass the window of 8 by several pages of 8: 3 of the 4
     # layers are window layers
     assert max(seen) > 0 and max(seen) % 0.75 == 0
+    # one step in flight: the carried vector holds the routing counts
+    # behind the tokens and the program was compiled for it once; no
+    # request ends on EOS, so no row was thrown away
+    assert eng._carry.shape == (4 + 3 * 3,)
+    assert eng.decode_compiles == 1 and eng.decode_retraces == 0
+    assert counters["decode_steps_overlapped"] >= steps - 4
+    assert counters["decode_rows_dropped"] == 0
+
+
+def test_routed_streams_one_step_ahead_are_the_lock_step_engines(weights):
+    """The routed model with one decode step in flight (the fed token
+    sliced from in front of the routing counts of the step before)
+    against the same engine under ``speculation=2``, whose tick reads
+    every step it dispatches: sampled and greedy streams, one ending on
+    an EOS that the plain engine learns a step late."""
+    from apex_tpu.observability import MetricsRegistry
+    from apex_tpu.serving import (EngineConfig, InferenceEngine, Request,
+                                  SamplingParams)
+
+    _, tree = weights
+    rng = np.random.default_rng(1)
+    specs = [(rng.integers(0, 128, n).tolist(), m, s) for n, m, s in [
+        (6, 12, SamplingParams(temperature=0.9, top_k=20, seed=3)),
+        (14, 9, SamplingParams()),
+        (9, 10, SamplingParams(temperature=1.2, seed=8))]]
+
+    def serve(eos=None, **config):
+        reg = MetricsRegistry()
+        with InferenceEngine(_model(), tree, EngineConfig(
+                max_slots=4, max_len=64, page_size=8, **config),
+                metrics=reg) as eng:
+            out = eng.serve([Request(prompt=p, max_new_tokens=m, sampling=s,
+                                     eos_token=eos if i == 0 else None)
+                             for i, (p, m, s) in enumerate(specs)])
+            assert eng.decode_retraces == 0
+        return [r.tokens for r in out], reg.counters()
+
+    free, _ = serve(speculation=2)
+    n = next(i for i in range(1, 11) if free[0][i] not in free[0][:i])
+    want, lock_step = serve(free[0][n], speculation=2)
+    assert want[0] == free[0][:n + 1] and want[1:] == free[1:]
+    got, counters = serve(free[0][n])
+    assert got == want
+    assert lock_step["decode_steps_overlapped"] == 0
+    assert counters["decode_steps_overlapped"] > 0
+    assert counters["decode_rows_dropped"] == 1
+    assert counters["moe_rows_routed"] > 0
 
 
 def test_fp8_control_moves_the_logits_far_more_than_bf16(weights):
@@ -269,7 +316,10 @@ def _lowered_programs():
 #: function: the same text lowers to the same program, so the outputs of
 #: every model that existed are unchanged bit for bit under the new
 #: config fields' defaults. A PR that changes one of these programs on
-#: purpose reads the digests anew and says so.
+#: purpose reads the digests anew and says so: PR 33 gave the plain
+#: decode program the carried token vector and the mask that chooses
+#: between it and the host's tokens (``engine/paged-decode``, read anew
+#: there; the prefill programs and the spec-decode body kept theirs).
 PARENT_PROGRAMS = {
     "gpt2-style/init": "54950bef44af6471",
     "gpt2-style/loss": "eff5da79fceee869",
@@ -285,7 +335,7 @@ PARENT_PROGRAMS = {
     "bf16-recompute/generate": "1c6985bfac1e684d",
     "bert/forward": "35e3f4c7245fd3e6",
     "encoder-decoder/loss": "f2746609c8484d9e",
-    "engine/paged-decode": "2fda3dc55a57469f",
+    "engine/paged-decode": "59e792bd043da028",
     "engine/paged-prefill": "b33e75e42f8d0415",
     "engine/suffix-prefill": "90c81dffea2e0d87",
     "engine/spec-decode": "5d15c62e192aebbf",

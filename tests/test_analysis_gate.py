@@ -50,6 +50,10 @@ CHEAP_MODEL_TEST_MODULES = {
     # (45 s); ISSUE 30 asks for them in tier-1
     "test_afmoe.py",
     "test_routed_experts.py",
+    # PR 33: the in-flight decode scenarios on a 2-layer hidden-32 engine,
+    # tick by tick (18 tests, 57 s for the file); ISSUE 33 asks for them
+    # in tier-1
+    "test_decode_in_flight.py",
     # not a test module: the per-request reference the serving tests
     # import (one jitted prefill and one decode step a request)
     "serving_reference.py",

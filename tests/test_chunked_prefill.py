@@ -232,7 +232,7 @@ class TestMixedTicks:
         eng = InferenceEngine(model, params, cfg)
         try:
             eng.submit(short)
-            eng.tick()                      # short prefills + decodes
+            eng.tick()                      # short prefills, step 0 goes out
             eng.submit(long_p)
             progress = []
             while long_p.request_id not in eng.completed:

@@ -558,6 +558,29 @@ class TestShardedEngine:
             ShardedEngine(model, params,
                           EngineConfig(max_slots=2, max_len=16))
 
+    def test_tp2_two_tick_stream_feeds_the_carried_token(self, small,
+                                                          tp2_mesh):
+        """The sharded decode program takes the carried token vector and
+        its mask replicated: a stream of three tokens (the prefill's,
+        then two decode ticks, the second step fed on the device from
+        the first) equals the per-request reference, greedy and sampled,
+        and the program compiled once: the first carried vector is
+        placed as the program returns the later ones."""
+        model, params = small
+        reqs = [Request(prompt=p, max_new_tokens=3, sampling=s)
+                for p, s in zip(_prompts([4, 6], seed=71), (
+                    SamplingParams(),
+                    SamplingParams(temperature=0.9, top_k=8, seed=5)))]
+        with ShardedEngine(model, params,
+                           EngineConfig(max_slots=2, max_len=16)) as eng:
+            out = eng.serve(reqs)
+            assert eng.decode_compiles == 1 and eng.decode_retraces == 0
+            counters = eng.metrics.counters()
+        for req, res in zip(reqs, out):
+            assert res.tokens == reference_stream(model, params, req, 16)
+        assert counters["decode_steps_overlapped"] >= 1
+        assert counters["decode_rows_dropped"] == 0
+
     @pytest.mark.slow  # TP model parity: the slow-tier class (ROADMAP)
     def test_tp2_token_exact_vs_unsharded(self, small, tp2_mesh):
         """Acceptance: ShardedEngine decode on a tp=2 CPU mesh is

@@ -6,8 +6,9 @@ scope names inside the step programs (``nvtx_range`` =
 A tiny engine is ticked under ``jax.profiler.start_trace`` on the CPU and
 the trace file is read back: every ``engine.tick`` holds the five leaf
 spans in order, disjoint, with their attributes, and a prefill carries its
-request's ``trace_id``. With no profiler on, a tick writes nothing new
-into the registry. The compiled text of a decode program, a prefill
+request's ``trace_id``; a decode dispatch says whether a step was in
+flight ahead of it, a read-back how many went out behind the step it
+reads. With no profiler on, a tick writes nothing new into the registry. The compiled text of a decode program, a prefill
 program and a sharded train step carries every scope name in some
 ``op_name``. Token streams do not change under the profiler.
 """
@@ -140,14 +141,22 @@ def test_every_tick_holds_the_leaf_spans_disjoint_and_in_order(traced_ticks):
     (tracing.TICK_SCHEDULE, {"active", "pages_mapped"}),
     (tracing.TICK_UPLOAD, {"arrays", "bytes"}),
     (tracing.TICK_DISPATCH, {"program", "rows"}),
+    (tracing.TICK_DISPATCH, {"program", "rows", "in_flight"}),
     (tracing.TICK_READBACK, {"reads", "bytes"}),
+    (tracing.TICK_READBACK, {"reads", "bytes", "lag"}),
     (tracing.TICK_COMMIT, {"tokens", "retired"}),
+    (tracing.TICK_COMMIT, {"tokens", "dropped", "retired"}),
 ])
 def test_leaf_spans_carry_their_counts(traced_ticks, name, keys):
     _, results, events = traced_ticks
     mine = [e for e in events if e[0] == name and keys <= set(e[3])]
     assert mine, f"no {name} span with {sorted(keys)}"
-    if name == tracing.TICK_DISPATCH:
+    if name == tracing.TICK_DISPATCH and "in_flight" in keys:
+        # one decode step in flight: only the first step of the run goes
+        # out with nothing ahead of it
+        assert [int(e[3]["in_flight"]) for e in mine] \
+            == [0] + [1] * (len(mine) - 1)
+    elif name == tracing.TICK_DISPATCH:
         assert {e[3]["program"] for e in mine} == {"decode", "paged_prefill"}
         # a decode step says how many pages its kernel walks: at least
         # one a row, never more than the rows' whole tables
@@ -156,7 +165,17 @@ def test_leaf_spans_carry_their_counts(traced_ticks, name, keys):
                 rows = int(e[3]["rows"])
                 assert rows <= int(e[3]["pages"]) \
                     <= rows * ENGINE.pages_per_slot
-    if name == tracing.TICK_COMMIT:
+    if name == tracing.TICK_READBACK and "lag" in keys:
+        # a step is read a tick after its dispatch, behind the next
+        # step's; the run's last step has none behind it
+        lags = [int(e[3]["lag"]) for e in mine]
+        assert lags[-1] == 0 and set(lags[:-1]) == {1}
+        steps = [e for e in events if e[0] == tracing.TICK_DISPATCH
+                 and e[3]["program"] == "decode"]
+        assert len(lags) == len(steps)
+    if name == tracing.TICK_COMMIT and "dropped" in keys:
+        assert sum(int(e[3]["dropped"]) for e in mine) == 0   # no EOS here
+    elif name == tracing.TICK_COMMIT:
         # the spans count the tokens the requests got, and each
         # retirement once
         commits = [e[3] for e in events if e[0] == name]
@@ -230,17 +249,20 @@ class _Recording(MetricsRegistry):
 
 def test_a_tick_writes_nothing_new_into_the_registry(small):
     """What a decode tick wrote before the spans existed is what it
-    writes now: no ``span/*`` histogram, no per-span counter."""
+    writes now, and one counter for the step that went out behind
+    another: no ``span/*`` histogram, no per-span counter."""
     model, params = small
     reg = _Recording()
     engine = InferenceEngine(model, params, ENGINE, metrics=reg)
     engine.submit(_requests()[0])
-    engine.tick()
+    engine.tick()                       # prefill, and step 0 goes out
     reg.writes.clear()
-    engine.tick()                       # one request decoding, no prefill
+    engine.tick()                       # step 1 goes out, step 0 is read
+    writes = sorted(reg.writes)
     engine.close()
-    assert sorted(reg.writes) == sorted([
+    assert writes == sorted([
         ("set_gauge", "kv_bytes_per_step"), ("inc", "decode_steps"),
+        ("inc", "decode_steps_overlapped"),
         ("observe", "decode_batch_size"), ("inc", "tokens_generated"),
         ("observe", "slot_occupancy"), ("set_gauge", "kv_pages_in_use"),
         ("set_gauge", "kv_pages_free"), ("observe", "kv_page_occupancy")])
