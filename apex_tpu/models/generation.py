@@ -47,6 +47,8 @@ def flatten_decode_caches(caches, num_layers: int):
     per-layer list of 4D ``(k, v)`` pairs."""
 
     def fl(x):
+        if x.ndim == 3:           # latent rows: flat as they are made
+            return x
         b, h, s, d = x.shape
         return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
@@ -77,6 +79,14 @@ def preslice_layer_params(params, num_layers: int):
     return params
 
 
+def _latent_widths(config):
+    """Minor dims of a latent-attention layer's two caches: the latent
+    ``c`` and the shared rotary key on whole lane tiles."""
+    from apex_tpu.ops.decode_attention import latent_rope_lanes
+
+    return (config.kv_lora_rank, latent_rope_lanes(config.qk_rope_head_dim))
+
+
 def init_kv_caches(model, batch_size: int, max_len: int,
                    dtype=None, *, stacked: bool = True, flat: bool = False):
     """Preallocate K/V caches. ``stacked=True`` (default): ``(k, v)``, each
@@ -90,7 +100,9 @@ def init_kv_caches(model, batch_size: int, max_len: int,
     fastest decode form (the 4D carry's minor dim is head_dim = half a
     128-lane tile, so XLA pads the cache 2x and reads it at ~50% HBM
     bandwidth; the flat minor dim stays full-lane — PERF.md round 5).
-    ``generate()`` uses the flat list form.
+    ``generate()`` uses the flat list form. A latent-attention model
+    (``kv_lora_rank``) has the list form only, each entry the flat ``(c
+    [batch, max_len, rank], kR [batch, max_len, lanes])`` pair.
 
     Heads are K/V heads (``config.kv_heads``), which under GQA/MQA is
     ``num_query_groups``, not the query head count. Inside ``shard_map``
@@ -101,6 +113,15 @@ def init_kv_caches(model, batch_size: int, max_len: int,
 
     c = model.config
     dtype = dtype or c.compute_dtype
+    if c.latent_attention:
+        # one row a token, no head axis: (c, kR) per layer, flat already
+        if stacked:
+            raise ValueError(
+                "latent attention (kv_lora_rank) caches per-layer LIST "
+                "entries: init_kv_caches(stacked=False)")
+        return [tuple(jnp.zeros((batch_size, max_len, w), dtype)
+                      for w in _latent_widths(c))
+                for _ in range(c.num_layers)]
     heads = c.kv_heads                     # == query heads unless GQA/MQA
     if axis_bound(c.axis_name):
         tp = axis_size(c.axis_name)
@@ -139,11 +160,24 @@ def init_paged_kv_caches(model, n_pages: int, page_size: int, dtype=None,
     docs/serving.md#kv-quantization): pools are int8 and each of k/v
     nests as a ``(pages, scales)`` pair, ``scales`` the per-(page,
     kv-head) float32 sidecar ``[n_pages, local_kv_heads]`` the fused
-    decode op dequantizes from — halving the decode-step HBM stream."""
+    decode op dequantizes from — halving the decode-step HBM stream.
+
+    A latent-attention model's pair is ``(c pool [n_pages, page_size,
+    kv_lora_rank], kR pool [n_pages, page_size, lanes])``, one shared row
+    a token, bf16 only (docs/serving.md#latent-kv)."""
     from apex_tpu.transformer.tensor_parallel.mappings import axis_bound
 
     c = model.config
     dtype = dtype or c.compute_dtype
+    if c.latent_attention:
+        # (c pool, kR pool): docs/serving.md#latent-kv
+        if quantized:
+            raise ValueError(
+                "kv_dtype='int8' quantizes a page per KV head; latent "
+                "attention (kv_lora_rank) rows have no head axis")
+        return [tuple(jnp.zeros((n_pages, page_size, w), dtype)
+                      for w in _latent_widths(c))
+                for _ in range(c.num_layers)]
     heads = c.kv_heads
     if axis_bound(c.axis_name):
         tp = axis_size(c.axis_name)

@@ -34,7 +34,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from apex_tpu.observability.tracing import SCOPE_ATTENTION, SCOPE_MLP
+from apex_tpu.observability.tracing import (SCOPE_ATTENTION, SCOPE_MLA,
+                                            SCOPE_MLA_ABSORB,
+                                            SCOPE_MLA_PREFILL, SCOPE_MLP)
+from apex_tpu.ops.attention import flash_chunk_fwd
+from apex_tpu.ops.rope import (YarnScaling, fused_rope_cached,
+                               yarn_inv_freq, yarn_mscale)
 from apex_tpu.ops import (
     flash_attention,
     flash_attention_packed,
@@ -66,6 +71,7 @@ __all__ = [
     "TransformerConfig",
     "ParallelMLP",
     "ParallelAttention",
+    "LatentAttention",
     "ParallelTransformerLayer",
     "ParallelTransformer",
 ]
@@ -165,6 +171,24 @@ class TransformerConfig:
     num_shared_experts: int = 0
     routed_expert_range: Optional[Tuple[int, int]] = None   # held here
     num_dense_layers: int = 0
+    # group-limited selection (DeepSeek-V3's noaux_tc): the experts in
+    # `routed_num_groups` consecutive groups, a group's score the sum of
+    # its two best, the top-k taken inside the best `routed_topk_groups`
+    routed_num_groups: int = 1
+    routed_topk_groups: int = 1
+    # -- latent attention (MLA, docs/serving.md#latent-kv): set
+    # -- `kv_lora_rank` and every layer's attention is LatentAttention:
+    # low-rank q and kv projections with their RMSNorms, `qk_rope_head_dim`
+    # rotary channels shared by all heads, and a cache of one row of
+    # `kv_lora_rank + qk_rope_head_dim` values a token (no head axis)
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # YaRN frequencies for the rotary channels of latent attention, and
+    # its share in the softmax scale (ops/rope.py)
+    rope_yarn: Optional[YarnScaling] = None
 
     def __post_init__(self):
         if self.position_embedding_type not in ("learned", "rope", "none"):
@@ -222,10 +246,46 @@ class TransformerConfig:
                 raise ValueError(
                     f"num_dense_layers ({self.num_dense_layers}) must lie "
                     f"in 0..num_layers ({self.num_layers})")
+        if self.latent_attention:
+            sizes = ("q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                     "v_head_dim")
+            missing = [k for k in sizes if not getattr(self, k)]
+            if missing:
+                raise ValueError(
+                    f"latent attention (kv_lora_rank) needs {missing}")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"qk_rope_head_dim ({self.qk_rope_head_dim}) must be "
+                    f"even: rotary channels come in pairs")
+            if self.position_embedding_type != "rope":
+                raise ValueError(
+                    "latent attention carries its positions in the shared "
+                    "rotary key: position_embedding_type must be 'rope'")
+            for key, off in (("attention_layer_types", None),
+                             ("sliding_window", None),
+                             ("num_query_groups", None),
+                             ("qk_layernorm", False),
+                             ("attention_output_gate", False),
+                             ("context_parallel_method", None),
+                             ("sequence_parallel", False)):
+                if getattr(self, key) != off:
+                    raise ValueError(
+                        f"latent attention (kv_lora_rank) does not take "
+                        f"{key}")
+            if self.attn_mask_type != AttnMaskType.causal:
+                raise ValueError("latent attention is causal only")
+        elif self.rope_yarn is not None:
+            raise ValueError(
+                "rope_yarn scales the rotary channels of latent attention "
+                "(kv_lora_rank); no other attention kind reads it")
 
     @property
     def ffn_size(self) -> int:
         return self.ffn_hidden_size or 4 * self.hidden_size
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
 
     @property
     def head_dim(self) -> int:
@@ -1059,6 +1119,216 @@ class ParallelAttention:
 
 
 @dataclass
+class LatentAttention:
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA), causal.
+
+    Per token: ``cq = N(x W_DQ)``, ``[qC_i ; qR_i] = cq W_UQ`` a head;
+    ``[c ; kR] = x W_DKV``, ``c = N(c)``, ``kR = RoPE(kR)`` ONE for all
+    heads; ``[kC_i ; v_i] = c [W_UK_i ; W_UV_i]``; ``q_i = [qC_i ;
+    RoPE(qR_i)]``, ``k_i = [kC_i ; kR]``; softmax over ``q_i . k_j *
+    scale``, ``scale = (nope + rope)^-0.5 * mscale^2`` (YaRN's share).
+    What a token leaves in the cache is ``c`` and ``kR``: ``kv_lora_rank +
+    qk_rope_head_dim`` values, no head axis (docs/serving.md#latent-kv).
+
+    Two forms of the same attention. EXPANDED: ``k_i``, ``v_i`` made from
+    the rows, flash attention at q/k head size ``nope + rope`` and v head
+    size ``v_head_dim``: a forward with no cache and a whole-prompt
+    prefill. ABSORBED: ``qL_i = qC_i W_UK_i`` (the query in the latent's
+    coordinates), ``score = qL_i . c_j + RoPE(qR_i) . kR_j``, ``oL_i =
+    sum_j p_j c_j``, ``o_i = oL_i W_UV_i``: every step that attends over
+    cached rows (a chunk or a suffix of a prompt, a decode step), since
+    the rows are then read as they lie and nothing a head wide is made
+    from them. Parameters: ``q_down``, ``q_up``, ``kv_down``, ``dense``
+    ``[out, in]``; ``k_up`` ``[heads, nope, rank]`` and ``v_up``
+    ``[heads, v, rank]`` apart, as the absorbed form reads them.
+    """
+
+    config: TransformerConfig
+
+    def __post_init__(self):
+        c = self.config
+        self.heads, self.rank = c.num_attention_heads, c.kv_lora_rank
+        self.nope, self.rot = c.qk_nope_head_dim, c.qk_rope_head_dim
+        self.scale = float(self.nope + self.rot) ** -0.5
+        if c.rope_yarn is None:
+            self.inv_freq, self.rot_scale = (
+                1.0 / c.rope_theta ** (jnp.arange(
+                    0, self.rot, 2, dtype=jnp.float32) / self.rot), 1.0)
+        else:
+            self.inv_freq, self.rot_scale = yarn_inv_freq(
+                self.rot, c.rope_theta, c.rope_yarn)
+            if c.rope_yarn.mscale_all_dim:
+                self.scale *= yarn_mscale(c.rope_yarn.factor,
+                                          c.rope_yarn.mscale_all_dim) ** 2
+
+    def init(self, key):
+        c = self.config
+        h, dt = c.hidden_size, c.params_dtype
+        keys = iter(jax.random.split(key, 7))
+
+        def w(shape, init=None):
+            return {"weight": (init or c.init_method())(next(keys), shape,
+                                                        dt)}
+
+        return {
+            "q_down": w((c.q_lora_rank, h)),
+            "q_layernorm": {"weight": jnp.ones((c.q_lora_rank,), dt)},
+            "q_up": w((self.heads * (self.nope + self.rot), c.q_lora_rank)),
+            "kv_down": w((self.rank + self.rot, h)),
+            "kv_layernorm": {"weight": jnp.ones((self.rank,), dt)},
+            "k_up": w((self.heads, self.nope, self.rank)),
+            "v_up": w((self.heads, c.v_head_dim, self.rank)),
+            "dense": w((h, self.heads * c.v_head_dim),
+                       c.output_init_method()),
+        }
+
+    def spec(self):
+        return {name: {"weight": PartitionSpec()} for name in (
+            "q_down", "q_layernorm", "q_up", "kv_down", "kv_layernorm",
+            "k_up", "v_up", "dense")}
+
+    def _rotate(self, t, start):
+        """Rotary positions ``start .. start + s`` (``start`` a scalar or
+        a ``[b]`` vector of per-row offsets) on ``t [s, b, n, rot]``,
+        rotate-half, in float32."""
+        pos = jnp.arange(t.shape[0], dtype=jnp.float32)[:, None] \
+            + jnp.asarray(start, jnp.float32).reshape(1, -1)      # [s, b|1]
+        f = pos[..., None] * jnp.asarray(self.inv_freq)[None, None, :]
+        f = jnp.concatenate([f, f], axis=-1)[:, :, None, :]
+        return fused_rope_cached(t, jnp.cos(f) * self.rot_scale,
+                                 jnp.sin(f) * self.rot_scale)
+
+    def _expanded(self, params, qc, qr, c, kr):
+        """Causal attention of ``s`` fresh tokens over themselves, K and V
+        made from their rows: ``[s, b, heads * v]``."""
+        s, b = qc.shape[:2]
+        k_up = params["k_up"]["weight"].astype(c.dtype)
+        v_up = params["v_up"]["weight"].astype(c.dtype)
+        kc = jnp.einsum("sbr,hnr->bhsn", c, k_up)
+        v = jnp.einsum("sbr,hdr->bhsd", c, v_up)
+        k = jnp.concatenate([kc, jnp.broadcast_to(
+            kr.transpose(1, 0, 2)[:, None], kc.shape[:3] + (self.rot,))], -1)
+        q = jnp.concatenate([qc, qr], -1).transpose(1, 2, 0, 3)
+        o, _ = flash_chunk_fwd(q, k, v, q_start=0, k_start=0, causal=True,
+                               softmax_scale=self.scale,
+                               name=SCOPE_MLA_PREFILL)
+        return o.transpose(2, 0, 1, 3).reshape(s, b, -1)
+
+    def _absorb_q(self, params, qc):
+        with nvtx_range(SCOPE_MLA_ABSORB):
+            return jnp.einsum("sbhn,hnr->sbhr", qc,
+                              params["k_up"]["weight"].astype(qc.dtype))
+
+    def _absorb_o(self, params, o_latent):
+        """``[s, b, heads, rank]`` -> ``[s, b, heads * v]``."""
+        with nvtx_range(SCOPE_MLA_ABSORB):
+            o = jnp.einsum("sbhr,hdr->sbhd", o_latent,
+                           params["v_up"]["weight"].astype(o_latent.dtype))
+        return o.reshape(*o.shape[:2], -1)
+
+    def _over_cached_rows(self, params, qc, qr, rows_c, rows_kr, start):
+        """Absorbed causal attention of ``s`` queries at positions ``start
+        ..`` over the flat cached rows ``[b, S, rank]`` / ``[b, S,
+        lanes]`` (their own rows written): one shared K/V "head" of
+        ``rank + lanes`` / ``rank`` for all query heads."""
+        lanes = rows_kr.shape[-1]
+        ql = self._absorb_q(params, qc)
+        q = jnp.concatenate(
+            [ql, jnp.pad(qr, ((0, 0),) * 3 + ((0, lanes - self.rot),))],
+            -1).transpose(1, 2, 0, 3)
+        k = jnp.concatenate([rows_c, rows_kr], -1)[:, None].astype(q.dtype)
+        o, _ = flash_chunk_fwd(q, k, rows_c[:, None].astype(q.dtype),
+                               q_start=start, k_start=0, causal=True,
+                               softmax_scale=self.scale, block_q=512,
+                               block_k=512, name=SCOPE_MLA_PREFILL)
+        return self._absorb_o(params, o.transpose(2, 0, 1, 3))
+
+    @nvtx_range(SCOPE_MLA)
+    def apply(self, params, hidden, *, attention_mask=None, kv_lengths=None,
+              kv_cache=None, cache_index=None, rng=None, deterministic=True,
+              dropout_seed=None, paged_state=None, lora=None):
+        """``hidden [s, b, h] -> [s, b, h]``, or ``(out, new_cache)`` with
+        ``kv_cache = (c rows, kR rows)``: flat ``[b, S, rank]`` / ``[b, S,
+        lanes]`` with a scalar ``cache_index`` (0: a whole prompt or a
+        prompt's first chunk, expanded; else a chunk over the rows before
+        it, absorbed), or the two page pools with ``paged_state`` and the
+        ``[b]`` position vector (one token a slot, the absorbed decode
+        kernel)."""
+        c = self.config
+        del rng, dropout_seed
+        if attention_mask is not None or kv_lengths is not None:
+            raise NotImplementedError(
+                "latent attention is causal over whole sequences: "
+                "attention_mask / kv_lengths are not supported")
+        if lora is not None:
+            raise ValueError(
+                "LoRA adapters target the fused query_key_value "
+                "projection, which latent attention (kv_lora_rank) does "
+                "not have")
+        if not deterministic and c.attention_dropout > 0.0:
+            raise NotImplementedError(
+                "latent attention has no attention dropout")
+        s, b = hidden.shape[:2]
+        eps = c.layernorm_epsilon
+
+        def mm(x, name):
+            return jnp.matmul(x, params[name]["weight"].T.astype(x.dtype))
+
+        cq = _head_rms(mm(hidden, "q_down"),
+                       params["q_layernorm"]["weight"], eps)
+        q = mm(cq, "q_up").reshape(s, b, self.heads, self.nope + self.rot)
+        down = mm(hidden, "kv_down")
+        row_c = _head_rms(down[..., :self.rank],
+                          params["kv_layernorm"]["weight"], eps)
+        start = 0 if cache_index is None else cache_index
+        qc, qr = q[..., :self.nope], self._rotate(q[..., self.nope:], start)
+        row_kr = self._rotate(down[..., None, self.rank:], start)[:, :, 0]
+
+        if kv_cache is None:
+            return mm(self._expanded(params, qc, qr, row_c, row_kr), "dense")
+        rows_c, rows_kr = kv_cache
+        if paged_state is not None:
+            if s != 1:
+                raise ValueError(
+                    "the latent decode kernel takes one token a slot: a "
+                    "speculation window (s > 1) over latent attention is "
+                    "not supported")
+            from apex_tpu.ops.decode_attention import (
+                fused_latent_decode_attention)
+            ql = self._absorb_q(params, qc)
+            o_latent, rows_c, rows_kr = fused_latent_decode_attention(
+                ql[0], qr[0], row_c[0], row_kr[0], rows_c, rows_kr,
+                paged_state, cache_index, softmax_scale=self.scale)
+            return (mm(self._absorb_o(params, o_latent[None]), "dense"),
+                    (rows_c, rows_kr))
+        if getattr(cache_index, "ndim", 0) == 1:
+            raise NotImplementedError(
+                "latent attention over a flat cache takes ONE offset for "
+                "the batch; per-row offsets are the paged form's")
+        lanes = rows_kr.shape[-1]
+        rows_c = lax.dynamic_update_slice(
+            rows_c, row_c.transpose(1, 0, 2).astype(rows_c.dtype),
+            (0, cache_index, 0))
+        rows_kr = lax.dynamic_update_slice(
+            rows_kr, jnp.pad(row_kr, ((0, 0), (0, 0), (0, lanes - self.rot))
+                             ).transpose(1, 0, 2).astype(rows_kr.dtype),
+            (0, cache_index, 0))
+        if isinstance(cache_index, int) and cache_index == 0:
+            ctx = self._expanded(params, qc, qr, row_c, row_kr)
+        else:
+            # a chunk that opens its prompt attends to nothing cached: it
+            # is a whole-prompt prefill, whatever program it rides in (the
+            # expanded form multiplies 320 values a pair where the
+            # absorbed one multiplies 1,088)
+            ctx = lax.cond(
+                cache_index == 0,
+                lambda: self._expanded(params, qc, qr, row_c, row_kr),
+                lambda: self._over_cached_rows(params, qc, qr, rows_c,
+                                               rows_kr, cache_index))
+        return mm(ctx, "dense"), (rows_c, rows_kr)
+
+
+@dataclass
 class ParallelTransformerLayer:
     """Pre-LN block: ln -> attn -> add -> ln -> mlp -> add.
 
@@ -1077,7 +1347,9 @@ class ParallelTransformerLayer:
 
     def __post_init__(self):
         c = self.config
-        self.attention = ParallelAttention(c, layer_kind=self.layer_kind[0])
+        self.attention = (
+            LatentAttention(c) if c.latent_attention
+            else ParallelAttention(c, layer_kind=self.layer_kind[0]))
         self.routed = self.layer_kind[1] == "routed"
         if self.layer_type == LayerType.decoder:
             # decoder blocks add cross-attention over the encoder output
@@ -1096,6 +1368,8 @@ class ParallelTransformerLayer:
                 route_scale=c.route_scale,
                 num_shared_experts=c.num_shared_experts,
                 expert_range=c.routed_expert_range,
+                num_groups=c.routed_num_groups,
+                topk_groups=c.routed_topk_groups,
                 params_dtype=c.params_dtype,
                 compute_dtype=c.compute_dtype,
                 init_method_std=c.init_method_std))

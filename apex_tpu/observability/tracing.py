@@ -86,6 +86,13 @@ SCOPE_MOE_EXPERTS = "moe_experts"               # ops/grouped_matmul.py: the
 #                                                 routed products (Pallas
 #                                                 calls moe_experts_up/_down)
 SCOPE_MOE_SHARED = "moe_shared"                 # the shared expert
+SCOPE_MLA = "mla"                               # models/transformer.py: a
+#                                                 whole latent-attention block
+SCOPE_MLA_ABSORB = "mla_absorb"                 # qL = qC W_UK, o = oL W_UV
+SCOPE_MLA_DECODE = "mla_decode_attention"       # ops/decode_attention.py:
+#                                                 scope and Pallas call alike
+SCOPE_MLA_PREFILL = "mla_prefill_attention"     # the flash call of a prefill
+#                                                 or a chunk (Pallas name)
 
 
 @contextlib.contextmanager

@@ -217,9 +217,11 @@ def _single_need_mask(causal, window, kv_lengths, skp, sk):
 
 
 def _run_fwd_single(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
-                    group, window, q_off, k_off):
+                    group, window, q_off, k_off,
+                    name="flash_attention_fwd_single"):
     """Single-block forward dispatch — see _fwd_single_kernel."""
     batch, heads, sqp, dp = q.shape
+    dv = v.shape[3]
     need_mask = _single_need_mask(causal, window, kv_lengths, k.shape[2], sk)
     kvl_spec = []
     args = [_offsets(q_off, k_off, sq, sk)]
@@ -234,20 +236,20 @@ def _run_fwd_single(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + kvl_spec + [
             pl.BlockSpec((1, 1, bq, dp), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, dp), lambda b, h: (b, h // group, 0, 0)),
-            pl.BlockSpec((1, 1, bk, dp), lambda b, h: (b, h // group, 0, 0)),
+            pl.BlockSpec((1, 1, bk, dv), lambda b, h: (b, h // group, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, dp), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch, heads, sqp, dp), q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, sqp, dv), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, sqp), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=pallas_interpret(),
-        name="flash_attention_fwd_single",
+        name=name,
     )(*args, q, k, v)
     return o, lse[:, :, 0, :]
 
@@ -337,20 +339,22 @@ def _offsets(q_off, k_off, sq, sk):
 
 
 def _run_fwd(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
-             group=1, window=None, q_off=None, k_off=None):
+             group=1, window=None, q_off=None, k_off=None, name=None):
     """q/k/v padded to block multiples; returns padded (o, lse). ``group``
     q heads share each K/V head (GQA/MQA): the K/V index maps divide the
     head coordinate, so grouped heads reread the same blocks instead of the
     caller materializing a broadcast copy in HBM. ``q_off``/``k_off``:
     global-position offsets (traced OK) — see :func:`_mask_block`."""
     batch, heads, sqp, dp = q.shape
-    skp = k.shape[2]
+    skp, dv = k.shape[2], v.shape[3]
     nq, nk = sqp // bq, skp // bk
     if nq == 1 and nk == 1:
         # whole problem fits one (bq, bk) tile: one-pass kernel, no
         # online-softmax machinery (see _fwd_single_kernel)
-        return _run_fwd_single(q, k, v, kv_lengths, scale, causal, sq, sk,
-                               bq, bk, group, window, q_off, k_off)
+        return _run_fwd_single(
+            q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk, group,
+            window, q_off, k_off,
+            **({} if name is None else {"name": name}))
     # banded grid for sliding windows with STATIC offsets (the plain flash
     # path): only the ~(window+bq)/bk k-blocks near the diagonal are walked,
     # making windowed attention O(s*window) in grid steps too, not just in
@@ -383,27 +387,27 @@ def _run_fwd(q, k, v, kv_lengths, scale, causal, sq, sk, bq, bk,
             pl.BlockSpec((1, 1, bq, dp), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, dp),
                          lambda b, h, i, j: (b, h // group, _kj(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, dp),
+            pl.BlockSpec((1, 1, bk, dv),
                          lambda b, h, i, j: (b, h // group, _kj(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, dp), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch, heads, sqp, dp), q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, sqp, dv), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, sqp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, dp), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=pallas_interpret(),
-        name="flash_attention_fwd",
+        name=name or "flash_attention_fwd",
     )(*args, q, k, v)
     return o, lse[:, :, 0, :]
 
@@ -1458,16 +1462,17 @@ def _run_bwd(q, k, v, do, lse, delta, kv_lengths, scale, causal,
 # ---------------------------------------------------------------------------
 
 def _pad_qkv(q, k, v, bq, bk):
-    sq, d = q.shape[2], q.shape[3]
-    sk = k.shape[2]
+    sq, sk = q.shape[2], k.shape[2]
     # head dim pads to a multiple of 64, not 128: Mosaic handles 64-lane
     # blocks, and the common head_dim=64 case halves kernel HBM traffic and
     # QK^T/PV FLOPs vs padding to 128 (measured ~20% faster fwd+bwd on v5e)
-    sqp, skp, dp = round_up(sq, bq), round_up(sk, bk), round_up(d, 64)
+    # v keeps a head size of its own (latent attention: qk 192, v 128);
+    # the forward kernels read it from v, the backward ones want one size
+    sqp, skp = round_up(sq, bq), round_up(sk, bk)
 
     def pad(x, sp):
         return jnp.pad(x, ((0, 0), (0, 0), (0, sp - x.shape[2]),
-                           (0, dp - d)))
+                           (0, round_up(x.shape[3], 64) - x.shape[3])))
     return pad(q, sqp), pad(k, skp), pad(v, skp)
 
 
@@ -1600,7 +1605,8 @@ def _chunk_reference_bwd(q, k, v, do, lse, delta, kv_lengths, scale,
 def flash_chunk_fwd(q, k, v, *, q_start, k_start, causal=False, window=None,
                     kv_lengths=None, softmax_scale=None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    name: Optional[str] = None):
     """One flash forward over a (q chunk, kv chunk) pair -> ``(o, lse)``.
 
     ``q_start``/``k_start`` (traced OK) place the chunks in GLOBAL sequence
@@ -1608,7 +1614,8 @@ def flash_chunk_fwd(q, k, v, *, q_start, k_start, causal=False, window=None,
     valid length) are exact across chunk boundaries; a chunk that is
     entirely in the causal future costs only grid overhead (every k-block
     is skipped) and returns ``lse = _LSE_PAD`` rows that merge with weight
-    zero."""
+    zero. ``v`` may have a head size of its own (``o`` then has it too);
+    ``name`` names the Pallas call in a device trace."""
     scale = float(softmax_scale if softmax_scale is not None
                   else 1.0 / np.sqrt(q.shape[-1]))
     if not use_pallas():
@@ -1623,8 +1630,8 @@ def flash_chunk_fwd(q, k, v, *, q_start, k_start, causal=False, window=None,
     qp, kp, vp = _pad_qkv(q, k, v, bq, bk)
     o, lse = _run_fwd(qp, kp, vp, kv_lengths, scale, causal, sq, sk, bq, bk,
                       group=group, window=window, q_off=q_start,
-                      k_off=k_start)
-    return o[:, :, :sq, :d], lse[:, :, :sq]
+                      k_off=k_start, name=name)
+    return o[:, :, :sq, :v.shape[3]], lse[:, :, :sq]
 
 
 @nvtx_range(SCOPE_FLASH_BWD)

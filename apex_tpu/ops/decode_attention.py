@@ -66,6 +66,11 @@ Layouts (see docs/serving.md#paged-kv):
   drop). Window rows past the table's span also clamp to the sentinel,
   so an over-long window can never corrupt the slot's own last page.
 
+A second kernel serves LATENT attention (:func:`fused_latent_decode_
+attention`, the end of this file): one shared row a token in a ``c`` and
+a ``kR`` pool, read by all query heads in the absorbed form, with the same
+page walk.
+
 Dispatch follows the repo convention (:mod:`apex_tpu.ops._support`):
 the Pallas kernel on TPU (or under ``APEX_TPU_FORCE_PALLAS=interpret``
 for CI parity), and a pure-``jnp`` reference elsewhere. The reference
@@ -92,13 +97,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.observability.tracing import SCOPE_PAGED_DECODE
-from apex_tpu.ops._support import cdiv, pallas_interpret, use_pallas
+from apex_tpu.observability.tracing import (SCOPE_MLA_DECODE,
+                                            SCOPE_PAGED_DECODE)
+from apex_tpu.ops._support import (cdiv, pallas_interpret, round_up,
+                                   use_pallas)
 from apex_tpu.utils.logging import log_event
 from apex_tpu.utils.profiling import nvtx_range
 
-__all__ = ["fused_paged_decode_attention", "paged_page_range",
-           "paged_pages_for", "paged_quant_fill", "paged_quant_scatter"]
+__all__ = ["fused_paged_decode_attention", "fused_latent_decode_attention",
+           "latent_rope_lanes", "paged_page_range", "paged_pages_for",
+           "paged_quant_fill", "paged_quant_scatter"]
 
 #: the masked-score floor the flat decode path uses — shared so paged
 #: and flat softmax see bitwise-identical masked entries
@@ -349,6 +357,131 @@ def _kernel_takes(pages) -> bool:
     return False
 
 
+class _PageWalk:
+    """The double-buffered walk over a slot's live pages that the decode
+    kernels share (one grid step a slot, the grid axis sequential).
+
+    ``pools`` pairs each pool left in HBM with its ``[2, pages_per_round *
+    page_size, lanes]`` VMEM buffer. A round's pages are copied by hand
+    (``make_async_copy`` from pool row ``page_table[slot, j]``) into one
+    of the two buffers of each pool, and every round starts the NEXT
+    round's copies into the other buffer before the kernel waits on its
+    own: the next round of this slot or, on a slot's last round, the
+    first round of the next slot that has one (``head_ref[r]`` is the
+    first slot ``>= r`` with a page to read, ``b`` if none), so the
+    copies of ``pages_per_round`` pages are always in flight and a slot's
+    first page never waits on an idle pipeline. Which buffer the next
+    slot starts in is carried across grid steps in ``parity_ref`` (SMEM).
+    A page outside ``[first, stop)`` is not copied; its buffer rows keep
+    an earlier round's page, maybe ANOTHER slot's, and a kernel that
+    multiplies weights by them zeroes them first (:meth:`wait_or_zero`:
+    a masked row's weight is 0, but ``0 x NaN`` is NaN on the MXU).
+    ``first_ref`` None: every slot's walk starts at its page 0."""
+
+    def __init__(self, pt_ref, first_ref, stop_ref, head_ref, pools, sems,
+                 parity_ref, *, page_size, pages_per_round):
+        self.pt_ref, self.first_ref = pt_ref, first_ref
+        self.stop_ref, self.head_ref = stop_ref, head_ref
+        self.pools, self.sems = pools, sems
+        self.parity_ref = parity_ref
+        self.page_size, self.pages_per_round = page_size, pages_per_round
+        self.r = pl.program_id(0)
+
+    def round_copies(self, slot, rnd, buf):
+        """``(whether it is made, [a copy a pool])`` for each page of round
+        ``rnd`` of ``slot`` into buffer ``buf``: built alike to start a
+        round and to wait on it."""
+        pages_per_slot = self.pt_ref.shape[1]
+        if self.first_ref is not None:
+            base = self.first_ref[slot] + rnd * self.pages_per_round
+        copies = []
+        for c in range(self.pages_per_round):
+            # (the index arithmetic each kernel was measured with, op for
+            # op: the walk traces to the kernels of PR 31 and PR 34)
+            j = (rnd * self.pages_per_round + c if self.first_ref is None
+                 else base + c)
+            page = self.pt_ref[slot, jnp.minimum(j, pages_per_slot - 1)]
+            rows = pl.ds(c * self.page_size, self.page_size)
+            copies.append((
+                j < self.stop_ref[slot],
+                [pltpu.make_async_copy(hbm.at[page], vmem.at[buf, rows],
+                                       self.sems.at[ix, buf])
+                 for ix, (hbm, vmem) in enumerate(self.pools)]))
+        return copies
+
+    def start_round(self, slot, rnd, buf):
+        """Start round ``rnd`` of ``slot`` (nothing if ``slot == b``)."""
+        b = self.pt_ref.shape[0]
+        for made, copies in self.round_copies(
+                jnp.minimum(slot, b - 1), rnd, buf):
+            @pl.when(jnp.logical_and(slot < b, made))
+            def _start():
+                for copy in copies:
+                    copy.start()
+
+    def start_call(self):
+        """On the call's first grid step: its first round."""
+        @pl.when(self.r == 0)
+        def _first_round_of_the_call():
+            self.parity_ref[0] = 0
+            self.start_round(self.head_ref[0], 0, 0)
+
+    def open(self):
+        """This slot's ``(first page, stop page, rounds)``."""
+        if self.first_ref is None:
+            first, stop = 0, self.stop_ref[self.r]
+            pages = stop
+        else:
+            first, stop = self.first_ref[self.r], self.stop_ref[self.r]
+            pages = stop - first
+        self.rounds = jax.lax.div(pages + (self.pages_per_round - 1),
+                                  self.pages_per_round)
+        self.parity = self.parity_ref[0]
+        return first, stop, self.rounds
+
+    def run(self, one_round):
+        """``one_round(i, buf, copies)`` for each round of this slot, the
+        round after it started first."""
+        r, rounds, parity = self.r, self.rounds, self.parity
+
+        def body(i, carry):
+            buf = jax.lax.rem(parity + i, 2)
+            last = i + 1 == rounds
+            self.start_round(jnp.where(last, self.head_ref[r + 1], r),
+                             jnp.where(last, 0, i + 1), 1 - buf)
+            one_round(i, buf, self.round_copies(r, i, buf))
+            return carry
+
+        jax.lax.fori_loop(0, rounds, body, 0)
+        self.parity_ref[0] = jax.lax.rem(parity + rounds, 2)
+
+    @staticmethod
+    def wait(copies, *pools):
+        """Wait on the copies of a round into the buffers of ``pools``."""
+        for made, of_pool in copies:
+            @pl.when(made)
+            def _arrived():
+                for pool in pools:
+                    of_pool[pool].wait()
+
+    def wait_or_zero(self, copies, buf, *pools):
+        """:meth:`wait`, and zero the buffer rows of the pages that were
+        not copied."""
+        for c, (made, of_pool) in enumerate(copies):
+            @pl.when(made)
+            def _arrived():
+                for pool in pools:
+                    of_pool[pool].wait()
+
+            @pl.when(jnp.logical_not(made))
+            def _no_stale_rows():
+                rows = pl.ds(c * self.page_size, self.page_size)
+                for pool in pools:
+                    vmem = self.pools[pool][1]
+                    vmem[buf, rows] = jnp.zeros(
+                        (self.page_size, vmem.shape[2]), vmem.dtype)
+
+
 def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
                    k_hbm, v_hbm, *rest, page_size, heads, window, quantized,
                    sliding_window, scale, pages_per_round):
@@ -358,19 +491,10 @@ def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
 
     Both pools stay in HBM; the page table, the positions, each slot's
     page range (:func:`paged_page_range`; an idle slot's is empty) and
-    ``head_ref`` (``head_ref[r]`` is the first slot ``>= r`` with a page
-    to read, ``b`` if none) are scalar-prefetched. A round's pages are
-    copied by hand (``make_async_copy`` from pool row ``page_table[r,
-    j]``) into one of two VMEM buffers each of K and V, and every round
-    starts the NEXT round's copies into the other buffer before it waits
-    on its own: the next round of this slot, or, on a slot's last round,
-    the first round of the next slot that has one — so the copies of
-    ``pages_per_round`` pages are always in flight and a slot's first
-    page never waits on an idle pipeline. Which buffer the next slot
-    starts in is carried across grid steps in SMEM (the grid axis is
-    sequential). The gather never exists as an array, a page outside
-    ``[first, stop)`` is neither copied nor multiplied, and a slot with
-    no page (an idle one) costs one empty grid step and writes zeros.
+    ``head_ref`` are scalar-prefetched, and :class:`_PageWalk` copies the
+    pages. The gather never exists as an array, a page outside ``[first,
+    stop)`` is neither copied nor multiplied, and a slot with no page (an
+    idle one) costs one empty grid step and writes zeros.
 
     Softmax is the standard flash recurrence over rounds (running max /
     normalizer / weighted accumulator in VMEM scratch), once per
@@ -401,51 +525,17 @@ def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
     else:
         o_ref, *scratch = rest
     kbuf, vbuf, sems, parity_ref, m_ref, l_ref, acc_ref = scratch
-    r = pl.program_id(0)
-    b, pages_per_slot = pt_ref.shape
+    walk = _PageWalk(pt_ref, first_ref, stop_ref, head_ref,
+                     [(k_hbm, kbuf), (v_hbm, vbuf)], sems, parity_ref,
+                     page_size=page_size, pages_per_round=pages_per_round)
     m = window * heads
     span = pages_per_round * page_size         # rows of one buffer
-
-    def round_copies(slot, rnd, buf):
-        """(whether it is made, K copy, V copy) for each page of round
-        ``rnd`` of ``slot`` into buffer ``buf`` — built alike to start
-        a round and to wait on it."""
-        base = first_ref[slot] + rnd * pages_per_round
-        copies = []
-        for c in range(pages_per_round):
-            j = base + c
-            page = pt_ref[slot, jnp.minimum(j, pages_per_slot - 1)]
-            rows = pl.ds(c * page_size, page_size)
-            copies.append((
-                j < stop_ref[slot],
-                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, rows],
-                                      sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, rows],
-                                      sems.at[1, buf])))
-        return copies
-
-    def start_round(slot, rnd, buf):
-        """Start round ``rnd`` of ``slot`` (nothing if ``slot == b``)."""
-        for made, k_copy, v_copy in round_copies(
-                jnp.minimum(slot, b - 1), rnd, buf):
-            @pl.when(jnp.logical_and(slot < b, made))
-            def _start():
-                k_copy.start()
-                v_copy.start()
-
-    @pl.when(r == 0)
-    def _first_round_of_the_call():
-        parity_ref[0] = 0
-        start_round(head_ref[0], 0, 0)
-
+    walk.start_call()
     m_ref[...] = jnp.full_like(m_ref, _NEG)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    pos = pos_ref[r]                  # first window row's append index
-    first, stop = first_ref[r], stop_ref[r]
-    rounds = jax.lax.div(stop - first + (pages_per_round - 1),
-                         pages_per_round)
-    parity = parity_ref[0]
+    pos = pos_ref[walk.r]             # first window row's append index
+    first, stop, _ = walk.open()
     col = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
     # the last row each query sees: window row t of each query (queries
     # are ordered [t, head]; a compare-and-add ladder, no vector integer
@@ -457,15 +547,9 @@ def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
                     jnp.zeros((m, 1), jnp.int32))
     newest = jnp.minimum(lim, stop * page_size - 1)
 
-    def one_round(i, carry):
-        buf = jax.lax.rem(parity + i, 2)
-        last = i + 1 == rounds
-        start_round(jnp.where(last, head_ref[r + 1], r),
-                    jnp.where(last, 0, i + 1), 1 - buf)
-        copies = round_copies(r, i, buf)
+    def one_round(i, buf, copies):
         base = first + i * pages_per_round
-        for made, k_copy, _ in copies:
-            pl.when(made)(k_copy.wait)
+        walk.wait(copies, 0)
         qb = q_ref[0]                                     # [m, f]
         kb = kbuf[buf].astype(qb.dtype)                   # [span, f]
         s_blk = jax.lax.dot_general(
@@ -504,23 +588,15 @@ def _decode_kernel(pt_ref, pos_ref, first_ref, stop_ref, head_ref, q_ref,
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         if quantized:
             p = p * per_column(vs_ref)
-        for c, (made, _, v_copy) in enumerate(copies):
-            pl.when(made)(v_copy.wait)
-
-            @pl.when(jnp.logical_not(made))
-            def _no_stale_rows():
-                vbuf[buf, pl.ds(c * page_size, page_size)] = jnp.zeros(
-                    (page_size, vbuf.shape[2]), vbuf.dtype)
+        walk.wait_or_zero(copies, buf, 1)
         vb = vbuf[buf].astype(qb.dtype)
         pv = jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # [m, f]
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
-        return carry
 
-    jax.lax.fori_loop(0, rounds, one_round, 0)
-    parity_ref[0] = jax.lax.rem(parity + rounds, 2)
+    walk.run(one_round)
     # l > 0 for every real window row: row `pos + t` itself is valid by
     # construction (garbage rows past the slot's window are normalized
     # over whatever survived the mask — the engine never reads them); a
@@ -713,3 +789,195 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
     if k_scales is None:
         return ctx, k_pages, v_pages
     return ctx, k_pages, v_pages, k_scales, v_scales
+
+
+# -- the latent (MLA) pool: one shared row a token, read by every head --------
+#
+# A latent-attention layer (models/transformer.py ``LatentAttention``)
+# caches ``c`` (``kv_lora_rank`` values, normed) and ``kR`` (the shared
+# rotary key, rotated) a token: no head axis. They live in TWO pools paged
+# by the one table, ``c`` ``[n_pages, page_size, rank]`` and ``kR``
+# ``[n_pages, page_size, latent_rope_lanes(rope)]`` (zero lanes behind the
+# rotary part: 576 values are 4.5 lane tiles, and Mosaic copies a page out
+# of a pool in HBM by whole tiles only), so the pair moves through the
+# engine like a K and a V pool (docs/serving.md#latent-kv). The decode
+# step is the ABSORBED form: the caller folds ``W_UK`` into the query
+# (``qL``) and ``W_UV`` into the result, so the kernel multiplies all
+# ``heads`` queries of width ``rank + rope`` against the one row, and the
+# values are the ``c`` half of that same row.
+
+
+def latent_rope_lanes(rope_dim: int) -> int:
+    """Minor dim of the ``kR`` pool: the rotary part padded to whole
+    128-lane tiles."""
+    return round_up(rope_dim, 128)
+
+
+def _latent_reference(q, c_new, kr_new, c_pages, kr_pages, page_table,
+                      positions, scale):
+    """Append, then softmax over the gathered logical view
+    ``pool[page_table]`` in float32: ``score = q . [c | kR]``, values the
+    ``c`` half. A slot whose table maps no page (an idle one) reads
+    zeros, as the kernel writes them."""
+    n_pages, page_size, rank = c_pages.shape
+    b = q.shape[0]
+    c_pages = _append_rows(c_pages, c_new[:, None], page_table, positions,
+                           page_size)
+    kr_pages = _append_rows(kr_pages, kr_new[:, None], page_table,
+                            positions, page_size)
+    pt = jnp.minimum(page_table, n_pages - 1)
+    c = c_pages[pt].reshape(b, -1, rank).astype(jnp.float32)
+    kr = kr_pages[pt].reshape(b, c.shape[1], -1).astype(jnp.float32)
+    qf = q.astype(jnp.float32)
+    scores = (jnp.einsum("bhr,bsr->bhs", qf[..., :rank], c)
+              + jnp.einsum("bhe,bse->bhs", qf[..., rank:], kr)) * scale
+    seen = jnp.arange(c.shape[1])[None, None, :] <= positions[:, None, None]
+    probs = jax.nn.softmax(jnp.where(seen, scores, _NEG), axis=-1)
+    # a masked row may hold another tenant's non-finite values: 0 x NaN
+    out = jnp.einsum("bhs,bsr->bhr", probs,
+                     jnp.where(seen.transpose(0, 2, 1), c, 0.0))
+    live = (page_table[:, 0] < n_pages)[:, None, None]
+    return (jnp.where(live, out, 0.0).astype(q.dtype), c_pages, kr_pages)
+
+
+def _latent_kernel(pt_ref, pos_ref, stop_ref, head_ref, q_ref, c_hbm, kr_hbm,
+                   o_ref, cbuf, krbuf, sems, parity_ref, m_ref, l_ref,
+                   acc_ref, *, page_size, rank, scale, pages_per_round):
+    """One slot of the absorbed latent decode pass: :class:`_PageWalk`
+    over the ``c`` and ``kR`` pools (one grid step a slot, an in-kernel
+    loop over the slot's live pages ``0 .. stop_ref[r]``). A round's rows
+    are scored once for all ``heads`` queries, ``qL @ c^T + qR @ kR^T``
+    (two MXU products over 512 and 128 lanes), and the same ``c`` buffer
+    is the values, so both buffers' rows of pages past ``stop`` are
+    zeroed before use."""
+    walk = _PageWalk(pt_ref, None, stop_ref, head_ref,
+                     [(c_hbm, cbuf), (kr_hbm, krbuf)], sems, parity_ref,
+                     page_size=page_size, pages_per_round=pages_per_round)
+    span = pages_per_round * page_size
+    walk.start_call()
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    newest = pos_ref[walk.r]
+    walk.open()
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+
+    def one_round(i, buf, copies):
+        walk.wait_or_zero(copies, buf, 0, 1)
+        qb = q_ref[0]                                     # [heads, r + e]
+        cb = cbuf[buf].astype(qb.dtype)                   # [span, rank]
+        kb = krbuf[buf].astype(qb.dtype)                  # [span, e]
+        contract = (((1,), (1,)), ((), ()))
+        s_blk = (jax.lax.dot_general(
+            qb[:, :rank], cb, contract, preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(
+                qb[:, rank:], kb, contract,
+                preferred_element_type=jnp.float32)) * scale
+        row = i * span + col
+        s_blk = jnp.where(row > newest, _NEG, s_blk)      # [heads, span]
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s_blk - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(cb.dtype), cb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [heads, rank]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+
+    walk.run(one_round)
+    l = jnp.where(l_ref[...] > 0.0, l_ref[...], 1.0)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _latent_pallas(q, c_new, kr_new, c_pages, kr_pages, page_table,
+                   positions, scale):
+    n_pages, page_size, rank = c_pages.shape
+    lanes = kr_pages.shape[2]
+    b, heads, width = q.shape
+    pages_per_slot = page_table.shape[1]
+    c_pages = _append_rows(c_pages, c_new[:, None], page_table, positions,
+                           page_size)
+    kr_pages = _append_rows(kr_pages, kr_new[:, None], page_table,
+                            positions, page_size)
+    pt = jnp.minimum(page_table, n_pages - 1).astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    _, stop = paged_page_range(positions, 1, page_size)
+    stop = jnp.where(page_table[:, 0] >= n_pages, 0,
+                     jnp.minimum(stop, pages_per_slot)).astype(jnp.int32)
+    slot_ix = jnp.arange(b + 1, dtype=jnp.int32)
+    head = jax.lax.cummin(
+        jnp.where(jnp.append(stop > 0, True), slot_ix, b), reverse=True)
+    pages_per_round = _pages_per_round(page_size, rank, c_pages.dtype,
+                                       pages_per_slot)
+    kernel = functools.partial(
+        _latent_kernel, page_size=page_size, rank=rank, scale=scale,
+        pages_per_round=pages_per_round)
+    rows = pages_per_round * page_size
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, heads, width), lambda r, *_: (r, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, heads, rank), lambda r, *_: (r, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, rank), c_pages.dtype),    # c rounds
+            pltpu.VMEM((2, rows, lanes), kr_pages.dtype),  # kR rounds
+            pltpu.SemaphoreType.DMA((2, 2)),      # [c / kR, buffer]
+            pltpu.SMEM((1,), jnp.int32),          # the next round's buffer
+            pltpu.VMEM((heads, 1), jnp.float32),  # running max
+            pltpu.VMEM((heads, 1), jnp.float32),  # normalizer
+            pltpu.VMEM((heads, rank), jnp.float32),   # weighted accumulator
+        ])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, heads, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(),
+        name=SCOPE_MLA_DECODE,
+    )(pt, positions, stop, head, q, c_pages, kr_pages)
+    return out, c_pages, kr_pages
+
+
+def fused_latent_decode_attention(q_latent, q_rope, c_new, kr_new, c_pages,
+                                  kr_pages, page_table, positions, *,
+                                  softmax_scale: float):
+    """One absorbed decode step of one latent-attention layer.
+
+    Args:
+      q_latent: ``[b, heads, rank]``, each head's content query times its
+        ``W_UK`` (the query in the latent's coordinates).
+      q_rope: ``[b, heads, rope]``, the rotary part, rotated.
+      c_new: ``[b, rank]``, this step's latent row (normed); ``kr_new``
+        ``[b, rope]``, its shared rotary key (rotated).
+      c_pages, kr_pages: ``[n_pages, page_size, rank]`` and ``[n_pages,
+        page_size, latent_rope_lanes(rope)]``, the layer's two pools.
+      page_table, positions: as :func:`fused_paged_decode_attention`.
+      softmax_scale: times the scores (the model's, YaRN's share in it).
+
+    Returns ``(o_latent [b, heads, rank], c_pages, kr_pages)``:
+    ``softmax(qL . c_j + qR . kR_j) @ c`` over rows ``0..positions[r]``,
+    the new row appended first. The caller multiplies by ``W_UV``.
+    """
+    rank, lanes = c_pages.shape[2], kr_pages.shape[2]
+    rope = q_rope.shape[-1]
+    if (q_latent.shape[-1] != rank or c_new.shape[-1] != rank
+            or lanes != latent_rope_lanes(rope)
+            or c_pages.shape[:2] != kr_pages.shape[:2]):
+        raise ValueError(
+            f"latent pools must be [n_pages, page_size, rank] and "
+            f"[n_pages, page_size, {latent_rope_lanes(rope)}] for queries "
+            f"of {q_latent.shape[-1]} + {rope}; got {c_pages.shape} / "
+            f"{kr_pages.shape}")
+    pad = [(0, 0)] * (q_rope.ndim - 1) + [(0, lanes - rope)]
+    q = jnp.concatenate([q_latent, jnp.pad(q_rope, pad)], axis=-1)
+    kr_new = jnp.pad(kr_new, ((0, 0), (0, lanes - rope)))
+    fn = (_latent_pallas if use_pallas() and _kernel_takes(c_pages)
+          else _latent_reference)
+    with nvtx_range(SCOPE_MLA_DECODE):
+        return fn(q, c_new, kr_new, c_pages, kr_pages, page_table,
+                  positions, scale=float(softmax_scale))

@@ -14,10 +14,54 @@ the CUDA build needed a kernel for is the compiler's default here.
 from __future__ import annotations
 
 import functools
+import math
+from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN context extension of rotary positions (arXiv 2309.00071), under
+    the published ``rope_scaling`` key names of the DeepSeek-V3 family."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(rot_dim: int, theta: float, yarn: YarnScaling):
+    """``(inv_freq [rot_dim / 2] float32, cos/sin scale)``: the rotary
+    frequencies blended between ``theta^(-2k/rot_dim)`` (extrapolated: the
+    fast dims, kept) and the same over ``factor`` (interpolated: the slow
+    dims), by a linear ramp between the dims whose wavelength makes
+    ``beta_fast`` and ``beta_slow`` turns in the original context."""
+    base = theta ** (np.arange(0, rot_dim, 2, dtype=np.float64) / rot_dim)
+
+    def turns_dim(turns):
+        return (rot_dim * math.log(yarn.original_max_position_embeddings
+                                   / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(yarn.beta_slow)), rot_dim - 1)
+    ramp = np.clip((np.arange(rot_dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 / (yarn.factor * base)) * ramp + (1.0 / base) * (1.0 - ramp)
+    scale = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return inv.astype(np.float32), scale
 
 
 def _rotate_half(t: jax.Array) -> jax.Array:

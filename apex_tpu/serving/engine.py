@@ -11,7 +11,9 @@ Architecture (docs/serving.md has the full walkthrough):
 
 - **Page pool**: every layer holds one ``[n_pages, page_size, kv_heads *
   head_dim]`` K and V pool (``init_paged_kv_caches``; int8 with a
-  per-(page, kv-head) scale sidecar under ``kv_dtype="int8"``) shared
+  per-(page, kv-head) scale sidecar under ``kv_dtype="int8"``; for a
+  latent-attention model the pair is the ``c`` and ``kR`` row pools,
+  docs/serving.md#latent-kv) shared
   by all slots. :class:`~apex_tpu.serving.slots.SlotPool` hands out
   slots, :class:`~apex_tpu.serving.slots.PagePool` the pages behind
   them: a request maps the pages of its prompt at admission (its worst
@@ -460,6 +462,18 @@ class InferenceEngine:
         self._faults = faults
         self._closed = False
         c = model.config
+        #: latent attention (docs/serving.md#latent-kv): each layer's
+        #: pool pair is (c, kR) rows with no head axis
+        self._latent = bool(getattr(c, "latent_attention", False))
+        if self._latent:
+            for what, on in (
+                    ("kv_dtype='int8'", self.config.kv_dtype == "int8"),
+                    ("speculation", bool(self.config.speculation)),
+                    ("LoRA adapters", adapters is not None)):
+                if on:
+                    raise ValueError(
+                        f"{what} is not supported with latent attention "
+                        f"(kv_lora_rank): docs/serving.md#latent-kv")
         if (c.position_embedding_type == "learned"
                 and self.config.max_len > c.max_position_embeddings):
             raise ValueError(
@@ -482,6 +496,16 @@ class InferenceEngine:
         self._routed = bool(getattr(c, "num_routed_experts", None))
         if self._routed:
             self.metrics.declare_counters("moe_rows_routed")
+        #: assignments a live row makes in one routed layer call where
+        #: this engine holds a SHARE of the layer's experts (0 = it holds
+        #: them all): those that do not land here are counted as
+        #: ``moe_rows_elsewhere``, what an exchange between the holders
+        #: would carry (docs/moe.md)
+        self._share_top_k = 0
+        if self._routed and getattr(c, "routed_expert_range", None) \
+                not in (None, (0, c.num_routed_experts)):
+            self._share_top_k = c.routed_top_k
+            self.metrics.declare_counters("moe_rows_elsewhere")
         #: share of the layers that are window layers, for the
         #: kv_pages_out_of_window gauge (0 = one kind of layer)
         kinds = getattr(c, "attention_layer_types", None) or ()
@@ -542,12 +566,17 @@ class InferenceEngine:
         # quantized) — the kv_bytes_per_step gauge's unit, computed
         # from the GLOBAL head count so the number means the same
         # thing sharded and unsharded
-        f_dim = c.kv_heads * c.head_dim
-        item = 1 if self._quantized else jnp.dtype(
-            c.compute_dtype).itemsize
-        self._page_read_bytes = 2 * c.num_layers * (
-            self.config.page_size * f_dim * item
-            + (c.kv_heads * 4 if self._quantized else 0))
+        if self._latent:
+            self._page_read_bytes = sum(
+                pool.dtype.itemsize * pool.shape[1] * pool.shape[2]
+                for pair in self._caches for pool in pair)
+        else:
+            f_dim = c.kv_heads * c.head_dim
+            item = 1 if self._quantized else jnp.dtype(
+                c.compute_dtype).itemsize
+            self._page_read_bytes = 2 * c.num_layers * (
+                self.config.page_size * f_dim * item
+                + (c.kv_heads * 4 if self._quantized else 0))
         # host page table; n_pages is the unmapped sentinel (reads
         # clamp+mask, scatters drop — see ops/decode_attention.py)
         self._page_table_h = np.full(
@@ -807,20 +836,22 @@ class InferenceEngine:
         clamped = jnp.clip(page_row, 0, n_pages - 1)
         filled = []
         for cache, (sk, sv) in zip(caches, small):
-            h, d = sk.shape[1], sk.shape[3]
-
             def place(pool, sm, scales=None):
                 g = pool[clamped]                       # [pps, ps, h*d]
                 if scales is not None:
                     # dequantize the shared-prefix rows with their pages'
                     # sidecar scales before they enter the fp forward
-                    sc = jnp.repeat(scales[clamped], d, axis=-1)
+                    sc = jnp.repeat(scales[clamped], sm.shape[3], axis=-1)
                     g = g.astype(jnp.float32) * sc[:, None, :]
                 # sentinel rows must read as EXACT zeros (a clamped
                 # gather could otherwise import a co-tenant's transient
                 # NaN into causally masked positions: 0-weight * NaN
                 # is still NaN)
                 g = jnp.where(valid_page[:, None, None], g, 0.0)
+                if sm.ndim == 3:      # latent rows: flat, no head axis
+                    return sm.at[:, :s0].set(
+                        g.reshape(1, s0, -1).astype(sm.dtype))
+                h, d = sm.shape[1], sm.shape[3]
                 g = g.reshape(s0, h, d).transpose(1, 0, 2)[None]
                 return sm.at[:, :, :s0, :].set(g.astype(sm.dtype))
 
@@ -845,11 +876,13 @@ class InferenceEngine:
         dest_page = jnp.where(valid, dest_page, n_pages)  # drop pads
         new = []
         for cache, (fk, fv) in zip(caches, filled):
-            h, d = fk.shape[1], fk.shape[3]
-
             def rows(f):
+                if f.ndim == 3:       # latent rows: flat already
+                    return jax.lax.dynamic_slice_in_dim(
+                        f, start, bucket, axis=1)[0]
                 r = jax.lax.dynamic_slice_in_dim(f, start, bucket, axis=2)
-                return r[0].transpose(1, 0, 2).reshape(bucket, h * d)
+                return r[0].transpose(1, 0, 2).reshape(
+                    bucket, f.shape[1] * f.shape[3])
 
             if self._quantized:
                 # suffix rows straddle pages, so they go through the
@@ -1144,6 +1177,10 @@ class InferenceEngine:
                                        self.pages.in_use_count)
                 self.metrics.set_gauge("kv_pages_free",
                                        self.pages.free_count)
+                if self._latent:
+                    self.metrics.set_gauge(
+                        "kv_latent_bytes_in_use",
+                        self.pages.in_use_count * self._page_read_bytes)
                 self.metrics.observe("kv_page_occupancy",
                                      self.pages.occupancy)
                 if self._window_share:
@@ -1722,8 +1759,31 @@ class InferenceEngine:
                     # prefix pages) are bitwise what the monolithic fill
                     # produces
                     ps = self.config.page_size
-                    chunk_len = ((rec.prefill_pos + chunk_len) // ps) * ps \
-                        - rec.prefill_pos
+
+                    def aligned(n):
+                        return ((rec.prefill_pos + n) // ps) * ps \
+                            - rec.prefill_pos
+
+                    # a chunk is a whole pass over the weights: neither it
+                    # nor what it leaves behind should be a sliver. A
+                    # chunk that is not the prompt's last is a quarter of
+                    # the budget or more and leaves as much; what is left
+                    # of a tick's budget goes UNUSED where that cannot be
+                    # (the head of a tick has the whole budget and always
+                    # runs): docs/serving.md#chunked-prefill says what
+                    # that trades. The chunk programs that can be compiled
+                    # are then those of whole prompts and of the top two
+                    # octaves of the budget
+                    budget = self.config.prefill_token_budget
+                    floor = budget // 4
+                    whole = aligned(chunk_len)
+                    chunk_len = whole
+                    if remaining - chunk_len < floor:
+                        chunk_len = aligned(remaining - floor)
+                    if chunk_len < floor:
+                        if budget_left < budget:
+                            return 0
+                        chunk_len = max(chunk_len, whole)
                 if chunk_len <= 0:
                     return 0
             with span(TICK_UPLOAD, arrays=2, bytes=8):
@@ -2053,10 +2113,17 @@ class InferenceEngine:
             retired = len(finished)
             if self._routed:
                 nxt, routing = self._split_routing(nxt)
+                # every live row of the step (each row of its window,
+                # under speculation) made top_k assignments a layer call
+                made = (len(step.rows) * max(self._spec, 1)
+                        * self._share_top_k)
                 for rows, touched, busiest in routing:
                     # one routed layer call of this step: how many of the
                     # experts' weights it had to stream, and the straggler
                     self.metrics.inc("moe_rows_routed", int(rows))
+                    if made:
+                        self.metrics.inc("moe_rows_elsewhere",
+                                         made - int(rows))
                     self.metrics.observe("moe_experts_touched",
                                          int(touched))
                     self.metrics.observe("moe_max_expert_rows",

@@ -59,6 +59,11 @@ class ShardedEngine(InferenceEngine):
         self.mesh = mesh if mesh is not None else parallel_state.get_mesh()
         c = model.config
         self._tp = self.mesh.shape[c.axis_name]
+        if getattr(c, "latent_attention", False):
+            raise ValueError(
+                "ShardedEngine shards the K/V pools by their heads; latent "
+                "attention (kv_lora_rank) rows have no head axis: "
+                "docs/serving.md#latent-kv")
         if c.kv_heads % self._tp:
             raise ValueError(
                 f"kv heads ({c.kv_heads}) must be divisible by the "

@@ -48,12 +48,14 @@ def prefix_salt(config) -> str:
 
 
 def _kinds_salt(config) -> str:
-    """What the fingerprint adds for a stated head size and for layers of
-    more than one kind (both shape the cached K/V); empty for every model
-    that has neither, whose salt stays as it was."""
+    """What the fingerprint adds for a stated head size, for latent rows
+    and for layers of more than one kind (each shapes what is cached);
+    empty for every model that has none, whose salt stays as it was."""
     extra = ""
     if getattr(config, "kv_channels", None) is not None:
         extra += f":dh{config.kv_channels}"
+    if getattr(config, "kv_lora_rank", None) is not None:
+        extra += f":mla{config.kv_lora_rank}+{config.qk_rope_head_dim}"
     kinds = getattr(config, "attention_layer_types", None)
     if kinds is not None:
         extra += ":" + "".join(k[0] for k in kinds) \

@@ -376,6 +376,11 @@ class RoutedMoEConfig:
     # router still scores all ``num_experts``; rows that chose an expert
     # outside the range add nothing here (another holder computes them)
     expert_range: Optional[Tuple[int, int]] = None
+    # group-limited selection: `num_groups` groups of consecutive experts,
+    # a group's score the sum of its two best biased scores, the top_k
+    # taken inside the `topk_groups` best groups (1 group: plain top_k)
+    num_groups: int = 1
+    topk_groups: int = 1
     params_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
     init_method_std: float = 0.02
@@ -384,6 +389,16 @@ class RoutedMoEConfig:
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k ({self.top_k}) must lie in 1.."
                              f"num_experts ({self.num_experts})")
+        if self.num_groups > 1:
+            per_group, rest = divmod(self.num_experts, self.num_groups)
+            if (rest or per_group < 2
+                    or not 1 <= self.topk_groups <= self.num_groups
+                    or self.topk_groups * per_group < self.top_k):
+                raise ValueError(
+                    f"{self.num_groups} groups over {self.num_experts} "
+                    f"experts, {self.topk_groups} kept: groups must be "
+                    f"equal, of two experts or more, and the kept ones "
+                    f"must hold top_k ({self.top_k})")
         lo, hi = self.held
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(
@@ -402,7 +417,9 @@ class RoutedExperts:
     ``y = FFN_shared(x) + sum_{e in sel} w_e FFN_e(x)`` over the experts
     held here, each ``FFN`` the gated form ``(silu(x Wg) * (x Wu)) Wd``.
     ``s = sigmoid(x W_r)`` in float32; ``sel = top_k(s + bias)`` (the bias
-    selects only); ``w = s[sel] / (sum(s[sel]) + 1e-20) * route_scale``.
+    selects only; with ``num_groups`` > 1 over the ``topk_groups`` groups
+    whose two best biased scores add up highest);
+    ``w = s[sel] / (sum(s[sel]) + 1e-20) * route_scale``.
     The two halves
     are also callable apart (:meth:`routed`, :meth:`shared`): what every
     holder of a share computes alike is counted once by the caller that
@@ -453,8 +470,18 @@ class RoutedExperts:
                          r["weight"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
-        _, experts = lax.top_k(scores + r["bias"].astype(jnp.float32),
-                               c.top_k)
+        biased = scores + r["bias"].astype(jnp.float32)
+        if c.num_groups > 1:
+            groups = biased.reshape(-1, c.num_groups,
+                                    c.num_experts // c.num_groups)
+            best_two = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+            _, kept = lax.top_k(best_two, c.topk_groups)       # [T, kept]
+            in_kept = jnp.any(
+                kept[:, :, None] == jnp.arange(c.num_groups)[None, None, :],
+                axis=1)                                        # [T, groups]
+            biased = jnp.where(in_kept[:, :, None], groups,
+                               -jnp.inf).reshape(biased.shape)
+        _, experts = lax.top_k(biased, c.top_k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
         return weights * c.route_scale, experts

@@ -51,6 +51,9 @@ from collections import defaultdict
 #: rounds scores, probabilities and output, the kernel only the
 #: probabilities and output — a few roundings of an O(1) value apart
 KERNEL_ATOL, KERNEL_RTOL = 2e-2, 2e-2
+#: (query heads, kv_lora_rank, qk_rope_head_dim) of the latent kernel's
+#: case: dots-vlm1-inst's published sizes
+LATENT_SIZES = (128, 512, 64)
 #: four-chip first-step loss vs the single-chip phase, same seeds: tp=2
 #: splits every row-parallel reduction in two bf16 partial sums
 MULTICHIP_LOSS_RTOL = 5e-3
@@ -192,14 +195,18 @@ def kernel_phase(*, slots, heads, head_dim, page_size, pages_per_slot,
                  dtype) -> dict:
     """Pallas decode kernel vs the jnp reference on one random pool and
     page table with ragged positions; returns max-abs errors per variant
-    (plain, w=3 verify window, int8 pool)."""
+    (plain, w=3 verify window, int8 pool, and the latent kernel over its
+    own two pools)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from apex_tpu.ops.decode_attention import (
+        _latent_pallas,
+        _latent_reference,
         _pallas,
         _reference,
+        latent_rope_lanes,
         paged_quant_fill,
     )
 
@@ -256,6 +263,36 @@ def kernel_phase(*, slots, heads, head_dim, page_size, pages_per_slot,
                      for a, b in zip(got[1:3], want[1:3])),
                  f"kernel {name}: appended pools differ")
         errors[name] = float(jnp.max(err))
+
+    # the latent (MLA) kernel at the published head sizes of
+    # dots-vlm1-inst: 128 queries of 512 + 64 against one shared row a
+    # position, over the same table and ragged positions
+    heads_l, rank, rope = LATENT_SIZES
+    lanes = latent_rope_lanes(rope)
+    c_pages = jax.random.normal(keys[0], (n_pages, page_size, rank), dtype)
+    kr_pages = jax.random.normal(
+        keys[1], (n_pages, page_size, lanes), dtype).at[:, :, rope:].set(0)
+    q = jax.random.normal(keys[2], (slots, heads_l, rank + lanes), dtype)
+    q = q.at[:, :, rank + rope:].set(0)
+    c_new = jax.random.normal(keys[3], (slots, rank), dtype)
+    kr_new = jax.random.normal(keys[4], (slots, lanes), dtype)
+    kr_new = kr_new.at[:, rope:].set(0)
+    args = (q, c_new, kr_new, c_pages, kr_pages, table, positions)
+    scale = float(rank + rope) ** -0.5
+    got = _latent_pallas(*args, scale=scale)
+    want = jax.jit(_latent_reference, static_argnames=("scale",))(
+        *args, scale=scale)
+    ctx, ref = (x[0].astype(jnp.float32) for x in (got, want))
+    err = jnp.abs(ctx - ref)
+    _require(bool(jnp.all(jnp.isfinite(ctx))),
+             "kernel latent: non-finite context")
+    _require(bool(jnp.all(err <= KERNEL_ATOL + KERNEL_RTOL * jnp.abs(ref))),
+             f"kernel latent: max-abs {float(jnp.max(err)):.3e} outside "
+             f"atol {KERNEL_ATOL} + rtol {KERNEL_RTOL}")
+    _require(all(bool(jnp.array_equal(a, b))
+                 for a, b in zip(got[1:], want[1:])),
+             "kernel latent: appended pools differ")
+    errors["latent"] = float(jnp.max(err))
     return {"max_abs_err": errors, "atol": KERNEL_ATOL,
             "rtol": KERNEL_RTOL}
 

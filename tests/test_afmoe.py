@@ -308,6 +308,20 @@ def _lowered_programs():
     spec = InferenceEngine(m, params, EngineConfig(
         max_slots=4, max_len=32, page_size=8, speculation=3))
     out["engine/spec-decode"] = (spec._decode_fn._fn, *spec._decode_args())
+    # the same three of the mixed, routed model above (what
+    # trinitym.serve-decode-4k runs): window and full layers, the
+    # ungrouped router, every expert held
+    m = _model(jnp.bfloat16)
+    eng = InferenceEngine(m, m.init(key), EngineConfig(
+        max_slots=4, max_len=32, page_size=8))
+    out["routed/paged-decode"] = (eng._decode_fn._fn, *eng._decode_args())
+    out["routed/paged-prefill"] = (
+        eng._prefill_fn._fn, eng._params, eng._caches, row, prompt,
+        jnp.int32(11), *sampling, aix, None)
+    out["routed/suffix-prefill"] = (
+        eng._suffix_fn._fn, eng._params, eng._caches, row, prompt,
+        jnp.int32(8), jnp.int32(3), jnp.int32(11), *sampling,
+        jnp.bool_(False), aix, None)
     return out
 
 
@@ -339,6 +353,12 @@ PARENT_PROGRAMS = {
     "engine/paged-prefill": "b33e75e42f8d0415",
     "engine/suffix-prefill": "90c81dffea2e0d87",
     "engine/spec-decode": "5d15c62e192aebbf",
+    # read at the parent of PR 34 (commit 8cb1cae), which gave the router
+    # its groups, the engine its latent kind and the chunk program's body
+    # a second cache form: the routed model's programs are the parent's
+    "routed/paged-decode": "974f933466642150",
+    "routed/paged-prefill": "62e2aa0c77207753",
+    "routed/suffix-prefill": "5b5ae1a983a1aa2a",
 }
 
 

@@ -54,6 +54,10 @@ CHEAP_MODEL_TEST_MODULES = {
     # tick by tick (18 tests, 57 s for the file); ISSUE 33 asks for them
     # in tier-1
     "test_decode_in_flight.py",
+    # PR 34: a 3-layer hidden-64 latent-attention model against its plain
+    # reference, every path jitted once (41 tests, 70 s for the file);
+    # ISSUE 34 asks for them in tier-1
+    "test_dots_vlm.py",
     # not a test module: the per-request reference the serving tests
     # import (one jitted prefill and one decode step a request)
     "serving_reference.py",

@@ -99,7 +99,7 @@ def test_kernel_phase_tiny(pallas_kernels):
     errors = chip_smoke.kernel_phase(
         slots=4, heads=4, head_dim=16, page_size=8, pages_per_slot=8,
         dtype=jnp.bfloat16)["max_abs_err"]
-    assert set(errors) == {"bf16", "bf16_w3", "int8", "int8_w3"}
+    assert set(errors) == {"bf16", "bf16_w3", "int8", "int8_w3", "latent"}
 
 
 def test_compile_cache_is_placeable_and_fixed(monkeypatch):
@@ -191,6 +191,66 @@ def _described_v5e(monkeypatch):
     return SingleDeviceSharding(topo.devices[0])
 
 
+#: sha256[:16] of the decode kernels' calls as jaxprs (kernel bodies
+#: included), read where each was measured on the chip: the K/V kernel at
+#: PR 33's tree (8cb1cae), the latent kernel at PR 34's first tree. The
+#: kernels share their page walk (``_PageWalk``) since PR 34; an edit that
+#: keeps these keeps the measured kernels op for op, and one that moves
+#: them wants a chip run.
+MEASURED_KERNELS = {
+    "gpt2m": "e2b03a570c069802",
+    "trinity-window": "2eb671714e0c29e0",
+    "int8-w3": "a6d0292bf3778012",
+    "latent": "1ba8e3fa9289f559",
+}
+
+
+@pytest.mark.parametrize("which", sorted(MEASURED_KERNELS))
+def test_decode_kernels_trace_to_the_measured_programs(monkeypatch, which):
+    import hashlib
+
+    monkeypatch.setenv("APEX_TPU_FORCE_PALLAS", "tpu")
+    _support.pallas_mode.cache_clear()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    try:
+        if which == "latent":
+            b, heads, rank, lanes, ps, pps = 64, 128, 512, 128, 64, 128
+            jaxpr = jax.make_jaxpr(
+                lambda *a: decode_attention._latent_pallas.__wrapped__(
+                    *a, scale=0.0781))(
+                arg((b, heads, rank + lanes), jnp.bfloat16),
+                arg((b, rank), jnp.bfloat16), arg((b, lanes), jnp.bfloat16),
+                arg((b * pps, ps, rank), jnp.bfloat16),
+                arg((b * pps, ps, lanes), jnp.bfloat16),
+                arg((b, pps), jnp.int32), arg((b,), jnp.int32))
+        else:
+            b, w, heads, kvh, dh, ps, pps, window, dtype = {
+                "gpt2m": (96, 1, 16, 16, 64, 64, 16, None, jnp.bfloat16),
+                "trinity-window": (96, 1, 32, 4, 128, 64, 64, 2048,
+                                   jnp.bfloat16),
+                "int8-w3": (8, 3, 16, 16, 64, 64, 16, None, jnp.int8),
+            }[which]
+            f = kvh * dh
+            pool = arg((b * pps, ps, f), dtype)
+            scales = (arg((b * pps, kvh), jnp.float32)
+                      if dtype == jnp.int8 else None)
+            jaxpr = jax.make_jaxpr(
+                lambda *a: decode_attention._pallas.__wrapped__(
+                    *a, group=heads // kvh, sliding_window=window))(
+                arg((b, w, heads, dh), jnp.bfloat16),
+                arg((b, w, f), jnp.bfloat16), arg((b, w, f), jnp.bfloat16),
+                pool, pool, scales, scales, arg((b, pps), jnp.int32),
+                arg((b,), jnp.int32))
+    finally:
+        _support.pallas_mode.cache_clear()
+    assert "pallas_call" in str(jaxpr)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] == \
+        MEASURED_KERNELS[which]
+
+
 @pytest.mark.parametrize("window", [None, 2048])
 def test_windowed_decode_kernel_compiles_at_gqa_widths(monkeypatch, window):
     """The decode kernel as ``trinitym.serve-decode-4k`` runs it (32 query
@@ -214,6 +274,72 @@ def test_windowed_decode_kernel_compiles_at_gqa_widths(monkeypatch, window):
     finally:
         _support.pallas_mode.cache_clear()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_decode_kernel_compiles_at_published_sizes(monkeypatch):
+    """The latent kernel as ``dotsvlm1.serve-decode-8k`` runs it (128
+    query heads of 512 + 64 against one shared row a position, 64 slots x
+    128 pages of 64, eight pages a DMA round from the ``c`` and the ``kR``
+    pool) through Mosaic."""
+    on = _described_v5e(monkeypatch)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    b, heads, rank, lanes, ps, pps = 64, 128, 512, 128, 64, 128
+    try:
+        compiled = decode_attention._latent_pallas.lower(
+            arg((b, heads, rank + lanes), jnp.bfloat16),
+            arg((b, rank), jnp.bfloat16), arg((b, lanes), jnp.bfloat16),
+            arg((b * pps, ps, rank), jnp.bfloat16),
+            arg((b * pps, ps, lanes), jnp.bfloat16),
+            arg((b, pps), jnp.int32), arg((b,), jnp.int32),
+            scale=0.0781).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+
+
+@pytest.mark.parametrize("form", ["expanded", "absorbed"])
+def test_latent_prefill_attention_compiles_at_published_sizes(monkeypatch,
+                                                              form):
+    """The two flash calls of a latent-attention prefill at
+    ``dots-vlm1-inst``'s sizes through Mosaic: a whole prompt of 2,048 at
+    q/k head size 192 against v head size 128, and a 2,048-token chunk of
+    128 heads over one shared row of 640 / 512 lanes a position, at a
+    traced offset into 10,240 cached rows."""
+    from apex_tpu.ops.attention import flash_chunk_fwd
+
+    on = _described_v5e(monkeypatch)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=on)
+
+    if form == "expanded":
+        args = (arg(1, 128, 2048, 192), arg(1, 128, 2048, 192),
+                arg(1, 128, 2048, 128))
+        kw = dict(q_start=0)
+    else:
+        args = (arg(1, 128, 2048, 640), arg(1, 1, 10240, 640),
+                arg(1, 1, 10240, 512))
+        kw = dict(block_q=512, block_k=512)
+    try:
+        if form == "expanded":
+            fn = jax.jit(lambda q, k, v: flash_chunk_fwd(
+                q, k, v, k_start=0, causal=True, softmax_scale=0.1,
+                name="mla_prefill_attention", **kw)[0])
+            compiled = fn.lower(*args).compile()
+        else:
+            fn = jax.jit(lambda q, k, v, s: flash_chunk_fwd(
+                q, k, v, q_start=s, k_start=0, causal=True,
+                softmax_scale=0.1, name="mla_prefill_attention", **kw)[0])
+            compiled = fn.lower(*args, jax.ShapeDtypeStruct(
+                (), jnp.int32, sharding=on)).compile()
+    finally:
+        _support.pallas_mode.cache_clear()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_prefill_attention" in text
 
 
 @pytest.mark.parametrize("page_size,f,takes", [

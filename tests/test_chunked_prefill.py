@@ -594,3 +594,52 @@ class TestTokenAwareLoad:
                 queued_token_excess_s = 0.0
 
         assert Router.cost(_Fresh())[0] == 0.0
+
+
+class TestNoSlivers:
+    """A chunk that is not a prompt's last is a quarter of the budget or
+    more and leaves as much behind: the chunk programs that can compile
+    are those of whole prompts and of the top two octaves of the budget."""
+
+    def test_chunks_are_whole_prompts_or_the_budgets_top_octaves(self):
+        model = GPTModel(TransformerConfig(
+            num_layers=1, hidden_size=32, num_attention_heads=4,
+            vocab_size=64, max_position_embeddings=256, hidden_dropout=0.0,
+            attention_dropout=0.0))
+        params = model.init(jax.random.PRNGKey(1))
+        cfg = EngineConfig(max_slots=6, max_len=256, page_size=4,
+                           prefill_token_budget=32,
+                           scheduler=SchedulerConfig(max_prefills_per_tick=3))
+        eng = InferenceEngine(model, params, cfg)
+        seen = []
+        run_chunk = eng._run_chunk
+
+        def spy(rec, budget_left, finished):
+            before = rec.prefill_pos
+            ran = run_chunk(rec, budget_left, finished)
+            if ran:
+                seen.append((ran, rec.request.prompt_len - before - ran,
+                             budget_left))
+            return ran
+
+        eng._run_chunk = spy
+        lens = [9, 33, 41, 70, 37, 100, 12, 65, 34, 129, 47, 95]
+        reqs = [Request(prompt=p, max_new_tokens=4, request_id=i)
+                for i, p in enumerate(_prompts(lens, seed=3))]
+        try:
+            results = eng.serve(reqs)
+        finally:
+            eng.close()
+        assert all(r.finish_reason == "length" for r in results)
+        assert sum(ran for ran, _, _ in seen) == sum(lens)
+        floor = 32 // 4
+        for ran, left, budget_left in seen:
+            assert ran <= budget_left
+            if left:                  # not the prompt's last chunk
+                assert ran >= floor and left >= floor, (ran, left)
+        # a last chunk is a whole prompt or what such a chunk left behind
+        assert min(ran for ran, _, _ in seen) >= floor
+        # several prompts shared a tick's budget, and some budget went
+        # unused rather than to a sliver
+        assert any(budget_left < 32 for _, _, budget_left in seen)
+        assert eng.chunk_compiles <= 4        # buckets 8, 16, 32 (+ 9 -> 16)

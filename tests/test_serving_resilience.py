@@ -286,10 +286,12 @@ class TestSupervisorRecovery:
     def test_hung_tick_triggers_restart(self, small):
         model, params = small
         (prompt,) = _prompts([3], seed=43)
-        inj = ServingFaultInjector(decode_hang={1: 0.08})
+        # a budget that a tick starved by the other test workers does not
+        # pass (at 30 ms the whole-suite run counted 2-3 "hung" ticks)
+        inj = ServingFaultInjector(decode_hang={1: 0.6})
         sup = EngineSupervisor(
             model, params, EngineConfig(max_slots=2, max_len=16),
-            supervisor=SupervisorConfig(hung_tick_s=0.03), faults=inj)
+            supervisor=SupervisorConfig(hung_tick_s=0.25), faults=inj)
         (res,) = sup.serve([Request(prompt=prompt, max_new_tokens=6)])
         req = Request(prompt=prompt, max_new_tokens=6)
         assert res.finish_reason == "length"
