@@ -19,7 +19,8 @@ from apex_tpu.utils.sharding import axis_size
 
 __all__ = ["init_kv_caches", "init_paged_kv_caches", "decode_step",
            "generate", "cast_decode_params", "flatten_decode_caches",
-           "preslice_layer_params"]
+           "preslice_layer_params", "split_gated_mlp_params",
+           "is_gated_mlp_weight"]
 
 
 def cast_decode_params(params, compute_dtype):
@@ -38,6 +39,57 @@ def cast_decode_params(params, compute_dtype):
         return x.astype(compute_dtype) if x.dtype == jnp.float32 else x
 
     return tree_map_with_path(cast, params)
+
+
+def is_gated_mlp_weight(path) -> bool:
+    """Whether a ``tree_map_with_path`` path ends at a ``ParallelMLP``'s
+    in-projection weight (``.../dense_h_to_4h/weight``)."""
+    return [getattr(p, "key", None) for p in path[-2:]] == [
+        "dense_h_to_4h", "weight"]
+
+
+@jax.jit
+def _halves_apart(w):
+    """``[..., 2*ffn, h]`` interleaved -> ``[..., 2, ffn, h]``, as one
+    program (called outside ``jit`` it makes one copy, not two)."""
+    *lead, rows, h = w.shape
+    return jnp.swapaxes(w.reshape(*lead, rows // 2, 2, h), -3, -2)
+
+
+def split_gated_mlp_params(params, config):
+    """Re-lay every gated ``ParallelMLP``'s ``dense_h_to_4h.weight`` ONCE
+    from the interleaved ``[2*ffn, h]`` (rows ``gate_0, up_0, gate_1,
+    ...``: the form of init, training and checkpoints) to halves apart,
+    ``[2, ffn, h]`` (plane 0 = gate, plane 1 = up), stacked layers or a
+    per-layer list alike. The interleaved product is sliced along a lane
+    dim of 2, and on the chip XLA turns that into a copy of the whole
+    weight in every program run (528 MB a step at ``dots-vlm1-inst``,
+    three times the product it feeds: PERF.md section 6, PR 36);
+    ``ParallelMLP.apply`` sees the form from the weight's rank. Returns
+    ``(params, bytes)``, the bytes of weight now held halves apart. The
+    identity (0 bytes) on a model that is not gated; a weight already
+    apart is kept and counted; every other leaf is the same object."""
+    from jax.tree_util import tree_map_with_path
+
+    from apex_tpu.utils.activations import is_gated
+
+    # (the supervisor hands over whatever config its model has; the
+    # model checker's stub has no activation)
+    if not is_gated(getattr(config, "activation", "")):
+        return params, 0
+    ffn = config.ffn_size
+    apart = 0
+
+    def relay(path, x):
+        nonlocal apart
+        if not is_gated_mlp_weight(path):
+            return x
+        if x.shape[-2] == 2 * ffn:
+            x = _halves_apart(x)
+        apart += x.size * x.dtype.itemsize
+        return x
+
+    return tree_map_with_path(relay, params), apart
 
 
 def flatten_decode_caches(caches, num_layers: int):
@@ -317,7 +369,10 @@ def generate(model, params, prompt: jax.Array, max_new_tokens: int, *,
     # bf16 weights are the standard serving precision). The barrier pins
     # the cast params as materialized buffers; without it XLA sinks the
     # (loop-invariant) casts back into the scan body.
+    # A gated dense layer's gate/up weight is re-laid halves apart beside
+    # the cast, once a call (split_gated_mlp_params).
     c = model.config
+    params, _ = split_gated_mlp_params(params, c)
     if c.compute_dtype != jnp.float32:
         params = jax.lax.optimization_barrier(
             cast_decode_params(params, c.compute_dtype))

@@ -63,6 +63,7 @@ from apex_tpu.transformer.tensor_parallel.utils import divide
 from apex_tpu.utils.profiling import nvtx_range
 from apex_tpu.utils.activations import (
     apply_activation,
+    gated_product,
     is_gated,
     validate_activation,
 )
@@ -508,6 +509,17 @@ class ParallelMLP:
     gate/up **unit-interleaved** along the output dim (column ``2i`` =
     gate_i, ``2i+1`` = up_i), so one matmul + one input-grad collective
     serves both halves and every TP slice holds matched pairs.
+
+    That interleaved ``[2*ffn, h]`` weight is the form of ``init``,
+    ``spec()``, training, checkpoints and whatever a caller hands over.
+    The serving side (``InferenceEngine``, ``generate()``) re-lays it
+    once at intake to halves apart, ``[2, ffn, h]`` (plane 0 = gate,
+    plane 1 = up; ``models.generation.split_gated_mlp_params``), because
+    on the chip the interleaved product is sliced along a lane dim of 2
+    and XLA pays for that with a copy of the whole weight every program
+    run. ``apply`` tells the two by the weight's rank and computes the
+    same ``act(x Wg) * (x Wu)`` from either; a TP slice of the ``ffn``
+    axis holds matched pairs in both.
     """
 
     config: TransformerConfig
@@ -543,10 +555,26 @@ class ParallelMLP:
 
     def apply(self, params, hidden, *, lora=None):
         c = self.config
-        x = self.dense_h_to_4h.apply(params["dense_h_to_4h"], hidden)
+        p_in = params["dense_h_to_4h"]
+        w = p_in["weight"]
+        apart = w.ndim == 3
+        if apart:
+            # halves apart (the serving side's form): one product over
+            # the [2*ffn, h] view of the major dims, split at lane ffn
+            ffn = w.shape[1]
+            p_in = {"weight": w.reshape(2 * ffn, w.shape[2])}
+        x = self.dense_h_to_4h.apply(p_in, hidden)
         if lora is not None:
-            x = x + _lora_delta(hidden, lora).astype(x.dtype)
-        x = apply_activation(x, c.activation)
+            d = _lora_delta(hidden, lora).astype(x.dtype)
+            if apart:
+                # the delta's columns are interleaved, as B's are
+                d = jnp.swapaxes(d.reshape(*d.shape[:-1], ffn, 2),
+                                 -1, -2).reshape(d.shape)
+            x = x + d
+        if apart:
+            x = gated_product(x[..., :ffn], x[..., ffn:], c.activation)
+        else:
+            x = apply_activation(x, c.activation)
         return self.dense_4h_to_h.apply(params["dense_4h_to_h"], x)
 
 

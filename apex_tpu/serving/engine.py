@@ -97,6 +97,7 @@ from apex_tpu.models.generation import (
     init_kv_caches,
     init_paged_kv_caches,
     preslice_layer_params,
+    split_gated_mlp_params,
 )
 from apex_tpu.observability import MetricsRegistry
 from apex_tpu.observability.trace import (
@@ -539,10 +540,13 @@ class InferenceEngine:
         self._chunk_tokens_tick = 0   # prefill tokens run this tick
         self._vocab = c.vocab_size
 
-        # serving precision: generate()'s own one-time pre-cast +
+        # serving precision: generate()'s own one-time pre-cast, re-lay
+        # of a gated dense layer's gate/up weight to halves apart, and
         # per-layer param pre-slice, materialized ONCE at engine build
         if c.compute_dtype != jnp.float32:
             params = cast_decode_params(params, c.compute_dtype)
+        params, apart = split_gated_mlp_params(params, c)
+        self.metrics.set_gauge("decode_weights_relaid_bytes", apart)
         self._params = preslice_layer_params(params, c.num_layers)
         pps = self.config.pages_per_slot
         n_pages = self.config.n_pages or self.config.max_slots * pps

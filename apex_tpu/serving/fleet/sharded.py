@@ -39,7 +39,9 @@ from typing import Optional
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.tree_util import tree_map_with_path
 
+from apex_tpu.models.generation import is_gated_mlp_weight
 from apex_tpu.serving.engine import EngineConfig, InferenceEngine
 from apex_tpu.transformer import parallel_state
 from apex_tpu.utils.sharding import shard_map
@@ -98,7 +100,10 @@ class ShardedEngine(InferenceEngine):
         params: the one-time ``preslice_layer_params`` turns the stacked
         ``[L, ...]`` transformer layers into a per-layer LIST, so the
         stacked spec's leading (layer) dim is stripped and the per-layer
-        spec repeated."""
+        spec repeated; and a gated dense layer's in-projection weight is
+        held halves apart, ``[2, ffn, h]`` (``split_gated_mlp_params``),
+        so its row spec moves to the ``ffn`` axis: every rank holds
+        matched gate/up pairs, as it does of the interleaved rows."""
         spec = self.model.spec()
         layers = self._params.get("transformer", {}).get("layers")
         if isinstance(layers, (list, tuple)):
@@ -109,7 +114,10 @@ class ShardedEngine(InferenceEngine):
             spec = dict(spec)
             spec["transformer"] = dict(spec["transformer"])
             spec["transformer"]["layers"] = [per_layer] * len(layers)
-        return spec
+        return tree_map_with_path(
+            lambda path, x, s: P(None, *s)
+            if is_gated_mlp_weight(path) and x.ndim == len(s) + 1 else s,
+            self._params, spec)
 
     def _cache_spec(self):
         """The ``[n_pages, page_size, kv_heads * head_dim]`` pools shard

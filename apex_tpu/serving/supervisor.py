@@ -50,6 +50,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
+from apex_tpu.models.generation import split_gated_mlp_params
 from apex_tpu.observability import MetricsRegistry
 from apex_tpu.serving import clock
 from apex_tpu.observability.trace import (
@@ -184,7 +185,11 @@ class EngineSupervisor:
                  service_s: Optional[float] = None,
                  engine_factory=None, adapters=None):
         self._model = model
-        self._params = params
+        # a gated dense layer's weight is re-laid for serving here, once
+        # for every engine incarnation (the engine's own intake is then
+        # the identity), so the caller's interleaved copy is not held
+        # beside it for the life of the supervisor
+        self._params, _ = split_gated_mlp_params(params, model.config)
         #: LoRA :class:`~apex_tpu.lora.AdapterStore`, handed to every
         #: engine incarnation — the store (and its device bank) is
         #: SUPERVISOR state, so loaded adapters survive engine rebuilds

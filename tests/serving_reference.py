@@ -9,6 +9,8 @@ engine's. A stream the engine serves, greedy or sampled, must equal it
 token for token.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -69,3 +71,35 @@ def reference_stream(model, params, request, max_len):
         position += 1
         tokens.append(pick_token(logits, request.sampling, position))
     return tokens
+
+
+def serving_programs(engine) -> dict:
+    """An engine's decode, bucketed-prefill and suffix/chunk-prefill
+    programs as ``(jitted fn, *args)``, each with the arguments the
+    engine builds for it (one 16-token bucket for the prefills)."""
+    row = jnp.asarray(engine._page_table_h[0])
+    prompt = jnp.zeros((1, 16), jnp.int32)
+    sampling = (jnp.float32(0.0), jnp.int32(engine._vocab), jnp.int32(0))
+    aix = jnp.zeros(1, jnp.int32)
+    return {
+        "decode": (engine._decode_fn._fn, *engine._decode_args()),
+        "prefill": (engine._prefill_fn._fn, engine._params, engine._caches,
+                    row, prompt, jnp.int32(11), *sampling, aix, None),
+        "suffix": (engine._suffix_fn._fn, engine._params, engine._caches,
+                   row, prompt, jnp.int32(8), jnp.int32(3), jnp.int32(11),
+                   *sampling, jnp.bool_(False), aix, None),
+    }
+
+
+def serving_program_text(engine, name: str) -> str:
+    """Lowered (StableHLO) text of one of :func:`serving_programs`."""
+    fn, *args = serving_programs(engine)[name]
+    return fn.lower(*args).as_text()
+
+
+def lane_pair_reshapes(text: str, ffn: int) -> list:
+    """The reshapes of a lowered program to ``[..., ffn, 2]``: what the
+    interleaved gated activation slices, and what the chip's compiler
+    turns into a copy of the whole gate/up weight."""
+    return [line.strip() for line in text.splitlines() if re.search(
+        rf"stablehlo\.reshape.*-> tensor<[0-9x]*x{ffn}x2x\w+>", line)]

@@ -191,6 +191,64 @@ def test_engine_serves_the_mixed_model_token_exact_in_float32(weights):
     assert counters["decode_rows_dropped"] == 0
 
 
+@pytest.fixture(scope="module")
+def served(weights):
+    """(engine over the interleaved tree, its registry)."""
+    from apex_tpu.observability import MetricsRegistry
+    from apex_tpu.serving import EngineConfig, InferenceEngine
+
+    reg = MetricsRegistry()
+    return InferenceEngine(_model(), weights[1], EngineConfig(
+        max_slots=4, max_len=64, page_size=8), metrics=reg), reg
+
+
+def test_engine_holds_the_dense_layers_gate_and_up_apart(weights, served):
+    """The engine takes the interleaved tree the adapter builds and holds
+    the one dense layer's ``[2*ffn, h]`` weight as ``[2, ffn, h]``; the
+    gauge says how much was re-laid; the forward on what it holds is
+    ``model.apply`` on what it was given."""
+    _, tree = weights
+    eng, reg = served
+    given = tree["transformer"]["layers"][0]["mlp"]["dense_h_to_4h"][
+        "weight"]
+    held = eng._params["transformer"]["layers"][0]["mlp"]["dense_h_to_4h"][
+        "weight"]
+    assert given.shape == (2 * 96, 64) and held.shape == (2, 96, 64)
+    np.testing.assert_array_equal(held[0], given[0::2])
+    np.testing.assert_array_equal(held[1], given[1::2])
+    assert reg.gauges()["decode_weights_relaid_bytes"] == 2 * 96 * 64 * 4
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, 24), 0, 128)
+    model = _model()
+    want = jax.jit(model.apply)(tree, ids)
+    caches = init_kv_caches(model, 2, 32, stacked=False)
+    got, _ = jax.jit(lambda p, c, t: _cached_forward(model, p, c, t, 0))(
+        eng._params, caches, ids)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "suffix"])
+def test_serving_programs_make_no_lane_dim_of_two(served, program):
+    """No serving program of the gated model reshapes the dense layer's
+    product to ``[..., ffn, 2]``."""
+    from serving_reference import lane_pair_reshapes, serving_program_text
+
+    text = serving_program_text(served[0], program)
+    assert "stablehlo.dot_general" in text
+    assert lane_pair_reshapes(text, 96) == []
+
+
+def test_training_forward_keeps_its_lane_dim_of_two():
+    """The training forward takes the interleaved weight and slices the
+    pair as it did (its digests stand: ``PARENT_PROGRAMS`` below)."""
+    from serving_reference import lane_pair_reshapes
+
+    model = _model()
+    text = jax.jit(model.apply).lower(
+        jax.eval_shape(model.init, KEY),
+        jnp.zeros((1, 16), jnp.int32)).as_text()
+    assert len(lane_pair_reshapes(text, 96)) == 1
+
+
 def test_routed_streams_one_step_ahead_are_the_lock_step_engines(weights):
     """The routed model with one decode step in flight (the fed token
     sliced from in front of the routing counts of the step before)
@@ -252,6 +310,7 @@ def _lowered_programs():
     from apex_tpu.models.encoder_decoder import EncoderDecoderModel
     from apex_tpu.models.generation import generate
     from apex_tpu.transformer.enums import AttnMaskType
+    from serving_reference import serving_programs
 
     base = dict(num_layers=2, hidden_size=32, num_attention_heads=4,
                 vocab_size=64, max_position_embeddings=32,
@@ -291,20 +350,12 @@ def _lowered_programs():
     params = m.init(key)
     eng = InferenceEngine(m, params, EngineConfig(
         max_slots=4, max_len=32, page_size=8))
-    out["engine/paged-decode"] = (eng._decode_fn._fn, *eng._decode_args())
-    # the prefill programs, one bucket each, with the arguments
-    # InferenceEngine._prefill_into builds for them
-    row = jnp.asarray(eng._page_table_h[0])
-    prompt = jnp.zeros((1, 16), jnp.int32)
-    sampling = (jnp.float32(0.0), jnp.int32(64), jnp.int32(0))
-    aix = jnp.zeros(1, jnp.int32)
-    out["engine/paged-prefill"] = (
-        eng._prefill_fn._fn, eng._params, eng._caches, row, prompt,
-        jnp.int32(11), *sampling, aix, None)
-    out["engine/suffix-prefill"] = (
-        eng._suffix_fn._fn, eng._params, eng._caches, row, prompt,
-        jnp.int32(8), jnp.int32(3), jnp.int32(11), *sampling,
-        jnp.bool_(False), aix, None)
+    # decode, and the prefill programs, one bucket each, with the
+    # arguments InferenceEngine._prefill_into builds for them
+    names = {"decode": "paged-decode", "prefill": "paged-prefill",
+             "suffix": "suffix-prefill"}
+    for name, program in serving_programs(eng).items():
+        out["engine/" + names[name]] = program
     spec = InferenceEngine(m, params, EngineConfig(
         max_slots=4, max_len=32, page_size=8, speculation=3))
     out["engine/spec-decode"] = (spec._decode_fn._fn, *spec._decode_args())
@@ -314,14 +365,8 @@ def _lowered_programs():
     m = _model(jnp.bfloat16)
     eng = InferenceEngine(m, m.init(key), EngineConfig(
         max_slots=4, max_len=32, page_size=8))
-    out["routed/paged-decode"] = (eng._decode_fn._fn, *eng._decode_args())
-    out["routed/paged-prefill"] = (
-        eng._prefill_fn._fn, eng._params, eng._caches, row, prompt,
-        jnp.int32(11), *sampling, aix, None)
-    out["routed/suffix-prefill"] = (
-        eng._suffix_fn._fn, eng._params, eng._caches, row, prompt,
-        jnp.int32(8), jnp.int32(3), jnp.int32(11), *sampling,
-        jnp.bool_(False), aix, None)
+    for name, program in serving_programs(eng).items():
+        out["routed/" + names[name]] = program
     return out
 
 
@@ -334,13 +379,20 @@ def _lowered_programs():
 #: decode program the carried token vector and the mask that chooses
 #: between it and the host's tokens (``engine/paged-decode``, read anew
 #: there; the prefill programs and the spec-decode body kept theirs).
+#: PR 36 re-lays a gated dense layer's gate/up weight halves apart where
+#: the serving side takes the weights, so the four programs that serve a
+#: gated dense layer read a ``[2, ffn, h]`` weight and slice the product
+#: at ``ffn`` (``rope-rms-swiglu-gqa-window/generate`` and the three
+#: ``routed/*``, read anew there); every other digest, the gated model's
+#: ``init`` and ``loss`` among them, stood: training and the models that
+#: are not gated lower to the text they did.
 PARENT_PROGRAMS = {
     "gpt2-style/init": "54950bef44af6471",
     "gpt2-style/loss": "eff5da79fceee869",
     "gpt2-style/generate": "1e9382acb4c9d6d3",
     "rope-rms-swiglu-gqa-window/init": "4be10ee2967089e2",
     "rope-rms-swiglu-gqa-window/loss": "015f5b5ee09f107a",
-    "rope-rms-swiglu-gqa-window/generate": "c6d9513e97cd3827",
+    "rope-rms-swiglu-gqa-window/generate": "14b5ba9961434cde",
     "switch-moe-top2/init": "bc22a398d187ac0e",
     "switch-moe-top2/loss": "41e4e2b0e51b05d4",
     "switch-moe-top2/generate": "ff72039acdf97118",
@@ -355,10 +407,11 @@ PARENT_PROGRAMS = {
     "engine/spec-decode": "5d15c62e192aebbf",
     # read at the parent of PR 34 (commit 8cb1cae), which gave the router
     # its groups, the engine its latent kind and the chunk program's body
-    # a second cache form: the routed model's programs are the parent's
-    "routed/paged-decode": "974f933466642150",
-    "routed/paged-prefill": "62e2aa0c77207753",
-    "routed/suffix-prefill": "5b5ae1a983a1aa2a",
+    # a second cache form: the routed model's programs were the parent's
+    # until PR 36 (above), whose own these three are
+    "routed/paged-decode": "1602bcec0c68f676",
+    "routed/paged-prefill": "1dd8284305184d89",
+    "routed/suffix-prefill": "44e5bb840412f0d8",
 }
 
 

@@ -282,6 +282,63 @@ def test_engine_serves_the_reference_logits_in_float32(weights, budget):
         reg.gauges()["kv_pages_in_use"] * 3 * 8 * (16 + 128) * 4)
 
 
+@pytest.fixture(scope="module")
+def supervised(weights):
+    """(supervisor over the interleaved tree, as the cell builds it, its
+    registry)."""
+    from apex_tpu.observability import MetricsRegistry
+    from apex_tpu.serving import EngineConfig, EngineSupervisor
+
+    reg = MetricsRegistry()
+    return EngineSupervisor(_model(), weights[1], EngineConfig(
+        max_slots=4, max_len=64, page_size=8, prefill_token_budget=16),
+        metrics=reg), reg
+
+
+def test_engine_holds_the_dense_layers_gate_and_up_apart(weights,
+                                                         supervised):
+    """The supervisor takes the interleaved tree of ``program_tree`` and
+    keeps the dense layer's ``[2*ffn, h]`` weight as ``[2, ffn, h]``, the
+    caller's tree left as it was; its engines re-lay nothing more; the
+    gauge says what is held so; the forward on it is ``model.apply`` on
+    what was given."""
+    _, tree = weights
+    sup, reg = supervised
+
+    def dense(params):
+        return params["transformer"]["layers"][0]["mlp"]["dense_h_to_4h"][
+            "weight"]
+
+    given, kept, held = dense(tree), dense(sup._params), dense(
+        sup.engine._params)
+    assert given.shape == (2 * 96, 64) and kept.shape == (2, 96, 64)
+    assert held is kept
+    np.testing.assert_array_equal(held[0], given[0::2])
+    np.testing.assert_array_equal(held[1], given[1::2])
+    assert reg.gauges()["decode_weights_relaid_bytes"] == 2 * 96 * 64 * 4
+    assert dense(sup._build_engine()._params) is kept
+    assert reg.gauges()["decode_weights_relaid_bytes"] == 2 * 96 * 64 * 4
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, 24), 0, 128)
+    model = _model()
+    want = jax.jit(model.apply)(tree, ids)
+    caches = init_kv_caches(model, 2, 32, stacked=False)
+    got, _ = jax.jit(lambda p, c, t: _cached_forward(model, p, c, t, 0))(
+        sup.engine._params, caches, ids)
+    np.testing.assert_allclose(got, want, atol=5e-5)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "suffix"])
+def test_serving_programs_make_no_lane_dim_of_two(supervised, program):
+    """None of the three serving programs (the suffix program is the
+    chunk program) reshapes the dense layer's product to ``[..., ffn,
+    2]``."""
+    from serving_reference import lane_pair_reshapes, serving_program_text
+
+    text = serving_program_text(supervised[0].engine, program)
+    assert "stablehlo.dot_general" in text
+    assert lane_pair_reshapes(text, 96) == []
+
+
 def test_engine_with_a_share_counts_rows_elsewhere(weights):
     """An engine that holds experts 4..8 of 16: what it routes here and
     what it would hand to the other holders add up to every live row's

@@ -581,6 +581,37 @@ class TestShardedEngine:
         assert counters["decode_steps_overlapped"] >= 1
         assert counters["decode_rows_dropped"] == 0
 
+    def test_tp2_gated_weight_shards_its_ffn_axis(self, tp2_mesh):
+        """A gated model under tensor parallelism: the engine holds the
+        gate/up weight halves apart, ``[2, ffn, h]``, sharded on ``ffn``
+        (every rank: matched gate/up pairs, and the rows its slice of
+        ``dense_4h_to_h`` reads), and serves the tokens of the
+        per-request reference on the interleaved params."""
+        from jax.sharding import PartitionSpec as P
+
+        model = GPTModel(TransformerConfig(
+            num_layers=1, hidden_size=32, num_attention_heads=4,
+            vocab_size=64, max_position_embeddings=64, hidden_dropout=0.0,
+            attention_dropout=0.0, activation="swiglu",
+            untie_embeddings_and_output_weights=True, init_method_std=0.3))
+        params = model.init(jax.random.PRNGKey(0))
+        reqs = [Request(prompt=p, max_new_tokens=4, sampling=s)
+                for p, s in zip(_prompts([4, 6], seed=71), (
+                    SamplingParams(),
+                    SamplingParams(temperature=0.9, top_k=8, seed=5)))]
+        with ShardedEngine(model, params,
+                           EngineConfig(max_slots=2, max_len=16)) as eng:
+            mlp = eng._param_spec()["transformer"]["layers"][0]["mlp"]
+            assert mlp["dense_h_to_4h"]["weight"] == P(None, "tensor", None)
+            assert mlp["dense_4h_to_h"]["weight"] == P(None, "tensor")
+            assert eng._params["transformer"]["layers"][0]["mlp"][
+                "dense_h_to_4h"]["weight"].shape == (2, 128, 32)
+            out = eng.serve(reqs)
+            assert eng.decode_compiles == 1 and eng.decode_retraces == 0
+        for req, res in zip(reqs, out):
+            assert res.tokens == reference_stream(model, params, req, 16)
+        assert out[0].tokens != out[1].tokens
+
     @pytest.mark.slow  # TP model parity: the slow-tier class (ROADMAP)
     def test_tp2_token_exact_vs_unsharded(self, small, tp2_mesh):
         """Acceptance: ShardedEngine decode on a tp=2 CPU mesh is

@@ -598,3 +598,80 @@ class TestShardedAdapters:
             assert sh.decode_retraces == 0
         for a, b in zip(base, out):
             assert a.tokens == b.tokens
+
+
+# ---------------------------------------------------------------------------
+# a gated model: the engine holds the MLP's gate/up weight halves apart,
+# the adapter's B keeps its interleaved out columns
+
+
+@pytest.fixture(scope="module")
+def gated():
+    model = GPTModel(TransformerConfig(
+        num_layers=2, hidden_size=32, num_attention_heads=4, vocab_size=64,
+        max_position_embeddings=64, hidden_dropout=0.0,
+        attention_dropout=0.0, activation="swiglu",
+        untie_embeddings_and_output_weights=True, init_method_std=0.3))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _gated_requests():
+    prompts = _prompts([5, 9, 3], seed=23)
+    return [Request(prompt=list(prompts[0]), max_new_tokens=5,
+                    sampling=SamplingParams(adapter_id="a")),
+            Request(prompt=list(prompts[1]), max_new_tokens=4,
+                    sampling=SamplingParams(adapter_id="a", temperature=0.8,
+                                            top_k=8, seed=11)),
+            Request(prompt=list(prompts[2]), max_new_tokens=5)]
+
+
+class TestGatedModelAdapters:
+    def test_delta_meets_its_columns_under_the_relaid_weight(self, gated):
+        """An adapter on ``dense_h_to_4h`` of a gated model: the engine
+        (weight re-laid to ``[2, ffn, h]``, delta re-ordered to match)
+        serves the tokens of an engine over ``merge_adapter``'d params,
+        whose interleaved fold knows nothing of the serving form; greedy
+        and sampled, base traffic beside it."""
+        model, params = gated
+        store, factors = _store(model.config, ids=("a",), scale=0.3)
+        ec = EngineConfig(max_slots=4, max_len=32, retrace_budget=0)
+        with InferenceEngine(model, params, ec, adapters=store) as eng:
+            got = eng.serve(_gated_requests())
+            assert eng.decode_retraces == 0
+        merged = merge_adapter(params, factors["a"])
+        for q, r in zip(_gated_requests(), got):
+            ref = Request(prompt=list(q.prompt),
+                          max_new_tokens=q.max_new_tokens,
+                          sampling=SamplingParams(
+                              temperature=q.sampling.temperature,
+                              top_k=q.sampling.top_k, seed=q.sampling.seed))
+            with InferenceEngine(
+                    model, merged if q.sampling.adapter_id else params,
+                    ec) as one:
+                assert r.tokens == one.serve([ref])[0].tokens
+        # the adapter is no bystander: the same prompt without it differs
+        bare = Request(prompt=list(_gated_requests()[0].prompt),
+                       max_new_tokens=5)
+        with InferenceEngine(model, params, ec) as base:
+            assert base.serve([bare])[0].tokens != got[0].tokens
+
+    def test_tp2_delta_stays_rank_local(self, gated, tp2_mesh):
+        """Under tensor parallelism ``B`` shards its interleaved out
+        columns and the weight its ``ffn`` axis: each rank's delta slice
+        holds the pairs of its own ``ffn`` rows, so the re-ordering is
+        rank-local and the tokens are the unsharded engine's."""
+        from apex_tpu.transformer import parallel_state
+
+        model, params = gated
+        store, _ = _store(model.config, ids=("a",), scale=0.3)
+        ec = EngineConfig(max_slots=4, max_len=32, retrace_budget=0)
+        parallel_state.destroy_model_parallel()
+        with InferenceEngine(model, params, ec, adapters=store) as ref:
+            base = ref.serve(_gated_requests())
+        parallel_state.initialize_model_parallel(
+            tensor_model_parallel_size=2)
+        with ShardedEngine(model, params, ec, adapters=store) as sh:
+            out = sh.serve(_gated_requests())
+            assert sh.decode_retraces == 0
+        for a, b in zip(base, out):
+            assert a.tokens == b.tokens

@@ -19,7 +19,13 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import GPTModel, TransformerConfig
-from apex_tpu.models.generation import decode_step, init_kv_caches
+from apex_tpu.models.generation import (
+    decode_step,
+    init_kv_caches,
+    is_gated_mlp_weight,
+    preslice_layer_params,
+    split_gated_mlp_params,
+)
 
 
 def _cfg(**kw):
@@ -252,6 +258,117 @@ class TestActivations:
     def test_invalid_activation_rejected(self):
         with pytest.raises(ValueError, match="activation"):
             _cfg(activation="swish")
+
+
+class TestGatedWeightHalvesApart:
+    """What the serving side does to a gated ``ParallelMLP`` at intake
+    (``split_gated_mlp_params``): the interleaved ``[2*ffn, h]`` weight
+    re-laid once to ``[2, ffn, h]``, and ``ParallelMLP.apply`` computing
+    the same numbers from either form."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("act", ["swiglu", "geglu"])
+    def test_apply_matches_the_interleaved_form(self, act, dtype):
+        from apex_tpu.models.transformer import ParallelMLP
+
+        dt = jnp.dtype(dtype)
+        c = _cfg(activation=act, ffn_hidden_size=96)
+        mlp = ParallelMLP(c)
+        params = jax.tree.map(lambda x: x.astype(dt),
+                              mlp.init(jax.random.PRNGKey(0)))
+        x = jax.random.normal(jax.random.PRNGKey(1), (5, 3, 64)).astype(dt)
+        apart, nbytes = split_gated_mlp_params({"mlp": params}, c)
+        w = apart["mlp"]["dense_h_to_4h"]["weight"]
+        assert w.shape == (2, 96, 64) and nbytes == w.size * dt.itemsize
+        inter = params["dense_h_to_4h"]["weight"]
+        np.testing.assert_array_equal(np.asarray(w[0], np.float32),
+                                      np.asarray(inter[0::2], np.float32))
+        np.testing.assert_array_equal(np.asarray(w[1], np.float32),
+                                      np.asarray(inter[1::2], np.float32))
+        want = np.asarray(mlp.apply(params, x), np.float32)
+        got = np.asarray(mlp.apply(apart["mlp"], x), np.float32)
+        if dt == jnp.float32:
+            # each output column is the same dot over h
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("act", ["swiglu", "geglu"])
+    def test_lora_delta_lands_on_the_same_columns(self, act):
+        """The LoRA delta's out columns are interleaved, as ``B``'s are;
+        under the re-laid weight each still meets its own column."""
+        from apex_tpu.models.transformer import ParallelMLP
+
+        c = _cfg(activation=act, ffn_hidden_size=96)
+        mlp = ParallelMLP(c)
+        params = mlp.init(jax.random.PRNGKey(0))
+        ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        x = jax.random.normal(ks[0], (5, 3, 64))
+        lora = {"A": jax.random.normal(ks[1], (3, 64, 4)) * 0.3,
+                "B": jax.random.normal(ks[2], (3, 4, 2 * 96)) * 0.3}
+        apart, _ = split_gated_mlp_params(params, c)
+        want = mlp.apply(params, x, lora=lora)
+        assert float(jnp.abs(want - mlp.apply(params, x)).max()) > 1e-3
+        np.testing.assert_array_equal(
+            np.asarray(mlp.apply(apart, x, lora=lora)), np.asarray(want))
+
+    def test_stacked_and_listed_layers_are_both_relaid(self):
+        model = GPTModel(_cfg(activation="swiglu"))
+        params = model.init(jax.random.PRNGKey(0))
+        stacked, n_stacked = split_gated_mlp_params(params, model.config)
+        w = stacked["transformer"]["layers"]["mlp"]["dense_h_to_4h"][
+            "weight"]
+        assert w.shape == (2, 2, 4 * 64, 64)          # [L, 2, ffn, h]
+        listed, n_listed = split_gated_mlp_params(
+            preslice_layer_params(params, 2), model.config)
+        for i, layer in enumerate(listed["transformer"]["layers"]):
+            np.testing.assert_array_equal(
+                np.asarray(layer["mlp"]["dense_h_to_4h"]["weight"]),
+                np.asarray(w[i]))
+        assert n_stacked == n_listed == w.size * 4
+
+    def test_identity_on_a_relaid_tree_and_every_other_leaf(self):
+        model = GPTModel(_cfg(activation="swiglu"))
+        params = model.init(jax.random.PRNGKey(0))
+        once, n_once = split_gated_mlp_params(params, model.config)
+        twice, n_twice = split_gated_mlp_params(once, model.config)
+        assert n_once == n_twice > 0
+        before = jax.tree_util.tree_flatten_with_path(params)[0]
+        for (path, x), y, z in zip(before, jax.tree.leaves(once),
+                                   jax.tree.leaves(twice)):
+            assert z is y                     # nothing is re-laid twice
+            if not is_gated_mlp_weight(path):
+                assert y is x, path           # nor anything else touched
+        assert sum(is_gated_mlp_weight(p) for p, _ in before) == 1
+
+    @pytest.mark.parametrize("act", ["gelu", "relu"])
+    def test_identity_on_a_model_that_is_not_gated(self, act):
+        model = GPTModel(_cfg(activation=act))
+        params = model.init(jax.random.PRNGKey(0))
+        same, nbytes = split_gated_mlp_params(params, model.config)
+        assert same is params and nbytes == 0
+
+    def test_generate_matches_the_interleaved_decode(self):
+        """``generate()`` re-lays the weight at intake; its greedy stream
+        is the one ``decode_step`` gives on the interleaved params."""
+        from apex_tpu.models.generation import generate
+
+        model = GPTModel(_cfg(
+            activation="swiglu", untie_embeddings_and_output_weights=True,
+            init_method_std=0.3))
+        params = model.init(jax.random.PRNGKey(0))
+        prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 5), 0, 64)
+        out = np.asarray(generate(model, params, prompt, 6, max_len=16))
+        caches = init_kv_caches(model, 2, 16)
+        tok, want = prompt[:, 0], []
+        for i in range(10):
+            logits, caches = decode_step(model, params, caches, tok, i)
+            tok = (prompt[:, i + 1] if i + 1 < 5
+                   else jnp.argmax(logits, -1).astype(prompt.dtype))
+            if i + 1 >= 5:
+                want.append(np.asarray(tok))
+        np.testing.assert_array_equal(out[:, 5:], np.stack(want, 1))
+        assert len(set(out[0, 5:].tolist())) > 1   # a stream that varies
 
 
 class TestNormalization:

@@ -442,6 +442,51 @@ class TestPagedEngine:
             assert r.tokens == reference_stream(model, params, req, 16), \
                 (r.request_id, req.sampling)
 
+    @pytest.mark.parametrize("act", ["swiglu", "geglu"])
+    def test_gated_weight_held_apart_token_exact_vs_reference(self, act):
+        """An engine built from the interleaved params of a gated model
+        holds each layer's gate/up weight as ``[2, ffn, h]``, says so in
+        ``decode_weights_relaid_bytes``, and serves the tokens of the
+        per-request reference, which decodes on the interleaved params
+        as they were handed over."""
+        model = GPTModel(TransformerConfig(
+            num_layers=2, hidden_size=32, num_attention_heads=4,
+            vocab_size=64, max_position_embeddings=64, hidden_dropout=0.0,
+            attention_dropout=0.0, activation=act,
+            untie_embeddings_and_output_weights=True, init_method_std=0.3))
+        params = model.init(jax.random.PRNGKey(0))
+        reg = MetricsRegistry()
+        eng = InferenceEngine(model, params, EngineConfig(
+            max_slots=3, max_len=16, page_size=4), metrics=reg)
+        given = params["transformer"]["layers"]["mlp"]["dense_h_to_4h"][
+            "weight"]
+        assert given.shape == (2, 2 * 128, 32)     # the caller's: as it was
+        for i, layer in enumerate(eng._params["transformer"]["layers"]):
+            held = layer["mlp"]["dense_h_to_4h"]["weight"]
+            assert held.shape == (2, 128, 32)
+            np.testing.assert_array_equal(held[0], given[i, 0::2])
+            np.testing.assert_array_equal(held[1], given[i, 1::2])
+        assert reg.gauges()["decode_weights_relaid_bytes"] == given.nbytes
+        with eng:
+            out = eng.serve(self._requests())
+            assert eng.decode_retraces == 0
+        streams = set()
+        for r, req in zip(out, self._requests()):
+            assert r.tokens == reference_stream(model, params, req, 16), \
+                (r.request_id, req.sampling)
+            streams.add(tuple(r.tokens[:3]))
+        assert len(streams) > 1       # streams that depend on the prompt
+
+    def test_model_that_is_not_gated_has_nothing_relaid(self, small):
+        model, params = small
+        reg = MetricsRegistry()
+        with InferenceEngine(model, params, EngineConfig(
+                max_slots=3, max_len=16, page_size=4), metrics=reg) as eng:
+            w = eng._params["transformer"]["layers"][0]["mlp"][
+                "dense_h_to_4h"]["weight"]
+            assert w.shape == (4 * 32, 32)
+        assert reg.gauges()["decode_weights_relaid_bytes"] == 0
+
     def test_close_resets_page_pool(self, small):
         model, params = small
         eng = InferenceEngine(model, params, EngineConfig(
