@@ -7,10 +7,8 @@ The program's two ways of putting a name on work, and the names:
   (a ``ProfilerCapture`` window, ``jax.profiler.start_trace``, a
   benchmark's traced run) the span is written into the profiler's
   trace, on the clock of the device ops, with its keyword attributes as
-  the event's stats; with no session on it costs a flag test. Given a
-  ``registry`` it also observes its host wall time into the
-  ``span/<name>_s`` histogram. A host span names host work only: it puts
-  nothing into a compiled program.
+  the event's stats; with no session on it costs a flag test. A host
+  span names host work only: it puts nothing into a compiled program.
 - names INSIDE jitted code are :func:`~apex_tpu.utils.profiling.
   nvtx_range` (``jax.named_scope``): the scope becomes a path element of
   every enclosed instruction's ``op_name``, which the trace file carries
@@ -28,9 +26,7 @@ benchmark's metric files (``cellbench/metrics/*.json``).
 
 from __future__ import annotations
 
-import contextlib
 import os
-import time
 from typing import Callable, Optional
 
 import jax
@@ -45,7 +41,12 @@ __all__ = ["span", "recording", "ProfilerCapture", "TICK_LEAF_SPANS"]
 TICK_SUPERVISOR = "supervisor.tick"  # all of EngineSupervisor.tick
 TICK_ENGINE = "engine.tick"          # all of InferenceEngine.tick
 TICK_PREFILL = "tick.prefill"        # one prefill or chunk: trace_id,
-#                                      prompt_tokens, bucket (chunk)
+#                                      prompt_tokens (the whole prompt),
+#                                      bucket (the program's padded
+#                                      length), tokens (the prompt tokens
+#                                      the program computed: the prompt,
+#                                      a prefix hit's suffix or the
+#                                      chunk), chunk (chunk)
 # leaf spans: disjoint, each around one step of the tick; the counts that
 # ride on them as attributes follow the colon
 TICK_SCHEDULE = "tick.schedule"      # expire, evict, preempt, pop the
@@ -95,27 +96,12 @@ SCOPE_MLA_PREFILL = "mla_prefill_attention"     # the flash call of a prefill
 #                                                 or a chunk (Pallas name)
 
 
-@contextlib.contextmanager
-def _observed(annotation, name: str, registry):
-    t0 = time.perf_counter()
-    try:
-        with annotation:
-            yield annotation
-    finally:
-        # host-side wall duration: dispatch time, not device time
-        registry.observe(f"span/{name}_s", time.perf_counter() - t0)
-
-
-def span(name: str, registry=None, **attrs):
+def span(name: str, **attrs):
     """``with span("tick.upload", arrays=7, bytes=n) as s:`` — a host
     span in the profiler's trace (see the module docstring). ``s.
     set_metadata(k=v)`` adds attributes known only at the end of the
-    span. With ``registry`` the enclosed host wall time is also observed
-    into the ``span/<name>_s`` histogram."""
-    annotation = jax.profiler.TraceAnnotation(name, **attrs)
-    if registry is None:
-        return annotation
-    return _observed(annotation, name, registry)
+    span."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def recording() -> bool:

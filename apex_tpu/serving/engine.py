@@ -1670,7 +1670,8 @@ class InferenceEngine:
                               rows=bucket):
                         first, finite, self._caches = self._prefill_fn(*args)
                 del args
-                group.set_metadata(bucket=bucket)
+                group.set_metadata(bucket=bucket, tokens=suffix_len
+                                   if shared_used else request.prompt_len)
                 with span(TICK_READBACK, reads=1, bytes=4):
                     first = int(np.asarray(first))
             except Exception:
@@ -1820,7 +1821,7 @@ class InferenceEngine:
                 aix = jnp.asarray([rec.adapter_ix], jnp.int32)
                 topk = jnp.int32(sp.top_k if sp.top_k is not None
                                  else self._vocab)
-            group.set_metadata(bucket=bucket)
+            group.set_metadata(bucket=bucket, tokens=chunk_len)
             try:
                 if self._faults is not None:
                     self._faults.before_prefill()

@@ -6,13 +6,14 @@ scope names inside the step programs (``nvtx_range`` =
 A tiny engine is ticked under ``jax.profiler.start_trace`` on the CPU and
 the trace file is read back: every ``engine.tick`` holds the five leaf
 spans in order, disjoint, with their attributes, and a prefill carries its
-request's ``trace_id``; a decode dispatch says whether a step was in
-flight ahead of it, a read-back how many went out behind the step it
-reads. With no profiler on, a tick writes nothing new into the registry. The compiled text of a decode program, a prefill
+request's ``trace_id`` and the prompt tokens its program computed; a
+decode dispatch says whether a step was in flight ahead of it, a read-back
+how many went out behind the step it reads. With no profiler on, a tick writes nothing new into the registry. The compiled text of a decode program, a prefill
 program and a sharded train step carries every scope name in some
 ``op_name``. Token streams do not change under the profiler.
 """
 
+import dataclasses
 import glob
 import os
 import re
@@ -196,6 +197,50 @@ def test_a_prefill_span_links_the_tick_to_the_request(traced_ticks):
         inner = [x[0] for x in _inside(events, e)]
         for leaf in TICK_LEAF_SPANS:
             assert leaf in inner
+
+
+@pytest.mark.parametrize("kind", ["monolithic", "prefix_hit", "chunked"])
+def test_a_prefill_span_counts_the_tokens_its_program_computed(
+        small, tmp_path, kind):
+    """``tokens`` is what the program dispatched under the span computed:
+    the prompt, a prefix hit's suffix, or the chunk. Never more than the
+    ``bucket`` it was padded to; a request's spans add up to the tokens it
+    prefilled. A span under which no program went out (a chunk with no
+    room left in the tick's budget) carries neither."""
+    model, params = small
+    config = ENGINE if kind != "chunked" else dataclasses.replace(
+        ENGINE, prefill_token_budget=ENGINE.page_size)
+    engine = InferenceEngine(model, params, config)
+    rng = np.random.RandomState(7)
+    if kind == "prefix_hit":
+        # an earlier request interns two full pages; the first traced
+        # prompt prefills its 5-token suffix after them, the second is
+        # those two pages whole and recomputes its last token only
+        shared = rng.randint(0, 64, size=2 * ENGINE.page_size).tolist()
+        engine.serve([Request(prompt=shared + [1, 2, 3], max_new_tokens=2)])
+        prompts = [shared + rng.randint(0, 64, size=5).tolist(), shared]
+        prefilled = [5, 1]
+    else:
+        prompts = [rng.randint(0, 64, size=n).tolist() for n in (5, 20, 27)]
+        prefilled = [5, 20, 27]
+    requests = [Request(prompt=p, max_new_tokens=3) for p in prompts]
+
+    _, events = _traced(lambda: engine.serve(requests), tmp_path)
+    engine.close()
+    spans = [e[3] for e in events if e[0] == tracing.TICK_PREFILL]
+    assert all(("tokens" in st) == ("bucket" in st) for st in spans)
+    tokens, count = {}, {}
+    for st in (st for st in spans if "bucket" in st):
+        assert 1 <= int(st["tokens"]) <= int(st["bucket"]), st
+        tokens[st["trace_id"]] = tokens.get(st["trace_id"], 0) \
+            + int(st["tokens"])
+        count[st["trace_id"]] = count.get(st["trace_id"], 0) + 1
+    assert tokens == {r.trace_id: n for r, n in zip(requests, prefilled)}
+    if kind == "chunked":
+        # the 20- and 27-token prompts take several chunks of 8 or less
+        assert sorted(count.values())[-2:] >= [3, 4]
+    else:
+        assert set(count.values()) == {1}
 
 
 def test_supervisor_tick_encloses_the_engine_tick(small, tmp_path):
