@@ -176,6 +176,12 @@ _COUNTERS = ("requests_submitted", "requests_eos", "requests_length",
              # was read (the price of learning EOS, a cancel or a
              # poisoned row one step late)
              "decode_steps_overlapped", "decode_rows_dropped",
+             # decode rows fed a first token that a prefill or final
+             # chunk of the same tick put on the device, unread by the
+             # host (== prefills on the plain path, but for requests
+             # whose first token is their last and prefills read early
+             # for an admission that shares their prefix)
+             "first_tokens_carried",
              "tokens_generated", "slots_quarantined",
              "requests_shed_pages",
              # multi-LoRA (docs/serving.md#multi-lora): submits whose
@@ -370,6 +376,24 @@ class _Flight(NamedTuple):
     nxt: jax.Array
     finite: jax.Array
     call: Optional[int]
+
+
+class _Unread(NamedTuple):
+    """A prefill or chunk dispatched this tick whose results the host
+    has not read: the record, the program's first token (None for a
+    chunk that is not the prompt's last: its sample is thrown away) and
+    its finite flag."""
+
+    rec: _Active
+    first: Optional[jax.Array]
+    finite: jax.Array
+
+
+def _carry_first(carry, slot, first):
+    """``carry`` with a prefill's first token at the slot's row: the
+    vector the next decode step takes a carried slot's fed token from
+    (None under speculation, whose steps carry nothing)."""
+    return None if carry is None else carry.at[slot].set(first)
 
 
 def _kth_largest(rows, k):
@@ -641,10 +665,17 @@ class InferenceEngine:
         #: layer). Speculation reads every step in the tick that
         #: dispatched it: its draft is built from the host's tokens.
         self._flight: Optional[_Flight] = None
+        self._carry = None
         if not self._spec:
             routed_calls = (c.num_layers - c.num_dense_layers
                             if self._routed else 0)
             self._carry = jnp.zeros(n + 3 * routed_calls, jnp.int32)
+        #: the prefills and chunks dispatched this tick and not read yet:
+        #: a prefill puts its first token into ``_carry`` on the device,
+        #: the tick's decode step is fed it from there, and the host
+        #: reads and commits it after that step went out
+        #: (``_read_prefills``; with speculation at once)
+        self._unread: List[_Unread] = []
         #: what one decode step uploads (the ``tick.upload`` span's
         #: attributes): the host arrays of ``_decode_args``
         up = [self._window_h if self._spec else self._tokens_h,
@@ -784,7 +815,7 @@ class InferenceEngine:
 
     def _paged_prefill_body(self, params, caches, page_row, prompt,
                             prompt_len, temp, topk, seed, adapter_ix,
-                            lora):
+                            lora, carry, slot):
         # the EXACT prefill generate() runs (4D per-layer list -> the
         # cache_index==0 causal-flash fast path) at the bucket-padded
         # length, so outputs stay token-exact; the flattened rows then
@@ -792,6 +823,10 @@ class InferenceEngine:
         # time. Chunks past the mapped count (bucket padding)
         # carry the sentinel and drop; garbage rows inside the last
         # mapped page are causally masked by the row's position forever.
+        # The first token also goes into ``carry`` at the slot's row, so
+        # the tick's decode step is fed it on the device
+        # (_carry_first; carry is not donated: the step in flight's
+        # result, which the host reads after this tick's step went out)
         model = self.model
         small = init_kv_caches(model, 1, prompt.shape[1], stacked=False)
         logits, small = _cached_forward(model, params, small, prompt, 0,
@@ -826,14 +861,16 @@ class InferenceEngine:
                  bv.at[dest].set(fv1.reshape(n_chunks, ps, -1)
                                  .astype(bv.dtype), mode="drop")))
         first = _sample_tokens(logits[0], temp[None], topk[None],
-                               seed[None], prompt_len[None])
+                               seed[None], prompt_len[None])[0]
         # finite flag gates publishing these pages to the prefix-intern
         # index: a poisoned prefill must never become a shared prefix
-        return first[0], jnp.all(jnp.isfinite(logits)), new
+        return (first, jnp.all(jnp.isfinite(logits)), new,
+                _carry_first(carry, slot, first))
 
     def _suffix_prefill_body(self, params, caches, page_row, suffix,
                              start, suffix_len, prompt_len, temp, topk,
-                             seed, skip_first, adapter_ix, lora):
+                             seed, skip_first, adapter_ix, lora, carry,
+                             slot):
         """Prefill ONLY the suffix of a prefix-cache hit.
 
         The slot's page table already maps the shared prefix pages for
@@ -847,6 +884,8 @@ class InferenceEngine:
         token logits), the recomputed row's scatter is masked so the
         boundary page keeps its original bitwise K/V — the copy-on-write
         seam with the copy elided, since the row is already resident.
+        The sampled token goes into ``carry`` at ``slot`` as the full
+        prefill's does.
         """
         model = self.model
         ps = self.config.page_size
@@ -928,8 +967,9 @@ class InferenceEngine:
                      bv.at[dest_page, dest_off].set(
                          rows(fv).astype(bv.dtype), mode="drop")))
         first = _sample_tokens(logits[0], temp[None], topk[None],
-                               seed[None], prompt_len[None])
-        return first[0], jnp.all(jnp.isfinite(logits)), new
+                               seed[None], prompt_len[None])[0]
+        return (first, jnp.all(jnp.isfinite(logits)), new,
+                _carry_first(carry, slot, first))
 
     def _build_step_fns(self, donate: bool):
         """Compile the device programs:
@@ -944,7 +984,10 @@ class InferenceEngine:
         so donation and the watchdogs are shared. With ``speculation``
         on, the decode program is the windowed verify body: the [n]
         token vector becomes the [n, k] window matrix, and the carried
-        token vector and its mask are not among its arguments."""
+        token vector and its mask are not among its arguments. The two
+        prefill programs take the carried vector and the slot's index
+        last and return the vector with their first token put in (None
+        in and out under speculation)."""
         donate_args = (1,) if donate else ()
         decode_body = (self._spec_decode_body if self._spec
                        else self._paged_decode_body)
@@ -1169,11 +1212,12 @@ class InferenceEngine:
 
     def tick(self) -> List[RequestResult]:
         """One scheduler iteration: expire deadlines, evict cancellations,
-        admit+prefill FCFS (decode-starvation capped), then dispatch one
-        batched decode step over the active slots and read and commit
-        the step dispatched a tick ago (with speculation: this tick's).
-        Returns the requests that reached a terminal state during this
-        tick."""
+        dispatch admissions' prefills FCFS (decode-starvation capped),
+        then dispatch one batched decode step over the active slots, read
+        and commit the tick's prefills, and read and commit the step
+        dispatched a tick ago (with speculation: prefills and step are
+        read at once). Returns the requests that reached a terminal state
+        during this tick."""
         if self._closed:
             raise RuntimeError("engine is closed")
         finished: List[RequestResult] = []
@@ -1265,6 +1309,7 @@ class InferenceEngine:
             # a step nobody will read: its rows are thrown away
             self.metrics.inc("decode_rows_dropped", len(self._flight.rows))
             self._flight = None
+        self._unread = []
         self._active.clear()
         self._prefilling.clear()
         self._parked.clear()
@@ -1531,9 +1576,11 @@ class InferenceEngine:
                 for request, submit_ts in shed:
                     finished.append(
                         self._shed_pages(request, submit_ts, now))
-                if not batch:
-                    break       # head deferred (pages) or queue drained
-                request, submit_ts = batch[0]
+            if not batch:
+                break           # head deferred (pages) or queue drained
+            request, submit_ts = batch[0]
+            self._read_prefixes_ahead(request, finished)
+            with span(TICK_SCHEDULE):
                 slot = self.slots.allocate()
                 assert slot is not None
                 rec = self._begin_chunked_prefill(request, slot, submit_ts)
@@ -1564,6 +1611,12 @@ class InferenceEngine:
 
     def _prefill_into(self, request: Request, slot: int, submit_ts: float,
                       finished: List[RequestResult]) -> None:
+        """Dispatch ``request``'s prefill into ``slot`` and put the slot
+        in the batch at ``position = prompt_len``, one token owed: its
+        first token reaches the tick's decode step on the device and the
+        host, in ``_read_prefills``. Under speculation it is read
+        here."""
+        self._read_prefixes_ahead(request, finished)
         with span(TICK_PREFILL, trace_id=request.trace_id,
                   prompt_tokens=request.prompt_len) as group:
             with span(TICK_SCHEDULE):
@@ -1644,13 +1697,14 @@ class InferenceEngine:
                             jnp.int32(request.prompt_len),
                             jnp.float32(sp.temperature), topk,
                             jnp.int32(sp.seed), jnp.bool_(skip_first), aix,
-                            bank)
+                            bank, *self._carry_args(slot))
                         up.set_metadata(
                             arrays=8, bytes=suffix.nbytes
                             + self._page_table_h[slot].nbytes)
                     with span(TICK_DISPATCH, program="suffix_prefill",
                               rows=bucket):
-                        first, finite, self._caches = self._suffix_fn(*args)
+                        first, finite, self._caches, carry = \
+                            self._suffix_fn(*args)
                 else:
                     with span(TICK_UPLOAD) as up:
                         bucket = bucket_for(request.prompt_len,
@@ -1662,18 +1716,18 @@ class InferenceEngine:
                             jnp.asarray(self._page_table_h[slot]),
                             jnp.asarray(padded), jnp.int32(request.prompt_len),
                             jnp.float32(sp.temperature), topk,
-                            jnp.int32(sp.seed), aix, bank)
+                            jnp.int32(sp.seed), aix, bank,
+                            *self._carry_args(slot))
                         up.set_metadata(
                             arrays=5, bytes=padded.nbytes
                             + self._page_table_h[slot].nbytes)
                     with span(TICK_DISPATCH, program="paged_prefill",
                               rows=bucket):
-                        first, finite, self._caches = self._prefill_fn(*args)
+                        first, finite, self._caches, carry = \
+                            self._prefill_fn(*args)
                 del args
                 group.set_metadata(bucket=bucket, tokens=suffix_len
                                    if shared_used else request.prompt_len)
-                with span(TICK_READBACK, reads=1, bytes=4):
-                    first = int(np.asarray(first))
             except Exception:
                 # keep the pool invariants even as the failure propagates:
                 # the slot never held committed state (nothing scattered, or
@@ -1681,39 +1735,18 @@ class InferenceEngine:
                 self.slots.release(slot)
                 self._release_pages(rec)
                 raise
-            with span(TICK_COMMIT, tokens=1) as commit:
-                if self.config.prefix_cache:
-                    if shared_used:
-                        self.metrics.inc("prefix_hits")
-                        self.metrics.inc("prefix_pages_shared", shared_used)
-                    else:
-                        self.metrics.inc("prefix_misses")
-                    # publish the prompt's full pages (shared run + freshly
-                    # prefilled privates) so later prompts hit; gated on
-                    # finite logits — a poisoned prefill must never be
-                    # shared. On an exact repeat this is a no-op; a longer
-                    # prompt upgrades the subsumed shorter entry.
-                    if chain and bool(np.asarray(finite)):
-                        self.pages.intern_prefix(
-                            chain,
-                            [int(p)
-                             for p in self._page_table_h[slot][:len(chain)]])
-                rec.prefill_end = clock.now()
-                rec.tokens.append(first)
-                rec.last_token = first
-                # token #1 lands with the prefill result — TTFT is submit ->
-                # here
-                rec.first_token_ts = rec.last_token_ts = rec.prefill_end
+            with span(TICK_SCHEDULE):
+                # what the decode step needs is known without the token:
+                # the slot's position, page row and sampling row
+                self._carry = carry
+                rec.chain, rec.shared_used = chain, shared_used
                 rec.position = request.prompt_len
                 self._active[slot] = rec
                 self.admission_log.append(request.request_id)
-                self.metrics.inc("prefills")
-                self.metrics.inc("tokens_generated")
                 self._sync_slot(rec)
-                done = self._finish_reason(rec, first)
-                if done is not None:
-                    finished.append(self._retire(rec, done, clock.now()))
-                    commit.set_metadata(retired=1)
+                self._queue_read(rec, first, finite)
+        if self._spec:
+            self._read_prefills(finished)
 
     def _begin_chunked_prefill(self, request: Request, slot: int,
                                submit_ts: float) -> Optional[_Active]:
@@ -1836,14 +1869,12 @@ class InferenceEngine:
                         jnp.int32(sp.seed),
                         jnp.bool_(rec.skip_first
                                   and rec.prefill_chunks == 0),
-                        aix, self._bank)
+                        aix, self._bank, *self._carry_args(rec.slot))
                 with span(TICK_DISPATCH, program="suffix_prefill",
                           rows=bucket):
-                    first, finite, self._caches = self._suffix_fn(*args)
+                    first, finite, self._caches, carry = \
+                        self._suffix_fn(*args)
                 del args
-                with span(TICK_READBACK, reads=2, bytes=5):
-                    rec.finite_ok = rec.finite_ok and bool(np.asarray(finite))
-                    first = int(np.asarray(first))
             except Exception:
                 # same failure contract as the monolithic prefill: the slot
                 # never held committed state — release everything as the
@@ -1855,54 +1886,137 @@ class InferenceEngine:
                 self._clear_slot(rec.slot)
                 raise
             with span(TICK_COMMIT):
+                self._carry = carry
                 rec.prefill_pos += chunk_len
                 rec.prefill_chunks += 1
                 self.metrics.inc("prefill_chunks")
                 self._chunk_tokens_tick += chunk_len
-                if rec.prefill_pos < request.prompt_len:
-                    rec.chunk_marks.append(clock.now())
-                else:
-                    self._complete_chunked_prefill(rec, first, finished)
-            return chunk_len
+                last = rec.prefill_pos >= request.prompt_len
+                if last:
+                    self._complete_chunked_prefill(rec)
+                self._queue_read(rec, first if last else None, finite)
+        if self._spec:
+            self._read_prefills(finished)
+        return chunk_len
 
-    def _complete_chunked_prefill(self, rec: _Active, first: int,
-                                  finished: List[RequestResult]) -> None:
-        """Final chunk landed: publish the page row to the global table
-        (the batched decode step sees — and appends to — the slot from
-        the next step on), intern the prefix, and promote the rec to
-        the active set with its first token."""
-        request = rec.request
+    def _complete_chunked_prefill(self, rec: _Active) -> None:
+        """Final chunk dispatched: publish the page row to the global
+        table (the batched decode step sees — and appends to — the slot
+        from this tick's step on) and promote the rec to the active set,
+        one token owed, as a monolithic prefill's; ``_read_prefills``
+        commits the token."""
         slot = rec.slot
         del self._prefilling[slot]
         self._page_table_h[slot] = rec.page_row
+        rec.position = rec.request.prompt_len
+        self._active[slot] = rec
+        self._sync_slot(rec)
+
+    def _carry_args(self, slot: int) -> tuple:
+        """The last two arguments of a prefill program: the carried token
+        vector and the slot's index (None, None under speculation)."""
+        if self._carry is None:
+            return None, None
+        return self._carry, jnp.int32(slot)
+
+    def _queue_read(self, rec: _Active, first: Optional[jax.Array],
+                    finite: jax.Array) -> None:
+        """Leave a prefill's results to ``_read_prefills``; their copies
+        to the host start now, so the read finds them there."""
+        for value in (first, finite):
+            if value is not None:
+                value.copy_to_host_async()
+        self._unread.append(_Unread(rec, first, finite))
+
+    def _read_prefixes_ahead(self, request: Request,
+                             finished: List[RequestResult]) -> None:
+        """Read this tick's prefills before ``request`` plans its prefix
+        where one of them may intern pages it would share: one whose
+        prompt starts with the same page (the chain is cumulative, so no
+        other can match any of it). The lock-step order interned each
+        prefill before the next admission planned, and this keeps the
+        hits it gave."""
+        if not self._unread or not self.config.prefix_cache:
+            return
+        ps = self.config.page_size
+        head = prefix_hash_chain(
+            request.prompt[:ps], ps,
+            adapter_salt(self._prefix_salt, request.sampling.adapter_id))
+        if head and any(u.first is not None and u.rec.chain[:1] == head
+                        for u in self._unread):
+            self._read_prefills(finished)
+
+    def _read_prefills(self, finished: List[RequestResult]) -> None:
+        """Read the prefills and chunks dispatched since the last call
+        and commit what they give; on the plain path after the tick's
+        decode step went out, so each read waits for its prefill with
+        that step queued behind it. A chunk that is not the prompt's
+        last adds its finite flag to the request's. A first token is
+        committed as it came: the prefix is interned if every program of
+        the prompt was finite and the slot quarantined if not, the token
+        appended and stamped (TTFT), and the request retired on EOS or
+        length. The row the tick's step holds for a slot that leaves
+        here is dropped when that step is read."""
+        unread, self._unread = self._unread, []
+        for rec, first, finite in unread:
+            request = rec.request
+            with span(TICK_PREFILL, trace_id=request.trace_id,
+                      prompt_tokens=request.prompt_len):
+                with span(TICK_READBACK, reads=2 if first is not None else 1,
+                          bytes=5 if first is not None else 1):
+                    rec.finite_ok = rec.finite_ok and bool(np.asarray(finite))
+                    if first is not None:
+                        first = int(np.asarray(first))
+                with span(TICK_COMMIT) as commit:
+                    if first is None:
+                        rec.chunk_marks.append(clock.now())
+                        continue
+                    retired = len(finished)
+                    took = self._commit_first(rec, first, finished)
+                    commit.set_metadata(tokens=took,
+                                        retired=len(finished) - retired)
+
+    def _commit_first(self, rec: _Active, first: int,
+                      finished: List[RequestResult]) -> int:
+        """Commit a prefill's first token; returns the tokens taken (0
+        when the prefill was not finite and the slot is quarantined)."""
+        slot = rec.slot
+        if self._active.get(slot) is not rec:
+            return 0            # retired since by a failed page extend
         if self.config.prefix_cache:
-            # hit/miss accounting lands at COMPLETION so hits +
+            # hit/miss accounting lands with the first token so hits +
             # misses stays == prefills even when a mid-prefill
             # request times out or is cancelled
             if rec.shared_used:
                 self.metrics.inc("prefix_hits")
-                self.metrics.inc("prefix_pages_shared",
-                                 rec.shared_used)
+                self.metrics.inc("prefix_pages_shared", rec.shared_used)
             else:
                 self.metrics.inc("prefix_misses")
+            # publish the prompt's full pages (shared run + freshly
+            # prefilled privates) so later prompts hit; gated on
+            # finite logits — a poisoned prefill must never be
+            # shared. On an exact repeat this is a no-op; a longer
+            # prompt upgrades the subsumed shorter entry.
             if rec.chain and rec.finite_ok:
+                row = self._page_table_h[slot]
                 self.pages.intern_prefix(
-                    rec.chain,
-                    [int(p) for p in rec.page_row[:len(rec.chain)]])
-        rec.prefill_end = clock.now()
+                    rec.chain, [int(p) for p in row[:len(rec.chain)]])
+        self.metrics.inc("prefills")
+        now = clock.now()
+        rec.prefill_end = now
+        if not rec.finite_ok:
+            finished.append(self._quarantine(rec, "nonfinite_logits", now))
+            return 0
         rec.tokens.append(first)
         rec.last_token = first
-        # token #1 is emitted by THIS tick's final chunk — TTFT stamps
-        # here, not at prefill admission
-        rec.first_token_ts = rec.last_token_ts = rec.prefill_end
-        rec.position = request.prompt_len
-        self._active[slot] = rec
-        self.metrics.inc("prefills")
+        # token #1 reaches the host here — TTFT is submit -> here
+        rec.first_token_ts = rec.last_token_ts = now
         self.metrics.inc("tokens_generated")
         self._sync_slot(rec)
         done = self._finish_reason(rec, first)
         if done is not None:
-            finished.append(self._retire(rec, done, clock.now()))
+            finished.append(self._retire(rec, done, now))
+        return 1
 
     def _abandon_prefill(self, rec: _Active, reason: str,
                          now: float) -> RequestResult:
@@ -2035,12 +2149,21 @@ class InferenceEngine:
     def _decode_tick(self, finished: List[RequestResult]) -> None:
         """The decode half of a tick. Plain decode keeps one step in
         flight: dispatch step k, built from what the host knows without
-        step k-1's tokens, THEN read and commit step k-1, so the host's
-        work on a tick runs while the device computes. A tick with a
-        step in flight and nothing to dispatch still commits it. With
+        step k-1's tokens or the tick's first tokens, THEN read and
+        commit the tick's prefills and step k-1, so the host's work on a
+        tick runs while the device computes. A tick with a step in
+        flight and nothing to dispatch still commits it. With
         speculation the step is read in the tick that dispatched it
         (the draft needs the host's newest tokens)."""
-        step = self._dispatch_step(finished, self._flight)
+        try:
+            step = self._dispatch_step(finished, self._flight)
+        except Exception:
+            # the tick's prefills went out before the step failed: their
+            # first tokens are committed, so that a restart resumes from
+            # them as it did when a prefill was read at once
+            self._read_prefills(finished)
+            raise
+        self._read_prefills(finished)
         if self._spec:
             if step is not None:
                 self._commit_step(step, finished, lag=0)
@@ -2055,10 +2178,12 @@ class InferenceEngine:
         ``ahead`` (the step still unread, or None). A slot with a row in
         ``ahead`` is one position further than the host has committed,
         and its fed token is that row's, taken on the device. A slot
-        whose row in flight delivers its last token (``max_new_tokens``
-        reached, or the next position would be ``max_len``) sits the
-        step out: its finish is known without the token. Returns None
-        when no slot takes part."""
+        prefilled this tick whose first token the host has not read is
+        fed that token from the device too (the prefill put it into
+        ``_carry``). A slot whose token in flight is its last
+        (``max_new_tokens`` reached, or the next position would be
+        ``max_len``) sits the step out: its finish is known without the
+        token. Returns None when no slot takes part."""
         n = self.config.max_slots
         with span(TICK_SCHEDULE, active=len(self._active)) as sched:
             lead = np.zeros(n, np.int32)    # rows in flight, slot by slot
@@ -2066,17 +2191,23 @@ class InferenceEngine:
                 for slot, rec in ahead.rows:
                     if self._active.get(slot) is rec:
                         lead[slot] = 1
+            # a first token not read yet is owed like a row in flight,
+            # at the position the prefill already reached
+            owed = lead.copy()
+            for u in self._unread:
+                if u.first is not None:
+                    owed[u.rec.slot] = 1
             if self._spec and self._active:
                 self._build_windows()
             now = clock.now()
             rows = []
             for slot in sorted(self._active):
                 rec = self._active[slot]
-                flying = int(lead[slot])
-                at = rec.position + flying
-                if len(rec.tokens) + flying >= rec.request.max_new_tokens \
+                at = rec.position + int(lead[slot])
+                if len(rec.tokens) + int(owed[slot]) \
+                        >= rec.request.max_new_tokens \
                         or at >= self.config.max_len:
-                    continue            # its row in flight is its last
+                    continue            # its token in flight is its last
                 # a speculative step appends K/V for the whole verify
                 # window (positions at..at+wl-1); wl is clipped to the
                 # request's max_new_tokens, so the target stays within
@@ -2106,9 +2237,10 @@ class InferenceEngine:
                              np.int32(self.pages.n_pages))
             positions = np.where(part, self._positions_h + lead,
                                  np.int32(0))
-            args = self._decode_args(table, positions, lead == 0)
+            carried = int((part & (owed > lead)).sum())
+            args = self._decode_args(table, positions, owed == 0)
         with span(TICK_DISPATCH, program="decode", rows=len(rows),
-                  in_flight=int(ahead is not None),
+                  in_flight=int(ahead is not None), carried=carried,
                   **self._dispatch_pages(slots, positions)):
             nxt, finite, self._caches = self._decode_fn(*args)
             # the copies to the host start now, so the read a tick
@@ -2121,6 +2253,8 @@ class InferenceEngine:
         self.metrics.inc("decode_steps")
         if ahead is not None:
             self.metrics.inc("decode_steps_overlapped")
+        if carried:
+            self.metrics.inc("first_tokens_carried", carried)
         self.metrics.observe("decode_batch_size", len(rows))
         return _Flight(rows, nxt, finite, call)
 

@@ -178,11 +178,14 @@ class ShardedEngine(InferenceEngine):
             in_specs=(pspec, cspec, rep, *fed, rep, rep, rep, rep,
                       rep, lspec),
             out_specs=(rep, rep, cspec))
+        # a prefill program also takes the carried token vector and the
+        # slot's index, and returns the vector with its first token in:
+        # replicated, as the decode program returns it
         prefill = shard_map(
             self._paged_prefill_body, mesh=mesh,
             in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                      rep, lspec),
-            out_specs=(rep, rep, cspec))
+                      rep, lspec, rep, rep),
+            out_specs=(rep, rep, cspec, rep))
         # suffix prefill (prefix-cache hit, and every chunk of a chunked
         # prefill): the gather/scatter of the slot's pages is rank-local
         # on each rank's head slice, so sharding follows the pool spec;
@@ -191,8 +194,8 @@ class ShardedEngine(InferenceEngine):
         suffix = shard_map(
             self._suffix_prefill_body, mesh=mesh,
             in_specs=(pspec, cspec, rep, rep, rep, rep, rep, rep,
-                      rep, rep, rep, rep, lspec),
-            out_specs=(rep, rep, cspec))
+                      rep, rep, rep, rep, lspec, rep, rep),
+            out_specs=(rep, rep, cspec, rep))
         scrub = shard_map(
             self._paged_scrub_body, mesh=mesh,
             in_specs=(cspec, rep), out_specs=cspec)

@@ -81,13 +81,15 @@ def serving_programs(engine) -> dict:
     prompt = jnp.zeros((1, 16), jnp.int32)
     sampling = (jnp.float32(0.0), jnp.int32(engine._vocab), jnp.int32(0))
     aix = jnp.zeros(1, jnp.int32)
+    carry = engine._carry_args(0)
     return {
         "decode": (engine._decode_fn._fn, *engine._decode_args()),
         "prefill": (engine._prefill_fn._fn, engine._params, engine._caches,
-                    row, prompt, jnp.int32(11), *sampling, aix, None),
+                    row, prompt, jnp.int32(11), *sampling, aix, None,
+                    *carry),
         "suffix": (engine._suffix_fn._fn, engine._params, engine._caches,
                    row, prompt, jnp.int32(8), jnp.int32(3), jnp.int32(11),
-                   *sampling, jnp.bool_(False), aix, None),
+                   *sampling, jnp.bool_(False), aix, None, *carry),
     }
 
 

@@ -385,7 +385,14 @@ def _lowered_programs():
 #: at ``ffn`` (``rope-rms-swiglu-gqa-window/generate`` and the three
 #: ``routed/*``, read anew there); every other digest, the gated model's
 #: ``init`` and ``loss`` among them, stood: training and the models that
-#: are not gated lower to the text they did.
+#: are not gated lower to the text they did. Carrying a prefill's first
+#: token to the decode step on the device gave the two prefill programs
+#: the carried token vector and the slot's index as their last arguments
+#: and the vector with the first token put in as their last result
+#: (``engine/paged-prefill``, ``engine/suffix-prefill`` and the two
+#: ``routed/*`` prefills, read anew then: the text before it with two
+#: arguments, one result and one scatter more); decode and spec-decode
+#: kept theirs.
 PARENT_PROGRAMS = {
     "gpt2-style/init": "54950bef44af6471",
     "gpt2-style/loss": "eff5da79fceee869",
@@ -402,16 +409,16 @@ PARENT_PROGRAMS = {
     "bert/forward": "35e3f4c7245fd3e6",
     "encoder-decoder/loss": "f2746609c8484d9e",
     "engine/paged-decode": "59e792bd043da028",
-    "engine/paged-prefill": "b33e75e42f8d0415",
-    "engine/suffix-prefill": "90c81dffea2e0d87",
+    "engine/paged-prefill": "450d89270bab5e2b",
+    "engine/suffix-prefill": "a880e4ad656e0bea",
     "engine/spec-decode": "5d15c62e192aebbf",
     # read at the parent of PR 34 (commit 8cb1cae), which gave the router
     # its groups, the engine its latent kind and the chunk program's body
     # a second cache form: the routed model's programs were the parent's
     # until PR 36 (above), whose own these three are
     "routed/paged-decode": "1602bcec0c68f676",
-    "routed/paged-prefill": "1dd8284305184d89",
-    "routed/suffix-prefill": "44e5bb840412f0d8",
+    "routed/paged-prefill": "b5667977bec981fa",
+    "routed/suffix-prefill": "fad958ffe6875b4e",
 }
 
 

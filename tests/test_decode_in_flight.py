@@ -11,6 +11,7 @@ once.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -428,3 +429,233 @@ def test_the_default_arguments_feed_the_hosts_tokens(small):
         assert np.array_equal(np.asarray(positions), eng._positions_h)
         assert np.array_equal(np.asarray(tokens), eng._tokens_h)
         assert carry.shape == (eng.config.max_slots,)
+
+
+# -- an admission tick: the first token rides to the step on the device -----
+
+class _Spans:
+    """Stands in for the engine's ``span``: every span as entered, its
+    name and attributes (``set_metadata`` adds to them)."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **attrs):
+        self.log.append((name, attrs))
+
+        class _Span:
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **more):
+                attrs.update(more)
+
+        return _Span()
+
+
+def _lock_step(eng):
+    """The order the plain engine kept before a prefill's first token
+    rode to the decode step on the device: every prefill and chunk is
+    read as soon as it went out, before the tick's step is built. The
+    same programs on the same inputs: only the host's order differs."""
+    for name in ("_prefill_into", "_run_chunk"):
+        run = getattr(eng, name)
+
+        def read_at_once(*args, run=run):
+            out = run(*args)
+            eng._read_prefills(args[-1])
+            return out
+
+        setattr(eng, name, read_at_once)
+    return eng
+
+
+@pytest.mark.parametrize("spec", [0, 2], ids=["plain", "speculation"])
+def test_an_admission_tick_dispatches_its_step_before_the_read(
+        small, monkeypatch, spec):
+    """Plain: the step that goes out in the admitting tick is fed the
+    prefill's token on the device (``carried``), and the prefill is read
+    after it. Speculation drafts from the host's tokens: it reads the
+    prefill at once, before the step, and carries nothing."""
+    import apex_tpu.serving.engine as engine_module
+
+    spans = _Spans()
+    monkeypatch.setattr(engine_module, "span", spans)
+    with _engine(small, speculation=spec) as eng:
+        eng.submit(Request(prompt=_prompt(5, 51), max_new_tokens=4))
+        eng.tick()
+        order = [(n, a) for n, a in spans.log
+                 if n in ("tick.dispatch", "tick.readback")]
+        kinds = [a.get("program") or ("step" if "lag" in a else "prefill")
+                 for _, a in order]
+        decode = next(a for _, a in order if a.get("program") == "decode")
+        counted = eng.metrics.counters()["first_tokens_carried"]
+        if spec:
+            assert kinds == ["paged_prefill", "prefill", "decode", "step"]
+            assert decode["carried"] == 0 and counted == 0
+        else:
+            assert kinds == ["paged_prefill", "decode", "prefill"]
+            assert decode["carried"] == 1 and counted == 1
+        # the first token is committed in its tick; with speculation the
+        # step's tokens are too
+        ((_, tokens, _),) = eng.inflight()
+        assert len(tokens) > 1 if spec else len(tokens) == 1
+
+
+def _served(eng, requests):
+    """``requests`` served through ``eng`` (copies: an engine keeps what
+    it completed by request id), results in order."""
+    return eng.serve([Request(prompt=list(r.prompt),
+                              max_new_tokens=r.max_new_tokens,
+                              sampling=r.sampling, eos_token=r.eos_token)
+                      for r in requests])
+
+
+def _case(small, kind):
+    """Engine settings and requests of one kind of admission."""
+    model, _ = small
+    mixed = [SamplingParams(), SAMPLED,
+             SamplingParams(temperature=0.7, top_k=9, seed=3)]
+    prompts = [_prompt(n, 60 + n) for n in (5, 11, 3, 17, 8)]
+    config, adapters, ids = {"max_slots": 3}, None, [None] * 5
+    if kind == "prefix_hit":
+        # two full pages shared: the second and later prompts hit the
+        # first's pages, in the tick it was prefilled and after
+        shared = _prompt(8, 70)
+        prompts = [shared + _prompt(n, 71 + n) for n in (3, 5, 0, 9, 1)]
+    elif kind == "int8":
+        config.update(kv_dtype="int8", prefix_cache=False)
+    elif kind == "lora":
+        from apex_tpu.lora import AdapterStore, random_adapter
+
+        adapters = AdapterStore(model.config, 4, max_adapters=2)
+        for i, aid in enumerate(("ta", "tb")):
+            adapters.load(aid, random_adapter(
+                model.config, 4, jax.random.PRNGKey(i + 1), scale=0.05))
+        ids = ["ta", None, "tb", "ta", "tb"]
+    elif kind == "chunked":
+        config.update(prefill_token_budget=8)
+    requests = [
+        Request(prompt=p, max_new_tokens=3 + i,
+                sampling=SamplingParams(
+                    temperature=mixed[i % 3].temperature,
+                    top_k=mixed[i % 3].top_k, seed=mixed[i % 3].seed,
+                    adapter_id=aid))
+        for i, (p, aid) in enumerate(zip(prompts, ids))]
+    return config, adapters, requests
+
+
+@pytest.mark.parametrize("kind", ["fresh", "prefix_hit", "int8", "lora",
+                                  "chunked"])
+def test_carried_first_tokens_serve_what_the_lock_step_order_serves(
+        small, kind):
+    """Mixed batches, greedy and sampled, each kind of admission: the
+    tokens are the lock-step order's bit for bit (the same programs,
+    the prefill read before the step is built), every first token fed
+    its tick's step on the device, one decode program, a prefill
+    program a bucket at most."""
+    model, params = small
+    config, adapters, requests = _case(small, kind)
+
+    def engine():
+        return InferenceEngine(
+            model, params, EngineConfig(max_len=MAX_LEN, page_size=4,
+                                        **config),
+            metrics=MetricsRegistry([InMemorySink()]), adapters=adapters)
+
+    with _lock_step(engine()) as lock_step:
+        want = _served(lock_step, requests)
+        assert lock_step.metrics.counters()["first_tokens_carried"] == 0
+    with engine() as eng:
+        got = _served(eng, requests)
+        assert [r.tokens for r in got] == [r.tokens for r in want]
+        assert [r.finish_reason for r in got] \
+            == [r.finish_reason for r in want] == ["length"] * 5
+        counters = eng.metrics.counters()
+        assert counters["prefills"] == len(requests)
+        carried = counters["first_tokens_carried"]
+        if kind == "prefix_hit":
+            # an admission whose prompt starts with the page of a prefill
+            # of its tick has that prefill read first, so that it finds
+            # the pages interned: the hits are the lock-step order's
+            assert counters["prefix_hits"] \
+                == lock_step.metrics.counters()["prefix_hits"] > 0
+            assert 0 < carried < counters["prefills"]
+        else:
+            assert carried == counters["prefills"]
+        if kind == "chunked":
+            assert counters["prefill_chunks"] > len(requests)
+        assert _compiled_once(eng)
+        assert eng.prefill_compiles <= len(eng.buckets)
+        assert eng.chunk_compiles <= len(eng.buckets)
+
+
+def test_a_first_token_that_is_eos_drops_its_row(small):
+    """EOS as the first token: the tick's step went out with a row for
+    the slot before the read found it; the request ends in that tick,
+    the row is dropped in the next, where the slot takes the next
+    request."""
+    a, want_a = _with_eos_at(small, 1, 4, 81, 6)
+    b = Request(prompt=_prompt(5, 82), max_new_tokens=4, sampling=SAMPLED)
+    with _engine(small, max_slots=1) as eng:
+        eng.submit(a)
+        eng.submit(b)
+        (done,) = eng.tick()
+        assert done.finish_reason == "eos" and done.tokens == want_a
+        assert [rec.request.request_id for _, rec in eng._flight.rows] \
+            == [a.request_id]
+        assert _counters(eng) == (1, 0, 0)
+        assert eng.tick() == []
+        assert eng.admission_log == [a.request_id, b.request_id]
+        assert _counters(eng) == (2, 1, 1)
+        _finish(eng)
+        assert eng.completed[b.request_id].tokens == _ref(small, b)
+        assert eng.metrics.counters()["first_tokens_carried"] == 2
+        assert _compiled_once(eng)
+
+
+def test_a_single_token_request_gets_no_row(small):
+    """``max_new_tokens == 1``: the prefill's token is the last, known
+    without reading it; no step goes out for the slot."""
+    req = Request(prompt=_prompt(6, 83), max_new_tokens=1, sampling=SAMPLED)
+    with _engine(small) as eng:
+        eng.submit(req)
+        (done,) = eng.tick()
+        assert done.tokens == _ref(small, req)
+        assert done.finish_reason == "length"
+        assert eng._flight is None
+        counters = eng.metrics.counters()
+        assert counters["decode_steps"] == 0
+        assert counters["first_tokens_carried"] == 0
+
+
+def test_a_nonfinite_prefill_is_quarantined_and_not_interned(small):
+    """A prefill whose logits are not finite: read after the tick's
+    step went out with a row for it, the slot is quarantined (that row
+    dropped), and its prompt's pages are not published; the same prompt
+    again misses and serves the reference's stream."""
+    prompt = _prompt(10, 84)
+    with _engine(small) as eng:
+        real = eng._prefill_fn
+
+        def poisoned(*args):
+            first, _, caches, carry = real(*args)
+            return first, jnp.zeros((), bool), caches, carry
+
+        eng._prefill_fn = poisoned
+        eng.submit(Request(prompt=prompt, max_new_tokens=5))
+        (bad,) = eng.tick()
+        assert bad.finish_reason == "error" and bad.tokens == []
+        eng._prefill_fn = real
+        again = Request(prompt=prompt, max_new_tokens=5)
+        (res,) = eng.serve([again])
+        assert res.tokens == _ref(small, again)
+        counters = eng.metrics.counters()
+        assert counters["slots_quarantined"] == 1
+        assert counters["decode_rows_dropped"] == 1
+        assert (counters["prefix_hits"], counters["prefix_misses"]) == (0, 2)
+        eng.pages.check()
+        assert _compiled_once(eng)

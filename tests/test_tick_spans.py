@@ -7,8 +7,10 @@ A tiny engine is ticked under ``jax.profiler.start_trace`` on the CPU and
 the trace file is read back: every ``engine.tick`` holds the five leaf
 spans in order, disjoint, with their attributes, and a prefill carries its
 request's ``trace_id`` and the prompt tokens its program computed; a
-decode dispatch says whether a step was in flight ahead of it, a read-back
-how many went out behind the step it reads. With no profiler on, a tick writes nothing new into the registry. The compiled text of a decode program, a prefill
+decode dispatch says whether a step was in flight ahead of it and how
+many of its rows were fed a prefill's first token on the device, which the
+tick reads after it, and a read-back how many went out behind the step it
+reads. With no profiler on, a tick writes nothing new into the registry. The compiled text of a decode program, a prefill
 program and a sharded train step carries every scope name in some
 ``op_name``. Token streams do not change under the profiler.
 """
@@ -186,6 +188,10 @@ def test_leaf_spans_carry_their_counts(traced_ticks, name, keys):
 
 
 def test_a_prefill_span_links_the_tick_to_the_request(traced_ticks):
+    """A request's prefill is two ``tick.prefill`` spans of one tick:
+    the one that dispatches its program (``bucket``), and the one that
+    reads and commits its first token, after the tick's decode step went
+    out. Between them they hold every leaf."""
     requests, _, events = traced_ticks
     prefills = [e for e in events if e[0] == tracing.TICK_PREFILL]
     assert ({e[3]["trace_id"] for e in prefills}
@@ -193,10 +199,49 @@ def test_a_prefill_span_links_the_tick_to_the_request(traced_ticks):
     by_id = {r.trace_id: r for r in requests}
     for e in prefills:
         assert e[3]["prompt_tokens"] == by_id[e[3]["trace_id"]].prompt_len
-        assert e[3]["bucket"] >= e[3]["prompt_tokens"]
-        inner = [x[0] for x in _inside(events, e)]
+    for r in requests:
+        sent, read = [e for e in prefills if e[3]["trace_id"] == r.trace_id]
+        assert sent[3]["bucket"] >= sent[3]["prompt_tokens"]
+        assert "bucket" not in read[3] and sent[2] <= read[1]
+        (tick,) = [t for t in events if t[0] == tracing.TICK_ENGINE
+                   and t[1] <= sent[1] and read[2] <= t[2]]
+        (step,) = [d for d in _inside(events, tick)
+                   if d[0] == tracing.TICK_DISPATCH
+                   and d[3]["program"] == "decode"]
+        assert sent[2] <= step[1] and step[2] <= read[1]
+        inner = [x[0] for x in _inside(events, sent) + _inside(events, read)]
         for leaf in TICK_LEAF_SPANS:
             assert leaf in inner
+
+
+def test_an_admission_tick_carries_its_first_tokens(traced_ticks):
+    """An admission tick's leaves stay disjoint and in order; its decode
+    step carries a row for each prefill it dispatched (``carried``) and
+    goes out before the first of their reads."""
+    requests, _, events = traced_ticks
+    admitting = 0
+    for tick in (e for e in events if e[0] == tracing.TICK_ENGINE):
+        inner = _inside(events, tick)
+        sent = [e for e in inner
+                if e[0] == tracing.TICK_PREFILL and "tokens" in e[3]]
+        if not sent:
+            continue
+        admitting += 1
+        leaves = [e for e in inner if e[0] in TICK_LEAF_SPANS]
+        for a, b in zip(leaves, leaves[1:]):
+            assert a[2] <= b[1], (a, b)
+        (step,) = [e for e in inner if e[0] == tracing.TICK_DISPATCH
+                   and e[3]["program"] == "decode"]
+        assert int(step[3]["carried"]) == len(sent)
+        reads = [e for e in inner if e[0] == tracing.TICK_READBACK
+                 and "lag" not in e[3]]
+        assert len(reads) == len(sent)
+        assert all(step[2] <= r[1] for r in reads)
+    assert admitting >= 1
+    carried = [int(e[3]["carried"]) for e in events
+               if e[0] == tracing.TICK_DISPATCH
+               and e[3]["program"] == "decode"]
+    assert sum(carried) == len(requests)
 
 
 @pytest.mark.parametrize("kind", ["monolithic", "prefix_hit", "chunked"])
@@ -354,7 +399,8 @@ def program_texts(small):
             engine._params, engine._caches,
             jnp.asarray(engine._page_table_h[0]), jnp.asarray(padded),
             jnp.int32(5), jnp.float32(0.0), jnp.int32(64), jnp.int32(0),
-            jnp.asarray([0], jnp.int32), engine._bank).compile().as_text()
+            jnp.asarray([0], jnp.int32), engine._bank,
+            *engine._carry_args(0)).compile().as_text()
         engine.close()
 
         parallel_state.destroy_model_parallel()
